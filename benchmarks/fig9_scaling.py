@@ -149,8 +149,9 @@ def run_devices(device_counts=None, n_nodes=5_000, n_edges=160_000,
             env=forced_host_device_env(n_dev),
         )
         if out.returncode != 0:
-            emit(f"fig9/devices_{n_dev}", 0.0, f"FAILED: {out.stderr[-200:]}")
-            continue
+            raise SystemExit(
+                f"fig9 device sweep failed on {n_dev} device(s):\n"
+                f"{out.stderr[-3000:]}")
         line = [l for l in out.stdout.splitlines() if l.startswith("RESULT,")][0]
         _, dev, wall_us, modeled_ms, iters, bytes_, ici_ms, ici_bytes = line.split(",")
         rows[n_dev] = float(modeled_ms)

@@ -45,6 +45,9 @@ def main() -> None:
                          "(default: 2.0x the baseline us_per_call)")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (
         autotune_bench,
         chaos_bench,

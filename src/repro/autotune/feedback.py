@@ -90,9 +90,16 @@ class OnlineCalibrator:
         observed engines; all-ones until the first update."""
         if self.n_updates == 0:
             return np.ones(N_ENGINES)
-        # ridge prior toward the (scale-free) uncorrected model
+        # ridge prior toward the uncorrected model at the data's own
+        # scale: s from the one-factor fit measured ≈ s · modeled, so the
+        # relative corrections do not depend on the wall-clock units
+        ones = np.ones(N_ENGINES)
+        weight = float(ones @ self._A @ ones)
+        s = float(ones @ self._b) / weight if weight > 0 else 1.0
+        if not s > 0:
+            s = 1.0
         A = self._A + self.ridge * np.eye(N_ENGINES)
-        b = self._b + self.ridge * np.ones(N_ENGINES)
+        b = self._b + self.ridge * s * ones
         try:
             c = np.linalg.solve(A, b)
         except np.linalg.LinAlgError:

@@ -23,8 +23,8 @@ flag (threaded from ``HyTMConfig.use_kernels`` — ``"auto"`` resolves via
   and relaxes the prefix, `zerocopy` gathers edge ids through a take
   (random access).
 * ``use_kernels=True`` — the Pallas kernel path: FILTER combines through
-  the blocked ``segment_spmm`` (one-hot MXU scatter-add / masked-select
-  scatter-min), COMPACT squeezes the active edges through the
+  the blocked ``segment_spmm`` (destination-routed masked-select
+  scatter-add / scatter-min), COMPACT squeezes the active edges through the
   ``frontier_compact`` stream-compaction kernel and relaxes the dense
   prefix, ZEROCOPY re-fetches the block as per-window DMA descriptors
   through ``hyb_gather`` before combining.
@@ -102,6 +102,24 @@ def _combine_spmm(block: EdgeBlock, msg: jax.Array, n: int, program: VertexProgr
     return RelaxOut(agg=out[:, 0], touched=out[:, 1] > 0)
 
 
+def _pack_fields(block: EdgeBlock) -> jax.Array:
+    """(B, 4) int32 [src, dst, weight bits, active] for the data-movement
+    kernels.  Int32 all the way: a float lane holding an id's bit pattern
+    is a denormal, and TPU arithmetic flushes denormals to zero."""
+    return jnp.stack([
+        block.src,
+        block.dst,
+        jax.lax.bitcast_convert_type(block.weight, jnp.int32),
+        block.active.astype(jnp.int32),
+    ], axis=-1)
+
+
+def _unpack_fields(packed: jax.Array):
+    return (packed[:, 0], packed[:, 1],
+            jax.lax.bitcast_convert_type(packed[:, 2], jnp.float32),
+            packed[:, 3] != 0)
+
+
 # ------------------------------------------------------------------ engines
 
 def relax_filter(
@@ -126,28 +144,23 @@ def relax_compact(
     downstream dense kernel would stream.  Correctness is unaffected by
     the permutation (combiners are commutative).  The kernel path runs
     the real ``frontier_compact`` stream-compaction kernel over the
-    packed (src, dst, weight) columns; both paths keep kept lanes in
-    original order (stable), so even the SUM summation order matches the
-    oracle on the dense prefix.
+    packed int32 (src, dst, weight bits, active) columns; both paths
+    keep kept lanes in original order (stable), so even the SUM
+    summation order matches the oracle on the dense prefix.
     """
     if use_kernels:
         from repro.kernels.frontier_compact.ops import frontier_compact
 
         B = block.src.shape[0]
-        # int32 ids ride the kernel's one-hot permutation matmul as exact
-        # float32 (ids < 2^24 — partition blocks are far smaller); the
-        # matmul multiplies by exact 0/1, so finite values copy bit-exact.
-        packed = jnp.stack([
-            block.src.astype(jnp.float32),
-            block.dst.astype(jnp.float32),
-            block.weight,
-        ], axis=-1)                                     # (B, 3)
-        comp, cnt = frontier_compact(packed, block.active)
+        # the kernel moves 32-bit patterns: ids ride as int32 and the
+        # weight as its bits, so every field copies exactly
+        comp, cnt = frontier_compact(_pack_fields(block), block.active)
         lane_valid = jnp.arange(B, dtype=jnp.int32) < cnt
+        src, dst, weight, _ = _unpack_fields(comp)
         compacted = EdgeBlock(
-            src=jnp.where(lane_valid, comp[:, 0].astype(jnp.int32), 0),
-            dst=jnp.where(lane_valid, comp[:, 1].astype(jnp.int32), 0),
-            weight=jnp.where(lane_valid, comp[:, 2], 0.0),
+            src=jnp.where(lane_valid, src, 0),
+            dst=jnp.where(lane_valid, dst, 0),
+            weight=jnp.where(lane_valid, weight, 0.0),
             active=lane_valid,
         )
     else:
@@ -170,34 +183,21 @@ def relax_zerocopy(
     identical; access pattern is the ZC one.  The kernel path issues the
     block as per-window ``hyb_gather`` DMA descriptors (one descriptor
     per PAD-lane window — the fine-grained request stream Eq. 3 charges)
-    instead of the oracle's ``take``; edge ids round-trip through the
-    gather as bit-cast float lanes (pure data movement, no arithmetic),
-    so reconstruction is exact for any int32 and the relax result is
-    bit-identical to the oracle for both combiners.
+    instead of the oracle's ``take``; the fields ride as int32 lanes
+    (the weight as its bit pattern) through pure data movement, so the
+    relax result is bit-identical to the oracle for both combiners.
     """
     if use_kernels:
         from repro.kernels.hyb_gather.hyb_gather import PAD
         from repro.kernels.hyb_gather.ops import hyb_gather
 
         B = block.src.shape[0]
-        as_f32 = lambda a: jax.lax.bitcast_convert_type(a, jnp.float32)
-        as_i32 = lambda a: jax.lax.bitcast_convert_type(a, jnp.int32)
-        packed = jnp.stack([
-            as_f32(block.src),
-            as_f32(block.dst),
-            block.weight,
-            as_f32(block.active.astype(jnp.int32)),
-        ], axis=-1)                                     # (B, 4)
         n_win = -(-B // PAD)
         starts = jnp.arange(n_win, dtype=jnp.int32) * PAD
         degs = jnp.minimum(jnp.int32(B) - starts, PAD)
-        flat = hyb_gather(packed, starts, degs).reshape(n_win * PAD, 4)[:B]
-        gathered = EdgeBlock(
-            src=as_i32(flat[:, 0]),
-            dst=as_i32(flat[:, 1]),
-            weight=flat[:, 2],
-            active=as_i32(flat[:, 3]) != 0,
-        )
+        flat = hyb_gather(_pack_fields(block), starts, degs)
+        src, dst, weight, active = _unpack_fields(flat.reshape(n_win * PAD, 4)[:B])
+        gathered = EdgeBlock(src=src, dst=dst, weight=weight, active=active)
     else:
         idx = jnp.arange(block.src.shape[0], dtype=jnp.int32)
         gathered = EdgeBlock(

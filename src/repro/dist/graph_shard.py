@@ -70,7 +70,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -510,7 +509,7 @@ def _make_iteration_impl(
         shard = P(axis)
         rep = P()
         state_spec = shard if owner else rep
-        fn = shard_map(
+        fn = jax.shard_map(
             local,
             mesh=mesh,
             in_specs=(
@@ -520,7 +519,7 @@ def _make_iteration_impl(
                 shard, shard, state_spec, state_spec, rep,
             ),
             out_specs=(state_spec, state_spec),
-            check_rep=False,
+            check_vma=False,
         )
         return fn(blocks, stats, second_mask, delta_mass, frontier,
                   operand, correction)

@@ -3,9 +3,9 @@
 JAX has no ``nn.EmbeddingBag``; the jnp substrate builds it from take +
 segment_sum (models/embedding.py).  This kernel fuses the two against the
 HBM-resident table: per grid step it processes one bag tile, issuing one
-row-DMA per (bag, slot) lookup (``pl.load`` with a dynamic row slice —
-the zero-copy access pattern) and reducing in a VMEM accumulator, so the
-gathered rows never round-trip through HBM.
+row-DMA per (bag, slot) lookup (``make_async_copy`` of a dynamic row
+slice — the zero-copy access pattern) and reducing in a VMEM accumulator,
+so the gathered rows never round-trip through HBM.
 
 Indices are scalar-prefetched (they drive the DMA descriptors).
 """
@@ -22,14 +22,17 @@ from jax.experimental.pallas import tpu as pltpu
 TILE_B = 8  # bags per grid step
 
 
-def _kernel(idx_ref, table_ref, out_ref, *, bag_size, mode):
+def _kernel(idx_ref, table_ref, out_ref, row_ref, sem, *, bag_size, mode):
     bi = pl.program_id(0)
 
     def bag_body(b, _):
         def slot_body(s, acc):
             row_id = idx_ref[(bi * TILE_B + b) * bag_size + s]
-            row = pl.load(table_ref, (pl.ds(row_id, 1), slice(None)))  # one DMA
-            row = row.astype(jnp.float32)
+            copy = pltpu.make_async_copy(
+                table_ref.at[pl.ds(row_id, 1)], row_ref, sem)   # one DMA
+            copy.start()
+            copy.wait()
+            row = row_ref[...].astype(jnp.float32)
             if mode == "max":
                 return jnp.maximum(acc, row)
             return acc + row
@@ -38,7 +41,7 @@ def _kernel(idx_ref, table_ref, out_ref, *, bag_size, mode):
         acc = jax.lax.fori_loop(0, bag_size, slot_body, init)
         if mode == "mean":
             acc = acc / bag_size
-        pl.store(out_ref, (pl.ds(b, 1), slice(None)), acc.astype(out_ref.dtype))
+        out_ref[pl.ds(b, 1), :] = acc.astype(out_ref.dtype)
         return _
 
     jax.lax.fori_loop(0, TILE_B, bag_body, 0)
@@ -61,8 +64,10 @@ def embedding_bag_pallas(
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(b_pad // TILE_B,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((TILE_B, d_pad), lambda bi, idx: (bi, 0)),
+        scratch_shapes=[pltpu.VMEM((1, d_pad), table.dtype),
+                        pltpu.SemaphoreType.DMA(())],
     )
     out = pl.pallas_call(
         functools.partial(_kernel, bag_size=L, mode=mode),

@@ -1,10 +1,12 @@
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
 from repro.kernels.frontier_compact.frontier_compact import frontier_compact_pallas
-from repro.kernels.runtime import interpret_mode
+from repro.kernels.runtime import interpret_mode, map_over_lanes
 
 
 def frontier_compact(values: jax.Array, mask: jax.Array):
@@ -19,6 +21,7 @@ def frontier_compact(values: jax.Array, mask: jax.Array):
         # operand, and a 0-step grid would leave the count uninitialized.
         out, cnt = values, jnp.int32(0)
     else:
-        out, cnt = frontier_compact_pallas(
-            values, mask, interpret=interpret_mode())
+        compact = functools.partial(frontier_compact_pallas,
+                                    interpret=interpret_mode())
+        out, cnt = map_over_lanes(compact)(values, mask)
     return (out[:, 0] if squeeze else out), cnt

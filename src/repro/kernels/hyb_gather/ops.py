@@ -1,10 +1,12 @@
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
 from repro.kernels.hyb_gather.hyb_gather import PAD, hyb_gather_pallas
-from repro.kernels.runtime import interpret_mode
+from repro.kernels.runtime import interpret_mode, map_over_lanes
 
 
 def hyb_gather(edges: jax.Array, seg_start: jax.Array, degree: jax.Array):
@@ -19,6 +21,6 @@ def hyb_gather(edges: jax.Array, seg_start: jax.Array, degree: jax.Array):
     if seg_start.shape[0] == 0:
         out = jnp.zeros((0, PAD, edges.shape[1]), edges.dtype)
     else:
-        out = hyb_gather_pallas(
-            edges, seg_start, degree, interpret=interpret_mode())
+        gather = functools.partial(hyb_gather_pallas, interpret=interpret_mode())
+        out = map_over_lanes(gather)(edges, seg_start, degree)
     return out[..., 0] if squeeze else out

@@ -22,6 +22,8 @@ The ``use_kernels`` tri-state:
 
 from __future__ import annotations
 
+from collections.abc import Callable
+
 import jax
 
 
@@ -48,3 +50,30 @@ def resolve_use_kernels(setting: bool | str) -> bool:
                 f"use_kernels must be True, False, or 'auto', got {setting!r}")
         return on_tpu()
     return bool(setting)
+
+
+def map_over_lanes(kernel: Callable) -> Callable:
+    """``kernel`` (array arguments only) with a ``vmap`` rule that loops
+    it over the batch axis (``lax.map``) instead of widening its grid.
+
+    The service lanes ``vmap`` the engines, and Pallas's own batching
+    rule gives every operand a batch block — which Mosaic refuses for a
+    whole-array ``pl.ANY`` (HBM) operand, the DMA source or destination
+    of ``hyb_gather`` and ``frontier_compact``.  One lane per call keeps
+    the compiled kernel exactly the unbatched one.
+    """
+    wrapped = jax.custom_batching.custom_vmap(kernel)
+
+    @wrapped.def_vmap
+    def _rule(axis_size, in_batched, *args):
+        del axis_size
+
+        def one(lane_args):
+            lane = iter(lane_args)
+            return wrapped(*(next(lane) if b else a
+                             for a, b in zip(args, in_batched)))
+
+        out = jax.lax.map(one, [a for a, b in zip(args, in_batched) if b])
+        return out, jax.tree.map(lambda _: True, out)
+
+    return wrapped

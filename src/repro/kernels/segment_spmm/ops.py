@@ -3,10 +3,12 @@ Pallas on TPU backends (backend policy: ``repro.kernels.runtime``)."""
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.runtime import interpret_mode
+from repro.kernels.runtime import interpret_mode, map_over_lanes
 from repro.kernels.segment_spmm.segment_spmm import segment_spmm_pallas
 
 
@@ -38,8 +40,8 @@ def segment_spmm(
         out = jnp.full((n_segments, messages.shape[1]), identity,
                        messages.dtype)
     else:
-        out = segment_spmm_pallas(
-            messages, seg_ids, valid, n_segments, combine=combine,
-            interpret=interpret_mode(),
-        )
+        combine_fn = functools.partial(
+            segment_spmm_pallas, n_segments=n_segments, combine=combine,
+            interpret=interpret_mode())
+        out = map_over_lanes(combine_fn)(messages, seg_ids, valid)
     return out[:, 0] if squeeze else out

@@ -1,31 +1,37 @@
-"""Blocked segment-SpMM Pallas kernel — the FILTER engine's compute core.
+"""Blocked segment-combine Pallas kernel — the FILTER engine's compute core.
 
 The paper's filter engine streams whole partitions over the slow link and
 masks inactive edges in compute.  TPU adaptation (DESIGN.md §2):
 
-* edge messages arrive as an (m, d) stream, tiled (TILE_E, d) through
-  VMEM with lane-aligned blocks — the ``cudaMemcpy``-style saturated
-  contiguous DMA;
+* the wrapper routes each edge to its output block: masked edges get a
+  sentinel block, one XLA sort groups the stream by destination block,
+  and a ``searchsorted`` over the block bounds gives each
+  (TILE_N,)-segment output block its range of 128-edge rows,
+  scalar-prefetched into SMEM;
+* the sorted stream is lane-dense and VMEM-resident for the call:
+  destination ids as an (R, 128) int32 array, messages as (d, R, 128);
 * destination combining cannot use atomics (TPU has none); instead each
-  tile builds a one-hot (TILE_E, TILE_N) routing matrix and reduces with
-  ONE MXU matmul: ``partial = onehot^T @ messages`` — scatter-add
-  re-expressed as systolic compute, the TPU-native idiom;
-* the grid is (n_out_blocks, n_edge_tiles); TPU grids execute
-  sequentially, so each output block accumulates across edge tiles in a
-  fp32 VMEM scratch accumulator and flushes on the last tile.
+  edge row of a block's range is tested against a (TILE_N, 128) tile of
+  output segment ids (segments on sublanes, edges on lanes) and the
+  routed messages are folded elementwise into a (TILE_N, 128) per-lane
+  fp32 partial — scatter re-expressed as a masked select on the VPU,
+  with one cross-lane reduction per output block.
 
-Inactive lanes (``valid=False``: filter-engine masked edges / padding)
-contribute zero rows through the same matmul.
+The grid is one step per output block, and a block visits only the rows
+that hold its edges, so a call does O(B + n) work for a block of B edges
+and n segments.  The whole block sits in VMEM: about 8·(d + 1) bytes per
+edge with double buffering, well inside the scoped-VMEM limit for the
+partition blocks the engines build (B ≈ 26 k at 100 k vertices / 64
+partitions).
 
-Traversal combiners (``combine="min"``) cannot ride the matmul (a sum),
-so the min variant routes each tile through an explicit masked
-select-and-reduce over a (TILE_E_MIN, TILE_N, d) broadcast — VPU, not
-MXU, with a smaller edge tile bounding the 3-D intermediate in VMEM —
-and accumulates with ``minimum`` into a ``+inf``-initialized scratch.
-``min`` of a fixed value multiset is order-independent, which is what
-makes the kernel-backed FILTER engine *bit-identical* to
-``jax.ops.segment_min`` (the engine oracle): segments receiving no valid
-message flush the ``+inf`` identity, exactly like the oracle.
+Both combiners share the body: ``sum`` folds with ``+`` from 0, ``min``
+with ``minimum`` from ``+inf``.  The select keeps ±inf messages intact
+(the 0 * inf = NaN trap rules a matmul out for min).  ``min`` of a fixed
+value multiset is order-independent, which is what makes the
+kernel-backed FILTER engine *bit-identical* to ``jax.ops.segment_min``
+(the engine oracle): segments receiving no valid message flush the
+``+inf`` identity, exactly like the oracle.  ``sum`` reassociates the
+float additions, so it matches ``segment_sum`` within tolerance.
 """
 
 from __future__ import annotations
@@ -37,76 +43,31 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-TILE_E = 512      # edges per tile (sum mode: one-hot MXU matmul)
-TILE_E_MIN = 128  # edges per tile (min mode: 3-D select bound to VMEM)
-TILE_N = 128      # output segments per block (lane-aligned)
+LANES = 128    # edges per row (one lane width)
+TILE_N = 128   # output segments per block (sublanes of the routing tile)
 
 
-def _kernel_sum(seg_ref, valid_ref, msg_ref, out_ref, acc_ref):
+def _kernel(first_ref, last_ref, seg_ref, msg_ref, out_ref, acc_ref, *, combine):
     oi = pl.program_id(0)   # output block index
-    ei = pl.program_id(1)   # edge tile index
-    n_edge_tiles = pl.num_programs(1)
+    d = msg_ref.shape[0]
+    identity = jnp.inf if combine == "min" else 0.0
+    fold = jnp.minimum if combine == "min" else jnp.add
 
-    @pl.when(ei == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    acc_ref[...] = jnp.full(acc_ref.shape, identity, acc_ref.dtype)
+    ids = jax.lax.broadcasted_iota(jnp.int32, (TILE_N, LANES), 0) + oi * TILE_N
 
-    seg = seg_ref[...]        # (TILE_E,)
-    valid = valid_ref[...]    # (TILE_E,)
-    msg = msg_ref[...]        # (TILE_E, d)
+    def row(r, carry):
+        hit = seg_ref[pl.ds(r, 1), :] == ids              # (TILE_N, LANES)
+        for k in range(d):
+            acc_ref[k] = fold(
+                acc_ref[k], jnp.where(hit, msg_ref[k, pl.ds(r, 1), :], identity))
+        return carry
 
-    base = oi * TILE_N
-    local = seg - base
-    in_block = (local >= 0) & (local < TILE_N) & valid
-    # one-hot routing matrix (TILE_E, TILE_N): scatter-add as MXU matmul
-    onehot = (
-        (local[:, None] == jax.lax.broadcasted_iota(jnp.int32, (TILE_E, TILE_N), 1))
-        & in_block[:, None]
-    ).astype(msg.dtype)
-    acc_ref[...] += jax.lax.dot_general(
-        onehot, msg,
-        dimension_numbers=(((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ).astype(acc_ref.dtype)
+    jax.lax.fori_loop(first_ref[oi], last_ref[oi], row, 0)
 
-    @pl.when(ei == n_edge_tiles - 1)
-    def _flush():
-        out_ref[...] = acc_ref[...].astype(out_ref.dtype)
-
-
-def _kernel_min(seg_ref, valid_ref, msg_ref, out_ref, acc_ref):
-    oi = pl.program_id(0)
-    ei = pl.program_id(1)
-    n_edge_tiles = pl.num_programs(1)
-
-    @pl.when(ei == 0)
-    def _init():
-        acc_ref[...] = jnp.full_like(acc_ref, jnp.inf)
-
-    seg = seg_ref[...]        # (TILE_E_MIN,)
-    valid = valid_ref[...]    # (TILE_E_MIN,)
-    msg = msg_ref[...]        # (TILE_E_MIN, d)
-
-    base = oi * TILE_N
-    local = seg - base
-    in_block = (local >= 0) & (local < TILE_N) & valid
-    route = (
-        (local[:, None] == jax.lax.broadcasted_iota(
-            jnp.int32, (TILE_E_MIN, TILE_N), 1))
-        & in_block[:, None]
-    )
-    # masked select keeps ±inf messages intact (0 * inf = NaN rules the
-    # matmul idiom out for min); non-routed lanes contribute the identity
-    contrib = jnp.min(
-        jnp.where(route[:, :, None], msg[:, None, :].astype(jnp.float32),
-                  jnp.inf),
-        axis=0,
-    )  # (TILE_N, d)
-    acc_ref[...] = jnp.minimum(acc_ref[...], contrib.astype(acc_ref.dtype))
-
-    @pl.when(ei == n_edge_tiles - 1)
-    def _flush():
-        out_ref[...] = acc_ref[...].astype(out_ref.dtype)
+    reduce = jnp.min if combine == "min" else jnp.sum
+    for k in range(d):
+        out_ref[:, k:k + 1] = reduce(acc_ref[k], axis=1, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("n_segments", "combine", "interpret"))
@@ -120,28 +81,52 @@ def segment_spmm_pallas(
 ) -> jax.Array:
     if combine not in ("sum", "min"):
         raise ValueError(f"combine must be 'sum' or 'min', got {combine!r}")
-    tile_e = TILE_E if combine == "sum" else TILE_E_MIN
-    kernel = _kernel_sum if combine == "sum" else _kernel_min
     m, d = messages.shape
-    m_pad = -(-m // tile_e) * tile_e
-    n_pad = -(-n_segments // TILE_N) * TILE_N
-    d_pad = -(-d // 128) * 128
-    msg = jnp.pad(messages, ((0, m_pad - m), (0, d_pad - d)))
-    seg = jnp.pad(seg_ids.astype(jnp.int32), (0, m_pad - m), constant_values=-1)
-    val = jnp.pad(valid, (0, m_pad - m), constant_values=False)
+    n_blocks = -(-n_segments // TILE_N)
+    n_pad = n_blocks * TILE_N
+    rows = -(-m // (8 * LANES)) * 8
+    m_pad = rows * LANES
 
-    grid = (n_pad // TILE_N, m_pad // tile_e)
-    out = pl.pallas_call(
-        kernel,
-        grid=grid,
+    # One single-key sort groups the edges by output block: the key packs
+    # (block, edge index), and masked or out-of-range edges take the
+    # sentinel block n_blocks, past every real one.  (A sort that carries
+    # the messages as extra operands compiles for tens of seconds.)
+    idx_bits = max(1, (m - 1).bit_length())
+    if n_blocks.bit_length() + idx_bits > 31:
+        raise ValueError(
+            f"segment_spmm packs (block, edge) into a 31-bit key; got "
+            f"{n_blocks} blocks x {m} edges")
+    seg_ids = seg_ids.astype(jnp.int32)
+    routed = valid & (seg_ids >= 0) & (seg_ids < n_segments)
+    block = jnp.where(routed, seg_ids // TILE_N, n_blocks)
+    # the keys are unique, so an unstable sort (much faster to compile) is exact
+    key = jax.lax.sort((block << idx_bits) | jnp.arange(m, dtype=jnp.int32),
+                       is_stable=False)
+    order = key & ((1 << idx_bits) - 1)
+    bounds = jnp.searchsorted(
+        key >> idx_bits, jnp.arange(n_blocks + 1, dtype=jnp.int32)).astype(jnp.int32)
+    first = bounds[:-1] // LANES
+    last = jnp.where(bounds[1:] > bounds[:-1], -(-bounds[1:] // LANES), first)
+
+    seg = jnp.where(routed, seg_ids, n_pad)[order]
+    seg = jnp.pad(seg, (0, m_pad - m), constant_values=n_pad).reshape(rows, LANES)
+    msg = messages.astype(jnp.float32)[order].T
+    msg = jnp.pad(msg, ((0, 0), (0, m_pad - m))).reshape(d, rows, LANES)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(n_blocks,),
         in_specs=[
-            pl.BlockSpec((tile_e,), lambda oi, ei: (ei,)),
-            pl.BlockSpec((tile_e,), lambda oi, ei: (ei,)),
-            pl.BlockSpec((tile_e, d_pad), lambda oi, ei: (ei, 0)),
+            pl.BlockSpec((rows, LANES), lambda oi, first, last: (0, 0)),
+            pl.BlockSpec((d, rows, LANES), lambda oi, first, last: (0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((TILE_N, d_pad), lambda oi, ei: (oi, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_pad, d_pad), messages.dtype),
-        scratch_shapes=[pltpu.VMEM((TILE_N, d_pad), jnp.float32)],
+        out_specs=pl.BlockSpec((TILE_N, d), lambda oi, first, last: (oi, 0)),
+        scratch_shapes=[pltpu.VMEM((d, TILE_N, LANES), jnp.float32)],
+    )
+    out = pl.pallas_call(
+        functools.partial(_kernel, combine=combine),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((n_pad, d), jnp.float32),
         interpret=interpret,
-    )(seg, val, msg)
-    return out[:n_segments, :d]
+    )(first, last, seg, msg)
+    return out[:n_segments].astype(messages.dtype)

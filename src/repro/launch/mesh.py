@@ -2,12 +2,7 @@
 
 A function (not a module-level constant) so importing this module never
 touches jax device state — required because the dry-run must set
-XLA_FLAGS before any jax initialization.
-
-``_mesh`` papers over the jax API skew around mesh axis types:
-``jax.make_mesh(..., axis_types=...)`` (and ``jax.sharding.AxisType``)
-only exist on newer jax releases; on older ones the plain call is the
-same Auto-typed mesh.
+XLA_FLAGS before any jax initialization.  Every mesh is Auto-typed.
 """
 
 from __future__ import annotations
@@ -16,12 +11,8 @@ import jax
 
 
 def _mesh(shape, axes):
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(
-            shape, axes, axis_types=(axis_type.Auto,) * len(axes)
-        )
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -64,10 +55,22 @@ def forced_host_device_env(n_devices: int) -> dict:
       everything on 1 real device;
     * ``PYTHONPATH`` led by this checkout's ``src`` (derived from the
       installed ``repro`` package, so it works from any cwd).
+
+    These children are CPU rehearsals.  A parent whose backend is an
+    accelerator refuses: it already holds the chip, and its children
+    would report forced-host CPU numbers under the benchmark's names.
     """
     import os
 
     import repro
+
+    if jax.default_backend() != "cpu":
+        raise RuntimeError(
+            f"forced-host CPU devices are a CPU rehearsal; this process runs "
+            f"on {jax.default_backend()!r} and holds its devices, and the "
+            f"children would time the CPU under the benchmark's names — run "
+            f"the multi-device path in this process (chip_smoke.py "
+            f"--four-chips) instead")
 
     # repro is a namespace package (no __init__.py): locate src via
     # __path__, not __file__ (which is None)

@@ -148,6 +148,9 @@ def main() -> None:
                          "constants (never fatal)")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.selfcheck:
         selfcheck()
         return

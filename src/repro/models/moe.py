@@ -35,7 +35,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.models.common import dense_init, swiglu
 
@@ -292,12 +291,12 @@ def moe_ffn(
                 "shared_up": P(None, tp_axis),
                 "shared_down": P(tp_axis, None),
             })
-        fn = shard_map(
+        fn = jax.shard_map(
             core,
             mesh=mesh,
             in_specs=(P(batch_axes, None), pspec),
             out_specs=(P(batch_axes, None), P(all_axes)),
-            check_rep=False,
+            check_vma=False,
         )
         y, aux = fn(xc, params)
         return y, jnp.mean(aux)

@@ -4,48 +4,3 @@ import sys
 # tests run against the source tree; single CPU device (the dry-run and
 # the distributed tests manage their own device counts via subprocesses)
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
-
-# ----------------------------------------------------------------------
-# hypothesis guard: several modules property-test with hypothesis.  When
-# it is genuinely unavailable (hermetic containers without the package)
-# install the deterministic fallback sampler so those modules still
-# collect and run; if even that fails, skip them with a clear message
-# instead of erroring the whole collection.
-_HYPOTHESIS_MODE = "real"
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    try:
-        sys.path.insert(0, os.path.dirname(__file__))
-        import _hypothesis_fallback
-
-        _hypothesis_fallback.install()
-        _HYPOTHESIS_MODE = "fallback"
-    except Exception:
-        _HYPOTHESIS_MODE = "missing"
-        # hypothesis unavailable and the fallback shim broke: skip the
-        # property-based modules rather than failing collection.
-        collect_ignore = [
-            "test_autotune.py",
-            "test_cost_model.py",
-            "test_engines.py",
-            "test_graph.py",
-            "test_serve.py",
-            "test_stream.py",
-        ]
-
-
-def pytest_report_header(config):
-    if _HYPOTHESIS_MODE == "fallback":
-        return (
-            "hypothesis: not installed — property tests run via the "
-            "deterministic fixed-seed fallback (tests/_hypothesis_fallback.py); "
-            "install hypothesis for real property testing"
-        )
-    if _HYPOTHESIS_MODE == "missing":
-        return (
-            "hypothesis: not installed and fallback unavailable — "
-            "skipping property-based test modules (test_autotune, "
-            "test_cost_model, test_engines, test_graph, test_stream)"
-        )
-    return None

@@ -5,7 +5,7 @@ import dataclasses
 
 import numpy as np
 import pytest
-from hypothesis import given, settings, strategies as st
+from hypothesis import example, given, settings, strategies as st
 
 from repro.autotune import (
     OnlineCalibrator,
@@ -200,6 +200,8 @@ def test_any_valid_profile_skips_inactive_partitions(
 
 @settings(deadline=None, max_examples=20)
 @given(scale=st.floats(1e-6, 1e3), ratio=st.floats(1.0, 2000.0))
+# a microsecond-scale wall clock once let the ridge prior swamp the data
+@example(scale=1e-6, ratio=1.0)
 def test_online_calibrator_learns_relative_ratio(scale, ratio):
     """Feeding measured = scale * (c_f*T_f + c_z*T_z) with c_z/c_f =
     ratio, the solved correction reproduces the *relative* ratio

@@ -107,8 +107,9 @@ def test_cache_rule_kv_heads_vs_sequence():
     # kv=8 divides model=4 -> heads shard; kv=1 (MQA) -> sequence shards
     heads = dict(lm_cache_rule(FakeMesh(), 8))
     seq = dict(lm_cache_rule(FakeMesh(), 1))
-    assert list(heads[r"(^|/)[kv]$"]) == [("data",), None, "model", None]
-    assert list(seq[r"(^|/)[kv]$"]) == [("data",), "model", None, None]
+    # PartitionSpec equality normalizes a 1-tuple axis to its bare name
+    assert heads[r"(^|/)[kv]$"] == P(("data",), None, "model", None)
+    assert seq[r"(^|/)[kv]$"] == P(("data",), "model", None, None)
 
 
 def test_all_arch_cells_build_on_debug_mesh(mesh11):

@@ -305,3 +305,30 @@ def test_checkpoint_elastic_reshard():
         assert jnp.array_equal(restored["w"], x)
         print("OK elastic")
     """)
+
+
+def test_forced_host_devices_refuse_an_accelerator_parent(monkeypatch):
+    """On a chip the parent already holds the device, and forced-host CPU
+    children would time the CPU under a benchmark's name: the recipe
+    behind fig9-devices and stream-devices refuses with the reason."""
+    import jax
+
+    from repro.launch.mesh import forced_host_device_env
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="CPU rehearsal"):
+        forced_host_device_env(4)
+
+
+def test_fig9_device_sweep_fails_on_a_failed_child(monkeypatch):
+    """A failed device-count child fails the sweep (no FAILED row and a
+    zero exit)."""
+    import subprocess
+
+    from benchmarks import fig9_scaling
+
+    monkeypatch.setattr(
+        subprocess, "run",
+        lambda cmd, **_: subprocess.CompletedProcess(cmd, 1, "", "child died"))
+    with pytest.raises(SystemExit, match="child died"):
+        fig9_scaling.run_devices(device_counts=(2,), fast=True)

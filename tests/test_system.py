@@ -66,3 +66,29 @@ def test_execution_path_follows_frontier_density():
     ss = run_hytm(g, SSSP, source=0, config=HyTMConfig(n_partitions=32, link=link))
     early = ss.history["engines"][0]
     assert (early == ZEROCOPY).sum() + (early == -1).sum() >= (early == FILTER).sum()
+
+
+def test_compile_cache_directory(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins and no other directory is set;
+    without it the cache sits at the checkout's fixed .jax_cache."""
+    from pathlib import Path
+
+    import jax
+
+    from repro.launch.compile_cache import enable_compile_cache
+
+    saved = (jax.config.jax_compilation_cache_dir,
+             jax.config.jax_persistent_cache_min_compile_time_secs)
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/where")
+        assert enable_compile_cache() == "/some/where"
+        assert jax.config.jax_compilation_cache_dir == saved[0]
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = enable_compile_cache()
+        checkout = Path(__file__).resolve().parents[1]
+        assert path == str(checkout / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+        assert ".jax_cache/" in (checkout / ".gitignore").read_text().split()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", saved[0])
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", saved[1])
