@@ -46,6 +46,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.graph.algorithms import MIN, VertexProgram
+from repro.obs.scopes import ENGINE_SCOPES, scope
 
 
 class EdgeBlock(NamedTuple):
@@ -220,8 +221,10 @@ def relax_with_engine(
     program: VertexProgram,
     use_kernels: bool = False,
 ) -> RelaxOut:
-    return jax.lax.switch(
-        jnp.clip(engine_id, 0, 2),
-        [lambda b=b: ENGINE_FNS[b](block, operand, n, program, use_kernels)
-         for b in range(3)],
-    )
+    def branch(b):
+        def relax():
+            with scope(ENGINE_SCOPES[b]):
+                return ENGINE_FNS[b](block, operand, n, program, use_kernels)
+        return relax
+
+    return jax.lax.switch(jnp.clip(engine_id, 0, 2), [branch(b) for b in range(3)])

@@ -81,6 +81,9 @@ from repro.core.scheduler import make_schedule
 from repro.core.task_generation import TaskPlan, forced_engine_plan, generate_tasks
 from repro.graph.algorithms import MIN, SUM, VertexProgram
 from repro.graph.csr import CSRGraph, DeviceCSR, to_device_csr
+from repro.obs import scopes, span
+from repro.obs.export import CAT_RUN
+from repro.obs.scopes import SELECT, SWEEP, SWEEP_BLOCK, SWEEP_COMBINE, UPDATE, scope
 
 
 @dataclass(frozen=True)
@@ -239,27 +242,8 @@ def _sweep(
     B = rt.parts.block_size
     values0, delta0 = state.values, state.delta
 
-    def body(carry, p):
-        values, delta, activated = carry
-        eng = engines[p]
-        start = rt.parts.edge_start[p]
-        local = jnp.arange(B, dtype=jnp.int32)
-        in_range = local < rt.parts.part_edges[p]
-        src = _slice_block(rt.csr.edge_src, start, B)
-        dst = _slice_block(rt.csr.edge_dst, start, B)
-        w = _slice_block(rt.csr.edge_weight, start, B)
-        processed = eng != NONE
-        active_lane = frontier[src] & in_range & processed
-        block = EdgeBlock(src=src, dst=dst, weight=w, active=active_lane)
-
-        if program.combine == SUM:
-            dsrc = delta if async_sweep else delta0
-            operand = program.damping * dsrc * rt.inv_deg
-        else:
-            operand = values if async_sweep else values0
-
-        out = relax_with_engine(eng, block, operand, n, program, use_kernels)
-
+    def combine(out, values, delta, activated, p, processed):
+        """The visit's n-wide update of values, Δ and ``activated``."""
         if program.peel_k is not None:
             # peeling (k-core): the aggregate is each destination's count
             # of newly-removed in-neighbors — its remaining degree drops
@@ -302,6 +286,30 @@ def _sweep(
             activated = activated | out.touched
         return (values, delta, activated), None
 
+    def body(carry, p):
+        values, delta, activated = carry
+        with scope(SWEEP_BLOCK):
+            eng = engines[p]
+            start = rt.parts.edge_start[p]
+            local = jnp.arange(B, dtype=jnp.int32)
+            in_range = local < rt.parts.part_edges[p]
+            src = _slice_block(rt.csr.edge_src, start, B)
+            dst = _slice_block(rt.csr.edge_dst, start, B)
+            w = _slice_block(rt.csr.edge_weight, start, B)
+            processed = eng != NONE
+            active_lane = frontier[src] & in_range & processed
+            block = EdgeBlock(src=src, dst=dst, weight=w, active=active_lane)
+
+            if program.combine == SUM:
+                dsrc = delta if async_sweep else delta0
+                operand = program.damping * dsrc * rt.inv_deg
+            else:
+                operand = values if async_sweep else values0
+
+        out = relax_with_engine(eng, block, operand, n, program, use_kernels)
+        with scope(SWEEP_COMBINE):
+            return combine(out, values, delta, activated, p, processed)
+
     init = (values0, delta0, jnp.zeros(n, dtype=bool))
     (values, delta, activated), _ = jax.lax.scan(body, init, order)
     return HyTMState(values=values, delta=delta, frontier=state.frontier), activated
@@ -329,100 +337,105 @@ def _iteration_impl(
     # dispatch is a Python-level branch — no runtime cost either way
     use_kernels = resolve_use_kernels(config.use_kernels)
 
-    # (1-3) stats -> costs -> engines -> combined tasks
-    stats = partition_stats(frontier, csr.out_degree, zc_req, parts)
-    if config.forced_engine is None:
-        plan: TaskPlan = generate_tasks(
-            stats, config.link, combine_k=config.combine_k,
-            enable_combination=config.enable_task_combination,
-            correction=correction,
-        )
-    else:
-        plan = forced_engine_plan(
-            stats, config.link, config.forced_engine,
-            enable_combination=config.enable_task_combination,
-            combine_k=config.combine_k,
-        )
+    with scope(SELECT):
+        # (1-3) stats -> costs -> engines -> combined tasks
+        stats = partition_stats(frontier, csr.out_degree, zc_req, parts)
+        if config.forced_engine is None:
+            plan: TaskPlan = generate_tasks(
+                stats, config.link, combine_k=config.combine_k,
+                enable_combination=config.enable_task_combination,
+                correction=correction,
+            )
+        else:
+            plan = forced_engine_plan(
+                stats, config.link, config.forced_engine,
+                enable_combination=config.enable_task_combination,
+                combine_k=config.combine_k,
+            )
 
-    # (4) contribution-driven priority schedule.  Only the 'delta' CDS
-    # mode reads the per-partition |Δ| mass, and min-combine programs
-    # carry an identically-zero Δ — in both cases the (n,)->(P,)
-    # segment-sum would reduce zeros (or feed a schedule that ignores
-    # it), so skip it.
-    if program.combine == MIN or config.cds_mode != "delta":
-        delta_mass = jnp.zeros(parts.n_partitions, jnp.float32)
-    else:
-        delta_mass = jax.ops.segment_sum(
-            jnp.abs(state.delta) * frontier, parts.vertex_part_id,
-            num_segments=parts.n_partitions,
+        # (4) contribution-driven priority schedule.  Only the 'delta' CDS
+        # mode reads the per-partition |Δ| mass, and min-combine programs
+        # carry an identically-zero Δ — in both cases the (n,)->(P,)
+        # segment-sum would reduce zeros (or feed a schedule that ignores
+        # it), so skip it.
+        if program.combine == MIN or config.cds_mode != "delta":
+            delta_mass = jnp.zeros(parts.n_partitions, jnp.float32)
+        else:
+            delta_mass = jax.ops.segment_sum(
+                jnp.abs(state.delta) * frontier, parts.vertex_part_id,
+                num_segments=parts.n_partitions,
+            )
+        mode = config.cds_mode
+        sched = make_schedule(
+            plan.engines, delta_mass, n_hub_partitions, mode, config.recompute_once,
         )
-    mode = config.cds_mode
-    sched = make_schedule(
-        plan.engines, delta_mass, n_hub_partitions, mode, config.recompute_once,
-    )
 
     # (5) asynchronous sweep in priority order
-    state1, activated = _sweep(
-        state, rt, program, plan.engines, sched.order, frontier,
-        config.async_sweep, consume="all", use_kernels=use_kernels,
-    )
+    with scope(SWEEP):
+        state1, activated = _sweep(
+            state, rt, program, plan.engines, sched.order, frontier,
+            config.async_sweep, consume="all", use_kernels=use_kernels,
+        )
 
     # (6) recompute-once: loaded priority partitions, zero extra transfer.
-    engines2 = jnp.where(sched.second_pass, plan.engines, NONE)
-    if program.peel_k is not None:
-        # peeling re-relaxation would re-subtract the same removal counts
-        # (double-count); an empty frontier makes pass 2 a harmless no-op
-        frontier2 = jnp.zeros_like(frontier)
-    elif program.combine == MIN:
-        frontier2 = frontier | activated
-    else:
-        # |Δ|: pending deltas are non-negative on a cold start, but the
-        # incremental path (repro.stream) injects *signed* correction
-        # deltas after edge deletions — negative mass must propagate too.
-        frontier2 = jnp.abs(state1.delta) > program.tolerance
-    state2, activated2 = _sweep(
-        state1, rt, program, engines2, sched.order, frontier2,
-        config.async_sweep, consume="processed", use_kernels=use_kernels,
-    )
-    activated = activated | activated2
-
-    # next frontier
-    if program.peel_k is not None:
-        # removal update: alive vertices whose remaining degree fell
-        # below k are removed now and become the next round's frontier
-        alive = state2.delta < 0.5
-        newly = alive & (state2.values < program.peel_k)
-        next_frontier = newly
-        new_state = HyTMState(
-            values=state2.values,
-            delta=state2.delta + newly.astype(jnp.float32),
-            frontier=next_frontier,
-        )
-    else:
-        if program.combine == MIN:
-            next_frontier = activated
+    with scope(SWEEP):
+        engines2 = jnp.where(sched.second_pass, plan.engines, NONE)
+        if program.peel_k is not None:
+            # peeling re-relaxation would re-subtract the same removal counts
+            # (double-count); an empty frontier makes pass 2 a harmless no-op
+            frontier2 = jnp.zeros_like(frontier)
+        elif program.combine == MIN:
+            frontier2 = frontier | activated
         else:
-            next_frontier = jnp.abs(state2.delta) > program.tolerance
-        new_state = HyTMState(values=state2.values, delta=state2.delta,
-                              frontier=next_frontier)
+            # |Δ|: pending deltas are non-negative on a cold start, but the
+            # incremental path (repro.stream) injects *signed* correction
+            # deltas after edge deletions — negative mass must propagate too.
+            frontier2 = jnp.abs(state1.delta) > program.tolerance
+        state2, activated2 = _sweep(
+            state1, rt, program, engines2, sched.order, frontier2,
+            config.async_sweep, consume="processed", use_kernels=use_kernels,
+        )
+    with scope(UPDATE):
+        # next frontier
+        activated = activated | activated2
+        if program.peel_k is not None:
+            # removal update: alive vertices whose remaining degree fell
+            # below k are removed now and become the next round's frontier
+            alive = state2.delta < 0.5
+            newly = alive & (state2.values < program.peel_k)
+            next_frontier = newly
+            new_state = HyTMState(
+                values=state2.values,
+                delta=state2.delta + newly.astype(jnp.float32),
+                frontier=next_frontier,
+            )
+        else:
+            if program.combine == MIN:
+                next_frontier = activated
+            else:
+                next_frontier = jnp.abs(state2.delta) > program.tolerance
+            new_state = HyTMState(values=state2.values, delta=state2.delta,
+                                  frontier=next_frontier)
 
-    per_engine_time, mispredictions = selection_diagnostics(
-        plan.engines, plan.transfer_time, stats, plan.costs, correction,
-    )
+    with scope(SELECT):
+        per_engine_time, mispredictions = selection_diagnostics(
+            plan.engines, plan.transfer_time, stats, plan.costs, correction,
+        )
 
-    info = {
-        KEY_ENGINES: plan.engines,
-        KEY_TRANSFER_BYTES: plan.transfer_bytes,
-        KEY_TRANSFER_TIME: jnp.sum(plan.transfer_time)
-        + plan.n_tasks.astype(jnp.float32) * config.link.launch_overhead_s,
-        KEY_N_TASKS: plan.n_tasks,
-        KEY_ACTIVE_VERTICES: jnp.sum(frontier.astype(jnp.int32)),
-        KEY_ACTIVE_EDGES: jnp.sum(stats.active_edges),
-        "next_active": jnp.sum(next_frontier.astype(jnp.int32)),
-        KEY_PER_ENGINE_TIME: per_engine_time,
-        KEY_MISPREDICTIONS: mispredictions,
-    }
-    return new_state, info
+    with scope(UPDATE):
+        info = {
+            KEY_ENGINES: plan.engines,
+            KEY_TRANSFER_BYTES: plan.transfer_bytes,
+            KEY_TRANSFER_TIME: jnp.sum(plan.transfer_time)
+            + plan.n_tasks.astype(jnp.float32) * config.link.launch_overhead_s,
+            KEY_N_TASKS: plan.n_tasks,
+            KEY_ACTIVE_VERTICES: jnp.sum(frontier.astype(jnp.int32)),
+            KEY_ACTIVE_EDGES: jnp.sum(stats.active_edges),
+            "next_active": jnp.sum(next_frontier.astype(jnp.int32)),
+            KEY_PER_ENGINE_TIME: per_engine_time,
+            KEY_MISPREDICTIONS: mispredictions,
+        }
+        return new_state, info
 
 
 # Public per-dispatch entry: one jitted iteration (the K=1 driver and the
@@ -472,9 +485,10 @@ def chunked_while(iter_fn, state: HyTMState, history: dict, chunk: int):
     def body(carry):
         st, hist, i, _prev, pe = carry
         new_st, info = iter_fn(st)
-        hist = {k: hist[k].at[i].set(info[k]) for k in hist}
-        return (new_st, hist, i + 1, info["next_active"],
-                pe + info["per_engine_time"])
+        with scope(UPDATE):
+            hist = {k: hist[k].at[i].set(info[k]) for k in hist}
+            return (new_st, hist, i + 1, info["next_active"],
+                    pe + info["per_engine_time"])
 
     init = (state, history, jnp.int32(0), jnp.int32(1),
             jnp.zeros(3, jnp.float32))
@@ -645,12 +659,18 @@ def count_driver_dispatches():
         counts["chunk"] += 1
         return orig_chunk(*a, **kw)
 
+    # a first dispatch registers the program it ran (obs.scopes), which
+    # has to lower like the real one
+    count_iter.lower, count_chunk.lower = orig_iter.lower, orig_chunk.lower
     mod.hytm_iteration, mod.hytm_chunk = count_iter, count_chunk
     try:
         yield counts
     finally:
         mod.hytm_iteration, mod.hytm_chunk = orig_iter, orig_chunk
 
+
+# the recorder track of the single-device driver's events
+_TRACK = "device0"
 
 # Host-side registry of dispatch signatures that have already compiled:
 # the first dispatch of a given (shapes, program, config) signature pays
@@ -747,13 +767,17 @@ def run_hytm(
     ``GraphService`` keeps one feedback loop across queries.  Only read
     when ``config.autotune`` is set.
 
-    ``obs``: an optional ``repro.obs.TraceRecorder``.  Per-iteration
-    events and per-chunk spans are emitted host-side from the drained
-    history rows (after the existing ``device_get`` syncs) plus one
-    run-summary span whose totals equal the returned ``HyTMResult``
-    fields exactly.  ``obs=None`` (the default) records nothing and runs
-    the identical jit programs — the traced and untraced paths are
-    bit-identical.
+    ``obs``: an optional ``repro.obs.TraceRecorder``.  A single-device
+    run always opens the live spans ``hytm.run`` > ``hytm.init``,
+    ``chunk`` > ``hytm.dispatch`` (``hytm.compile`` when the program is
+    new) / ``hytm.wait`` / ``hytm.drain``, and ``hytm.result`` as
+    profiler annotations (inert without a profile); a recorder records
+    them too, plus per-iteration events from the drained history rows
+    and one run-summary span whose totals equal the returned
+    ``HyTMResult`` fields exactly.  ``obs=None`` (the default) records
+    nothing and runs the identical jit programs — the traced and
+    untraced paths are bit-identical.  A program's first dispatch
+    registers its abstract signature with ``repro.obs.scopes``.
 
     ``faults``/``retry``: an optional ``repro.resilience.FaultPlan`` and
     ``RetryPolicy``.  Injected chunk-dispatch faults (site
@@ -781,42 +805,6 @@ def run_hytm(
         )
     if g is None and runtime is None:
         raise ValueError("run_hytm needs a graph or a prebuilt runtime")
-    if runtime is None and program.symmetrize:
-        # WCC-family programs are defined on the underlying undirected
-        # graph; a prebuilt runtime is assumed already symmetrized
-        g = g.symmetrize()
-    rt = runtime if runtime is not None else build_runtime(
-        g, config, n_hubs=n_hubs,
-        weighted_norm=program.use_delta and program.weighted,
-    )
-    if initial_state is None:
-        if program.peel_k is not None:
-            # peeling seeds from the runtime's (symmetrized) out-degrees,
-            # which init_state cannot see: values = remaining degree,
-            # Δ = removed flag, frontier = the initially-removed set
-            deg = rt.csr.out_degree.astype(jnp.float32)
-            removed = deg < program.peel_k
-            state = HyTMState(values=deg, delta=removed.astype(jnp.float32),
-                              frontier=removed)
-        else:
-            values, delta, frontier = program.init_state(
-                rt.csr.n_nodes, source)
-            state = HyTMState(values=values, delta=delta, frontier=frontier)
-    else:
-        state = initial_state
-
-    calib = None
-    correction = None
-    if config.autotune:
-        from repro.autotune.feedback import OnlineCalibrator
-
-        calib = (calibrator if calibrator is not None
-                 else OnlineCalibrator(decay=config.autotune_decay))
-        # start from the calibrator's current knowledge (identity when
-        # fresh); always an array so the iteration traces once, not
-        # twice (None -> array would retrace on iteration 2)
-        correction = jnp.asarray(calib.correction(), jnp.float32)
-
     # raised (not asserted): under ``python -O`` an assert vanishes and a
     # zero/negative chunk size would silently run the wrong driver
     if config.sync_every < 1:
@@ -825,107 +813,155 @@ def run_hytm(
         raise ValueError(
             "on_chunk (checkpointing) requires the chunked driver — "
             "set sync_every >= 2")
-    rows: dict[str, list] = {k: [] for k in HISTORY_KEYS}
+    with span("hytm.run", obs, track=_TRACK):
+        return _run_single_device(
+            g, program, source, config, n_hubs, runtime, initial_state,
+            calibrator, obs, faults, retry, on_chunk)
+
+
+def _run_single_device(g, program, source, config, n_hubs, runtime,
+                       initial_state, calibrator, obs, faults, retry,
+                       on_chunk) -> HyTMResult:
+    """``run_hytm`` on one device, in the host spans ``hytm.init``,
+    ``hytm.dispatch`` (``hytm.compile`` for a program's first dispatch),
+    ``hytm.wait``, ``hytm.drain`` and ``hytm.result``."""
+    # late imports: both modules import this one
+    from repro.obs.record import record_history_rows, record_run
+    if faults is not None:
+        from repro.resilience.supervisor import guarded_dispatch
+
+    with span("hytm.init", obs, track=_TRACK):
+        if runtime is None and program.symmetrize:
+            # WCC-family programs are defined on the underlying undirected
+            # graph; a prebuilt runtime is assumed already symmetrized
+            g = g.symmetrize()
+        rt = runtime if runtime is not None else build_runtime(
+            g, config, n_hubs=n_hubs,
+            weighted_norm=program.use_delta and program.weighted,
+        )
+        if initial_state is None:
+            if program.peel_k is not None:
+                # peeling seeds from the runtime's (symmetrized)
+                # out-degrees, which init_state cannot see: values =
+                # remaining degree, Δ = removed flag, frontier = the
+                # initially-removed set
+                deg = rt.csr.out_degree.astype(jnp.float32)
+                removed = deg < program.peel_k
+                state = HyTMState(values=deg,
+                                  delta=removed.astype(jnp.float32),
+                                  frontier=removed)
+            else:
+                values, delta, frontier = program.init_state(
+                    rt.csr.n_nodes, source)
+                state = HyTMState(values=values, delta=delta,
+                                  frontier=frontier)
+        else:
+            state = initial_state
+
+        calib = None
+        correction = None
+        if config.autotune:
+            from repro.autotune.feedback import OnlineCalibrator
+
+            calib = (calibrator if calibrator is not None
+                     else OnlineCalibrator(decay=config.autotune_decay))
+            # start from the calibrator's current knowledge (identity when
+            # fresh); always an array so the iteration traces once, not
+            # twice (None -> array would retrace on iteration 2)
+            correction = jnp.asarray(calib.correction(), jnp.float32)
+
+        rows: dict[str, list] = {k: [] for k in HISTORY_KEYS}
+        # the warm signature mirrors the jit cache key: statics + every
+        # shape the trace specializes on (node/edge capacity, partition
+        # grid) — a dispatch not seen here compiles, and its wall time
+        # must not feed the calibrator
+        shapes = (program, config, rt.n_hub_partitions, rt.csr.n_nodes,
+                  rt.csr.edge_src.shape[0], rt.parts.n_partitions,
+                  rt.parts.block_size)
+        if config.sync_every > 1:
+            info_shapes = rt.info_shape_cache.get(shapes)
+            if info_shapes is None:
+                info_shapes = jax.eval_shape(
+                    lambda s: _iteration_impl(
+                        s, rt.csr, rt.parts, rt.zc_req, rt.inv_deg, program,
+                        config, rt.n_hub_partitions, correction,
+                    ),
+                    state,
+                )[1]
+                rt.info_shape_cache[shapes] = info_shapes
+            cur_chunk = min(config.sync_every, config.max_iters)
+            history = init_history_buffers(info_shapes, cur_chunk)
     t0 = time.monotonic()
     iters = 0
     if config.sync_every > 1:
         # Chunked device-resident driver: one hytm_chunk dispatch per K
         # iterations, one host sync per chunk (n_done + history drain).
-        shape_key = (
-            program, config, rt.n_hub_partitions, rt.csr.n_nodes,
-            rt.csr.edge_src.shape[0], rt.parts.n_partitions,
-            rt.parts.block_size,
-        )
-        info_shapes = rt.info_shape_cache.get(shape_key)
-        if info_shapes is None:
-            info_shapes = jax.eval_shape(
-                lambda s: _iteration_impl(
-                    s, rt.csr, rt.parts, rt.zc_req, rt.inv_deg, program,
-                    config, rt.n_hub_partitions, correction,
-                ),
-                state,
-            )[1]
-            rt.info_shape_cache[shape_key] = info_shapes
-        history, cur_chunk = None, -1
         while iters < config.max_iters:
             chunk = min(config.sync_every, config.max_iters - iters)
             if chunk != cur_chunk:
-                # allocated once (and for the rare max_iters tail);
-                # otherwise the drained buffers cycle back in, so on
-                # accelerators the donated memory is reused across chunks
+                # the rare max_iters tail; otherwise the drained buffers
+                # cycle back in, so on accelerators the donated memory is
+                # reused across chunks
                 history = init_history_buffers(info_shapes, chunk)
                 cur_chunk = chunk
-            # the warm signature mirrors the jit cache key: statics +
-            # every shape the trace specializes on (node/edge capacity,
-            # partition grid) — a dispatch not seen here compiles, and
-            # its wall time must not feed the calibrator
-            warm = _consume_warm((
-                "chunk", program, config, rt.n_hub_partitions, chunk,
-                rt.csr.n_nodes, rt.csr.edge_src.shape[0],
-                rt.parts.n_partitions, rt.parts.block_size,
-                correction is not None,
-            ))
-            t_chunk = time.monotonic()
-            if faults is None:
+            signature = ("chunk", *shapes, chunk, correction is not None)
+            warm = _consume_warm(signature)
+
+            # injected faults fire BEFORE the dispatch (see
+            # resilience.supervisor) so the donated buffers of the
+            # previous chunk are intact and a retry is bit-identical
+            def attempt(st=state, h=history, corr=correction):
                 with quiet_donation():
-                    state, history, n_done, last_active, pe_sum = hytm_chunk(
-                        state, history, rt.csr, rt.parts, rt.zc_req,
-                        rt.inv_deg, program, config, rt.n_hub_partitions,
-                        chunk, correction,
+                    return hytm_chunk(
+                        st, h, rt.csr, rt.parts, rt.zc_req, rt.inv_deg,
+                        program, config, rt.n_hub_partitions, chunk, corr,
                     )
-            else:
-                # injected faults fire BEFORE the dispatch (see
-                # resilience.supervisor) so the donated buffers of the
-                # previous chunk are intact and a retry is bit-identical
-                from repro.kernels.runtime import resolve_use_kernels
-                from repro.resilience.supervisor import guarded_dispatch
 
-                def _attempt(st=state, h=history, corr=correction):
-                    with quiet_donation():
-                        return hytm_chunk(
-                            st, h, rt.csr, rt.parts, rt.zc_req,
-                            rt.inv_deg, program, config,
-                            rt.n_hub_partitions, chunk, corr,
-                        )
-
-                state, history, n_done, last_active, pe_sum = (
-                    guarded_dispatch(
-                        _attempt, site="chunk_dispatch", faults=faults,
-                        policy=retry, obs=obs, mesh=False,
-                        kernels=resolve_use_kernels(config.use_kernels),
-                    ))
-            n_done = int(n_done)
-            iters += n_done
-            if calib is not None:
-                # observe BEFORE the history drain so the measured wall
-                # window covers dispatch + execution only
-                correction = calib.observe_chunk(
-                    state.values, np.asarray(pe_sum, dtype=float),
-                    t_chunk,
-                    skip=not warm,  # a compiling chunk measures compile
-                )
-            # drain before the next dispatch donates these buffers; rows
-            # past n_done are stale (early exit) and sliced off
-            drained = jax.device_get(history)
-            for k in rows:
-                rows[k].append(drained[k][:n_done])
-            if obs is not None:
-                from repro.obs.record import record_chunk, record_history_rows
-
-                record_history_rows(obs, drained, n_done, iters - n_done)
-                record_chunk(
-                    obs, track="device0",
-                    wall_start=obs.wall_at(t_chunk),
-                    wall_dur=obs.wall() - obs.wall_at(t_chunk),
-                    start_iter=iters - n_done, n_done=n_done, warm=warm,
-                )
+            with span("chunk", obs, cat=CAT_RUN, track=_TRACK,
+                      vt=float(iters)) as chunk_span:
+                t_chunk = time.monotonic()
+                with span("hytm.dispatch" if warm else "hytm.compile", obs,
+                          track=_TRACK):
+                    if not warm:
+                        scopes.register(
+                            signature, hytm_chunk, state, history, rt.csr,
+                            rt.parts, rt.zc_req, rt.inv_deg, program, config,
+                            rt.n_hub_partitions, chunk, correction)
+                    state, history, n_done, last_active, pe_sum = (
+                        attempt() if faults is None else guarded_dispatch(
+                            attempt, site="chunk_dispatch", faults=faults,
+                            policy=retry, obs=obs, mesh=False,
+                            kernels=resolve_use_kernels(config.use_kernels),
+                        ))
+                with span("hytm.wait", obs, track=_TRACK):
+                    n_done, last_active = int(n_done), int(last_active)
+                iters += n_done
+                if calib is not None:
+                    # observe BEFORE the history drain so the measured
+                    # wall window covers dispatch + execution only
+                    correction = calib.observe_chunk(
+                        state.values, np.asarray(pe_sum, dtype=float),
+                        t_chunk,
+                        skip=not warm,  # a compiling chunk measures compile
+                    )
+                # drain before the next dispatch donates these buffers;
+                # rows past n_done are stale (early exit) and sliced off
+                with span("hytm.drain", obs, track=_TRACK):
+                    drained = jax.device_get(history)
+                    for k in rows:
+                        rows[k].append(drained[k][:n_done])
+                    if obs is not None:
+                        record_history_rows(obs, drained, n_done,
+                                            iters - n_done)
+                        chunk_span.vt_dur = float(n_done)
+                        chunk_span.args.update(n_done=n_done, warm=warm)
             if on_chunk is not None:
                 # chunk boundary: the drained rows are on host and the
                 # next dispatch has not donated the state yet — the one
                 # point a checkpoint can capture a resumable snapshot
                 on_chunk(state=state, iterations=iters, rows=rows,
-                         calibrator=calib, last_active=int(last_active))
-            if int(last_active) == 0:
+                         calibrator=calib, last_active=last_active)
+            if last_active == 0:
                 break
         history = {k: np.concatenate(v) for k, v in rows.items()}
     else:
@@ -934,24 +970,25 @@ def run_hytm(
         # pulled once after convergence — the only per-iteration sync
         # left is the loop condition itself.
         for _ in range(config.max_iters):
+            signature = ("iteration", *shapes, correction is not None)
+            warm = _consume_warm(signature)
             t_iter = time.monotonic()
-            if faults is None:
-                state, info = hytm_iteration(
-                    state, rt.csr, rt.parts, rt.zc_req, rt.inv_deg,
-                    program, config, rt.n_hub_partitions, correction,
+
+            def attempt(st=state, corr=correction):
+                return hytm_iteration(
+                    st, rt.csr, rt.parts, rt.zc_req, rt.inv_deg,
+                    program, config, rt.n_hub_partitions, corr,
                 )
-            else:
-                from repro.kernels.runtime import resolve_use_kernels
-                from repro.resilience.supervisor import guarded_dispatch
 
-                def _attempt(st=state, corr=correction):
-                    return hytm_iteration(
-                        st, rt.csr, rt.parts, rt.zc_req, rt.inv_deg,
-                        program, config, rt.n_hub_partitions, corr,
-                    )
-
-                state, info = guarded_dispatch(
-                    _attempt, site="chunk_dispatch", faults=faults,
+            with span("hytm.dispatch" if warm else "hytm.compile", obs,
+                      track=_TRACK):
+                if not warm:
+                    scopes.register(
+                        signature, hytm_iteration, state, rt.csr, rt.parts,
+                        rt.zc_req, rt.inv_deg, program, config,
+                        rt.n_hub_partitions, correction)
+                state, info = attempt() if faults is None else guarded_dispatch(
+                    attempt, site="chunk_dispatch", faults=faults,
                     policy=retry, obs=obs, mesh=False,
                     kernels=resolve_use_kernels(config.use_kernels),
                 )
@@ -963,34 +1000,34 @@ def run_hytm(
                 )
             for k in rows:
                 rows[k].append(info[k])
-            if int(info["next_active"]) == 0:
+            with span("hytm.wait", obs, track=_TRACK):
+                next_active = int(info["next_active"])
+            if next_active == 0:
                 break
-        staged = jax.device_get(rows)  # one host conversion, post-hoc
-        history = {k: np.stack(v) for k, v in staged.items()}
-        if obs is not None:
-            from repro.obs.record import record_history_rows
-
-            record_history_rows(obs, history, iters, 0)
-    jax.block_until_ready(state.values)
-    wall = time.monotonic() - t0
-    result = HyTMResult(
-        values=np.asarray(state.values),
-        delta=np.asarray(state.delta),
-        iterations=iters,
-        wall_seconds=wall,
-        modeled_seconds=float(np.sum(history[KEY_TRANSFER_TIME])),
-        total_transfer_bytes=float(np.sum(history[KEY_TRANSFER_BYTES])),
-        history=history,
-        total_mispredictions=int(np.sum(history[KEY_MISPREDICTIONS])),
-        engine_corrections=(
-            calib.correction() if calib is not None else None
-        ),
-    )
-    if obs is not None:
-        from repro.obs.record import record_run
-
-        record_run(
-            obs, result, track="device0", wall_start=obs.wall_at(t0),
-            wall_dur=wall, program=program.name,
+        with span("hytm.drain", obs, track=_TRACK):
+            staged = jax.device_get(rows)  # one host conversion, post-hoc
+            history = {k: np.stack(v) for k, v in staged.items()}
+            if obs is not None:
+                record_history_rows(obs, history, iters, 0)
+    with span("hytm.result", obs, track=_TRACK):
+        jax.block_until_ready(state.values)
+        wall = time.monotonic() - t0
+        result = HyTMResult(
+            values=np.asarray(state.values),
+            delta=np.asarray(state.delta),
+            iterations=iters,
+            wall_seconds=wall,
+            modeled_seconds=float(np.sum(history[KEY_TRANSFER_TIME])),
+            total_transfer_bytes=float(np.sum(history[KEY_TRANSFER_BYTES])),
+            history=history,
+            total_mispredictions=int(np.sum(history[KEY_MISPREDICTIONS])),
+            engine_corrections=(
+                calib.correction() if calib is not None else None
+            ),
         )
+        if obs is not None:
+            record_run(
+                obs, result, track=_TRACK, wall_start=obs.wall_at(t0),
+                wall_dur=wall, program=program.name,
+            )
     return result

@@ -116,6 +116,8 @@ from repro.core.scheduler import make_schedule
 from repro.core.task_generation import forced_engine_plan, generate_tasks
 from repro.graph.algorithms import MIN, SUM, VertexProgram
 from repro.graph.csr import CSRGraph
+from repro.obs import span
+from repro.obs.export import CAT_RUN
 
 
 @jax.tree_util.register_dataclass
@@ -1109,57 +1111,51 @@ def run_hytm_sharded(
                  rt.parts.block_size),
                 registry=cached["seen"],
             )
-            t_chunk = time.monotonic()
-            if faults is None:
+            # faults fire BEFORE the shard_mapped dispatch — donated
+            # buffers from the previous chunk stay intact, so a retried
+            # dispatch is bit-identical
+            def attempt(st=state, h=history, ca=corr_arr, fn=cached["fn"]):
                 with quiet_donation():
-                    state, history, n_done, last_active, pe_sum = (
-                        cached["fn"](
-                            state, history, *_runtime_args(rt), corr_arr))
-            else:
-                # faults fire BEFORE the shard_mapped dispatch — donated
-                # buffers from the previous chunk stay intact, so a
-                # retried dispatch is bit-identical
-                from repro.kernels.runtime import resolve_use_kernels
-                from repro.resilience.supervisor import guarded_dispatch
+                    return fn(st, h, *_runtime_args(rt), ca)
 
-                def _attempt(st=state, h=history, ca=corr_arr,
-                             fn=cached["fn"]):
-                    with quiet_donation():
-                        return fn(st, h, *_runtime_args(rt), ca)
+            with span("chunk", obs, cat=CAT_RUN, track="mesh",
+                      vt=float(iters)) as chunk_span:
+                t_chunk = time.monotonic()
+                if faults is None:
+                    out = attempt()
+                else:
+                    from repro.resilience.supervisor import guarded_dispatch
 
-                state, history, n_done, last_active, pe_sum = (
-                    guarded_dispatch(
-                        _attempt, site="chunk_dispatch", faults=faults,
+                    out = guarded_dispatch(
+                        attempt, site="chunk_dispatch", faults=faults,
                         policy=retry, obs=obs, mesh=True,
                         kernels=resolve_use_kernels(config.use_kernels),
-                    ))
-            n_done = int(n_done)
-            iters += n_done
-            if calib is not None:
-                # observe BEFORE the drain + ICI loop: the measured wall
-                # window covers dispatch + execution only
-                corr_arr = calib.observe_chunk(
-                    state.values, np.asarray(pe_sum, dtype=float),
-                    t_chunk, skip=not warm,
-                )
-            # drain BEFORE the next dispatch donates these buffers
-            drained = jax.device_get(history)
-            for me in drained[KEY_MERGED_ENTRIES][:n_done]:
-                charge_ici(me)  # charged under the chunk's correction
-            if calib is not None:
-                corr_np = np.asarray(corr_arr, dtype=float)
-            for k in rows:
-                rows[k].append(drained[k][:n_done])
-            if obs is not None:
-                from repro.obs.record import record_chunk, record_history_rows
+                    )
+                state, history, n_done, last_active, pe_sum = out
+                n_done = int(n_done)
+                iters += n_done
+                if calib is not None:
+                    # observe BEFORE the drain + ICI loop: the measured
+                    # wall window covers dispatch + execution only
+                    corr_arr = calib.observe_chunk(
+                        state.values, np.asarray(pe_sum, dtype=float),
+                        t_chunk, skip=not warm,
+                    )
+                # drain BEFORE the next dispatch donates these buffers
+                drained = jax.device_get(history)
+                for me in drained[KEY_MERGED_ENTRIES][:n_done]:
+                    charge_ici(me)  # charged under the chunk's correction
+                if calib is not None:
+                    corr_np = np.asarray(corr_arr, dtype=float)
+                for k in rows:
+                    rows[k].append(drained[k][:n_done])
+                if obs is not None:
+                    from repro.obs.record import record_history_rows
 
-                record_history_rows(
-                    obs, drained, n_done, iters - n_done, track="mesh")
-                record_chunk(
-                    obs, track="mesh", wall_start=obs.wall_at(t_chunk),
-                    wall_dur=obs.wall() - obs.wall_at(t_chunk),
-                    start_iter=iters - n_done, n_done=n_done, warm=warm,
-                )
+                    record_history_rows(
+                        obs, drained, n_done, iters - n_done, track="mesh")
+                    chunk_span.vt_dur = float(n_done)
+                    chunk_span.args.update(n_done=n_done, warm=warm)
             if on_chunk is not None:
                 on_chunk(state=state, iterations=iters, rows=rows,
                          calibrator=calib, last_active=int(last_active))
@@ -1178,7 +1174,6 @@ def run_hytm_sharded(
                 state, info = iteration(
                     state, *_runtime_args(rt), correction)
             else:
-                from repro.kernels.runtime import resolve_use_kernels
                 from repro.resilience.supervisor import guarded_dispatch
 
                 def _attempt(st=state, corr=correction):

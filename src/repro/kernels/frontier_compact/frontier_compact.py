@@ -109,6 +109,7 @@ def frontier_compact_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((r, m_pad + LANES), jnp.int32),
         interpret=interpret,
+        name="frontier_compact_pallas",
     )(offs, dest, bits)
     out = jax.lax.bitcast_convert_type(out[:c, :m].T, values.dtype)
     return out, offs[-1]

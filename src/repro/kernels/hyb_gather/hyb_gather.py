@@ -75,6 +75,7 @@ def hyb_gather_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((a, r, PAD), jnp.int32),
         interpret=interpret,
+        name="hyb_gather_pallas",
     )(seg_start.astype(jnp.int32), degree.astype(jnp.int32), bits)
     return jax.lax.bitcast_convert_type(
         jnp.swapaxes(out[:, :c], 1, 2), edges.dtype)

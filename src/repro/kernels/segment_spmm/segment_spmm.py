@@ -43,6 +43,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.obs.scopes import FILTER_ORDER, scope
+
 LANES = 128    # edges per row (one lane width)
 TILE_N = 128   # output segments per block (sublanes of the routing tile)
 
@@ -96,22 +98,23 @@ def segment_spmm_pallas(
         raise ValueError(
             f"segment_spmm packs (block, edge) into a 31-bit key; got "
             f"{n_blocks} blocks x {m} edges")
-    seg_ids = seg_ids.astype(jnp.int32)
-    routed = valid & (seg_ids >= 0) & (seg_ids < n_segments)
-    block = jnp.where(routed, seg_ids // TILE_N, n_blocks)
-    # the keys are unique, so an unstable sort (much faster to compile) is exact
-    key = jax.lax.sort((block << idx_bits) | jnp.arange(m, dtype=jnp.int32),
-                       is_stable=False)
-    order = key & ((1 << idx_bits) - 1)
-    bounds = jnp.searchsorted(
-        key >> idx_bits, jnp.arange(n_blocks + 1, dtype=jnp.int32)).astype(jnp.int32)
-    first = bounds[:-1] // LANES
-    last = jnp.where(bounds[1:] > bounds[:-1], -(-bounds[1:] // LANES), first)
+    with scope(FILTER_ORDER):  # all but the Pallas call
+        seg_ids = seg_ids.astype(jnp.int32)
+        routed = valid & (seg_ids >= 0) & (seg_ids < n_segments)
+        block = jnp.where(routed, seg_ids // TILE_N, n_blocks)
+        # the keys are unique, so an unstable sort (much faster to compile) is exact
+        key = jax.lax.sort((block << idx_bits) | jnp.arange(m, dtype=jnp.int32),
+                           is_stable=False)
+        order = key & ((1 << idx_bits) - 1)
+        bounds = jnp.searchsorted(
+            key >> idx_bits, jnp.arange(n_blocks + 1, dtype=jnp.int32)).astype(jnp.int32)
+        first = bounds[:-1] // LANES
+        last = jnp.where(bounds[1:] > bounds[:-1], -(-bounds[1:] // LANES), first)
 
-    seg = jnp.where(routed, seg_ids, n_pad)[order]
-    seg = jnp.pad(seg, (0, m_pad - m), constant_values=n_pad).reshape(rows, LANES)
-    msg = messages.astype(jnp.float32)[order].T
-    msg = jnp.pad(msg, ((0, 0), (0, m_pad - m))).reshape(d, rows, LANES)
+        seg = jnp.where(routed, seg_ids, n_pad)[order]
+        seg = jnp.pad(seg, (0, m_pad - m), constant_values=n_pad).reshape(rows, LANES)
+        msg = messages.astype(jnp.float32)[order].T
+        msg = jnp.pad(msg, ((0, 0), (0, m_pad - m))).reshape(d, rows, LANES)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -128,5 +131,7 @@ def segment_spmm_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_pad, d), jnp.float32),
         interpret=interpret,
+        name="segment_spmm_pallas",
     )(first, last, seg, msg)
-    return out[:n_segments].astype(messages.dtype)
+    with scope(FILTER_ORDER):
+        return out[:n_segments].astype(messages.dtype)

@@ -1,34 +1,47 @@
-"""repro.obs — unified tracing, metrics, and trace export.
+"""repro.obs — spans, device scopes, metrics and trace export.
 
 One observability layer across the engine (``core.hytm``), mesh
 (``dist.graph_shard``), streaming (``stream.service``), and serving
 (``serve.scheduler`` / ``serve.warm_cache``) stacks:
 
-* :class:`TraceRecorder` — host-side span/event ring with virtual-clock
-  *and* wall-clock timestamps (``trace.py``);
+* :func:`span` — a live host span: a ``jax.profiler.TraceAnnotation``
+  on the profiler's clock, and, given a :class:`TraceRecorder`, a span
+  in its ring too (``trace.py``);
+* ``scopes`` — the ``jax.named_scope`` names of the engine's layers,
+  and ``op_scopes()``, the map from each compiled instruction to its
+  scope that a device-trace reader needs (``scopes.py``);
+* :class:`TraceRecorder` — span/instant/counter ring with virtual-clock
+  *and* wall-clock timestamps;
 * :class:`MetricsRegistry` — labeled counter/gauge/histogram registry
-  unifying the per-engine bytes/time, ICI pick, misprediction,
-  admission, cache-tier and lane-occupancy counters (``metrics.py``);
+  for picks, modelled bytes and seconds, mispredictions, admission,
+  cache tiers and lane occupancy (``metrics.py``);
 * ``export`` — Chrome trace-event JSON (``chrome://tracing`` /
-  Perfetto), JSONL streaming, and a ``summary()``/``reconcile()`` that
-  cross-checks the trace against ``HyTMResult`` totals exactly.
+  Perfetto) and a ``summary()``/``reconcile()`` that cross-checks the
+  recorder against ``HyTMResult`` totals exactly.
 
-Contract: host-side only (events come from drained chunk history and
-scheduler/cache callbacks, never from inside jit-traced code);
-zero-overhead when disabled (every instrumentation site guards on
-``obs is not None``, so the untraced path is bit-identical); every event
-carries both clocks.  Gated by ``benchmarks/obs_bench.py --selfcheck``.
+Contract:
+
+* scopes are metadata only: they set the ``op_name`` of the ops traced
+  inside them and never change a compiled op, so they are always on;
+* spans are always emitted, and inert without a running profile or a
+  recorder (about a microsecond each);
+* instants, counters and metrics come from drained chunk history and
+  scheduler/cache callbacks, never from inside jit-traced code, so
+  ``obs=None`` and a recorder run bit-identical programs.
+
+Counters named ``modeled_*`` come from the cost model (PCIe-3 by
+default), not from the device; measured times come only from a device
+trace.  Gated by ``benchmarks/obs_bench.py --selfcheck``.
 """
 
 from repro.obs.metrics import Counter, Gauge, Histogram, MetricsRegistry
-from repro.obs.trace import NullRecorder, TraceEvent, TraceRecorder
+from repro.obs.trace import TraceEvent, TraceRecorder, span
 from repro.obs.export import (
     reconcile,
     summary,
     to_chrome_trace,
     validate_chrome_trace,
     write_chrome_trace,
-    write_jsonl,
 )
 
 __all__ = [
@@ -36,13 +49,12 @@ __all__ = [
     "Gauge",
     "Histogram",
     "MetricsRegistry",
-    "NullRecorder",
     "TraceEvent",
     "TraceRecorder",
     "reconcile",
+    "span",
     "summary",
     "to_chrome_trace",
     "validate_chrome_trace",
     "write_chrome_trace",
-    "write_jsonl",
 ]

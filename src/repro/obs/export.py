@@ -1,6 +1,6 @@
 """Trace export + reconciliation for ``repro.obs``.
 
-Three output forms:
+Two output forms:
 
 * :func:`to_chrome_trace` / :func:`write_chrome_trace` — Chrome
   trace-event JSON (the ``{"traceEvents": [...]}`` object format)
@@ -8,8 +8,6 @@ Three output forms:
   track (device, lane, tenant) becomes its own thread row; spans lay out
   on the wall clock (microseconds) and carry the virtual clock in
   ``args``.
-* :func:`write_jsonl` — one JSON object per event, for streaming
-  consumers.
 * :func:`summary` / :func:`reconcile` — host-side rollups.
   ``reconcile`` cross-checks the trace's run-span totals against the
   ``HyTMResult`` accounting (iterations, transfer bytes, modeled
@@ -136,22 +134,6 @@ def write_chrome_trace(rec: TraceRecorder, path: str) -> dict[str, Any]:
         json.dump(doc, f)
         f.write("\n")
     return doc
-
-
-def write_jsonl(rec: TraceRecorder, path: str) -> int:
-    """One JSON object per recorded event (the streaming form); returns
-    the number of lines written."""
-    n = 0
-    with open(path, "w") as f:
-        for ev in rec.events:
-            f.write(json.dumps({
-                "name": ev.name, "ph": ev.ph, "cat": ev.cat,
-                "track": ev.track, "wall": ev.wall, "wall_dur": ev.wall_dur,
-                "vt": ev.vt, "vt_dur": ev.vt_dur, "args": ev.args,
-            }))
-            f.write("\n")
-            n += 1
-    return n
 
 
 def summary(rec: TraceRecorder) -> dict[str, Any]:

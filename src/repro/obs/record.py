@@ -47,7 +47,8 @@ def record_history_rows(
     index of row 0 (the virtual-clock timestamp)."""
     m = obs.metrics
     picks = m.counter("engine.picks", "Algorithm-1 engine selections")
-    bytes_c = m.counter("engine.bytes", "modeled host->device transfer bytes")
+    bytes_c = m.counter("engine.modeled_bytes",
+                        "modeled host->device transfer bytes")
     secs_c = m.counter("engine.modeled_seconds", "modeled per-engine seconds")
     iters_c = m.counter("engine.iterations", "executed sweep iterations")
     mis_c = m.counter("engine.mispredictions",
@@ -89,20 +90,6 @@ def record_history_rows(
             mispredictions=int(mis[k]),
             picks=pick_counts,
         )
-        obs.counter("frontier", float(av[k]), track=track, vt=vt)
-
-
-def record_chunk(
-    obs: Any, *, track: str, wall_start: float, wall_dur: float,
-    start_iter: int, n_done: int, warm: bool,
-) -> None:
-    """One span per chunk dispatch: wall window = dispatch + execution +
-    drain, virtual window = the iterations the chunk executed."""
-    obs.span(
-        "chunk", cat=CAT_RUN, track=track, wall=wall_start,
-        wall_dur=wall_dur, vt=float(start_iter), vt_dur=float(n_done),
-        n_done=int(n_done), warm=bool(warm),
-    )
 
 
 def record_ici(
@@ -114,11 +101,11 @@ def record_ici(
     all-reduce pick), plus the unified ICI metrics.  ``halo_entries`` is
     set on owner-sharded runs: the boundary entries a compacted exchange
     would actually ship (``merged_entries`` capped at the runtime's
-    ``HaloPlan.halo_total``), surfaced as the ``ici.halo_bytes``
+    ``HaloPlan.halo_total``), surfaced as the ``ici.modeled_halo_bytes``
     counter (8 B per entry, matching ``halo_level_cost``)."""
     name = ENGINE_NAMES.get(int(engine), str(int(engine)))
     m = obs.metrics
-    m.counter("ici.bytes", "modeled cross-device merge bytes").inc(
+    m.counter("ici.modeled_bytes", "modeled cross-device merge bytes").inc(
         float(bytes_), engine=name)
     m.counter("ici.picks", "ICI exchange-level engine picks").inc(
         1, engine=name)
@@ -127,7 +114,7 @@ def record_ici(
     extra = {}
     if halo_entries is not None:
         m.counter(
-            "ici.halo_bytes",
+            "ici.modeled_halo_bytes",
             "compacted owner-halo exchange bytes (8 B/boundary entry)",
         ).inc(float(halo_entries) * 8.0, engine=name)
         extra["halo_entries"] = float(halo_entries)

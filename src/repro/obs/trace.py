@@ -1,23 +1,23 @@
-"""Host-side span/event recorder — the core of ``repro.obs``.
+"""Span/event recorder and the live span helper — the core of ``repro.obs``.
 
 Contract (ROADMAP module map):
 
-* **host-side only** — events are emitted from drained chunk history and
-  scheduler/cache callbacks, never inside jit-traced code.  Nothing in
-  this module ever touches a ``jax.Array`` that has not already been
-  fetched to host, so recording cannot perturb compilation, donation, or
-  dispatch of the runs it observes.
-* **zero-overhead disabled** — every instrumentation site threads an
-  ``obs`` parameter that defaults to ``None`` and guards emission with
-  ``if obs is not None``; the untraced path executes the exact same jit
-  programs and is bit-identical by construction (``benchmarks/obs_bench
-  --selfcheck`` proves it anyway).  ``NullRecorder`` exists for callers
-  that prefer an always-valid object over a ``None`` guard.
-* **virtual + wall clocks** — every event carries both a virtual-clock
-  timestamp (engine iterations, the serving stack's deterministic time
-  base) and a wall-clock timestamp (seconds since the recorder's
-  creation).  The Chrome export lays spans out on the wall clock and
-  keeps the virtual clock in ``args``.
+* **one span system, two sinks** — :func:`span` opens a
+  ``jax.profiler.TraceAnnotation``, which a running profile puts on the
+  device trace's clock and which costs about a microsecond without one;
+  given a :class:`TraceRecorder`, it also records the span there.
+  ``TraceRecorder.timed`` is the same helper.  Spans are always
+  emitted, and inert without a profile or a recorder.
+* **events outside jit** — instants and counters come from drained
+  chunk history and scheduler/cache callbacks, never from inside
+  jit-traced code, so a recorder cannot perturb compilation, donation
+  or dispatch of the runs it observes; ``obs=None`` runs the identical
+  jit programs.
+* **virtual + wall clocks** — every recorded event carries a
+  virtual-clock timestamp (engine iterations, the serving stack's
+  deterministic time base) and a wall-clock timestamp (seconds since
+  the recorder's creation).  The Chrome export lays spans out on the
+  wall clock and keeps the virtual clock in ``args``.
 
 The event buffer is a bounded ring (``capacity`` events): a runaway
 producer overwrites the oldest events and increments ``dropped`` instead
@@ -31,6 +31,8 @@ import contextlib
 import dataclasses
 import time
 from typing import Any, Iterator
+
+import jax
 
 from repro.obs.metrics import MetricsRegistry
 
@@ -69,8 +71,6 @@ class TraceRecorder:
     from drain loops (one call per iteration row, not per vertex), and
     never called from inside traced code.
     """
-
-    enabled = True
 
     def __init__(self, capacity: int = DEFAULT_CAPACITY):
         self.capacity = int(capacity)
@@ -124,69 +124,35 @@ class TraceRecorder:
         self._push(TraceEvent(name, PH_COUNTER, cat, track, w, vt,
                               args={"value": float(value)}))
 
-    @contextlib.contextmanager
-    def timed(
-        self, name: str, *, cat: str = "host", track: str = "main",
-        vt: float = 0.0, vt_dur: float = 0.0, **args: Any,
-    ) -> Iterator[dict[str, Any]]:
-        """Context manager recording a wall-timed span around its body.
-
-        Yields the span's ``args`` dict so the body can attach results
-        (bytes moved, iterations run) discovered while the span is open.
-        """
-        t0 = self.wall()
-        try:
-            yield args
-        finally:
-            self.span(name, cat=cat, track=track, wall=t0,
-                      wall_dur=self.wall() - t0, vt=vt, vt_dur=vt_dur, **args)
+    def timed(self, name: str, **kw: Any):
+        """:func:`span` into this recorder."""
+        return span(name, self, **kw)
 
     # -- views -----------------------------------------------------------
-    def drain(self) -> list[TraceEvent]:
-        """Snapshot-and-clear the event ring (for streaming JSONL export)."""
-        out = list(self.events)
-        self.events.clear()
-        return out
-
     def __len__(self) -> int:
         return len(self.events)
 
 
-class NullRecorder:
-    """API-compatible no-op recorder.  Instrumentation sites normally
-    guard with ``if obs is not None`` (so the disabled path pays nothing,
-    not even a method call); this class exists for callers that want to
-    pass a recorder unconditionally."""
+def span(
+    name: str, obs: TraceRecorder | None = None, *, cat: str = "host",
+    track: str = "main", vt: float = 0.0, **args: Any,
+):
+    """A live span around the body: a profiler annotation always, and a
+    recorded span when ``obs`` is given.  With ``obs``, the context
+    yields the event it will record, so the body can set ``vt_dur`` and
+    ``args`` it learns while the span is open."""
+    if obs is None:
+        return jax.profiler.TraceAnnotation(name)
+    return _recorded_span(
+        obs, TraceEvent(name, PH_SPAN, cat, track, 0.0, vt, args=args))
 
-    enabled = False
-    dropped = 0
-    capacity = 0
 
-    def __init__(self):
-        self.events: collections.deque[TraceEvent] = collections.deque(maxlen=0)
-        self.metrics = MetricsRegistry()
-
-    def wall(self) -> float:
-        return 0.0
-
-    def wall_at(self, t_monotonic: float) -> float:
-        return 0.0
-
-    def span(self, name: str, **kw: Any) -> None:
-        pass
-
-    def instant(self, name: str, **kw: Any) -> None:
-        pass
-
-    def counter(self, name: str, value: float, **kw: Any) -> None:
-        pass
-
-    @contextlib.contextmanager
-    def timed(self, name: str, **kw: Any) -> Iterator[dict[str, Any]]:
-        yield {}
-
-    def drain(self) -> list[TraceEvent]:
-        return []
-
-    def __len__(self) -> int:
-        return 0
+@contextlib.contextmanager
+def _recorded_span(obs: TraceRecorder, ev: TraceEvent) -> Iterator[TraceEvent]:
+    with jax.profiler.TraceAnnotation(ev.name):
+        ev.wall = obs.wall()
+        try:
+            yield ev
+        finally:
+            ev.wall_dur = obs.wall() - ev.wall
+            obs._push(ev)

@@ -1,7 +1,10 @@
 """repro.obs: recorder semantics, chrome-trace schema, zero-overhead
-no-op contract, and exact metrics-vs-HyTMResult reconciliation."""
+no-op contract, exact metrics-vs-HyTMResult reconciliation, live spans
+on the profiler's clock, and the map from compiled ops to device
+scopes."""
 
 import json
+import re
 
 import numpy as np
 import pytest
@@ -14,15 +17,14 @@ from repro.obs import (
     Gauge,
     Histogram,
     MetricsRegistry,
-    NullRecorder,
     TraceRecorder,
     reconcile,
     summary,
     to_chrome_trace,
     validate_chrome_trace,
     write_chrome_trace,
-    write_jsonl,
 )
+from repro.obs import scopes, span
 
 CFG = HyTMConfig(n_partitions=8, sync_every=4)
 CFG1 = HyTMConfig(n_partitions=8, sync_every=1)
@@ -45,28 +47,6 @@ def test_recorder_ring_is_bounded():
     assert rec.dropped == 6
     # oldest events fell off the ring; the survivors are the newest
     assert [e.vt for e in rec.events] == [6.0, 7.0, 8.0, 9.0]
-
-
-def test_recorder_drain_empties_and_preserves_order():
-    rec = TraceRecorder()
-    rec.span("s", wall=0.1, wall_dur=0.2)
-    rec.instant("i", vt=1.0)
-    rec.counter("c", 3.0)
-    drained = rec.drain()
-    assert [e.name for e in drained] == ["s", "i", "c"]
-    assert [e.ph for e in drained] == ["X", "i", "C"]
-    assert len(rec) == 0
-
-
-def test_null_recorder_is_inert():
-    rec = NullRecorder()
-    rec.span("s", wall=0.0)
-    rec.instant("i")
-    rec.counter("c", 1.0)
-    with rec.timed("t"):
-        pass
-    assert len(rec) == 0 and not rec.enabled
-    assert rec.drain() == []
 
 
 def test_metrics_registry():
@@ -144,10 +124,7 @@ def test_write_chrome_trace_and_jsonl(tmp_path):
     write_chrome_trace(rec, str(p))
     doc = json.loads(p.read_text())
     validate_chrome_trace(doc)
-    pj = tmp_path / "trace.jsonl"
-    write_jsonl(rec, str(pj))
-    lines = [json.loads(l) for l in pj.read_text().splitlines()]
-    assert lines and lines[0]["name"] == "e"
+    assert [e["name"] for e in doc["traceEvents"] if e["ph"] != "M"] == ["e"]
 
 
 # --------------------------------------------------------------------------
@@ -171,13 +148,6 @@ def test_traced_run_bit_identical_and_reconciles(graph, cfg):
     assert rep["checks"]["iterations"]["trace"] == traced.iterations
     assert (rep["checks"]["transfer_bytes"]["trace"]
             == traced.total_transfer_bytes)
-
-
-def test_null_recorder_matches_none(graph):
-    a = run_hytm(graph, SSSP, source=0, config=CFG, obs=None)
-    b = run_hytm(graph, SSSP, source=0, config=CFG, obs=NullRecorder())
-    np.testing.assert_array_equal(a.values, b.values)
-    assert a.iterations == b.iterations
 
 
 def test_span_nesting_invariants(graph):
@@ -212,7 +182,7 @@ def test_metrics_match_result_accounting(graph):
     assert m.get("engine.iterations").total() == res.iterations
     # per-engine byte counters sum to the result's transfer total
     # (float64 row-sum accumulation; exact for these magnitudes)
-    assert m.get("engine.bytes").total() == res.total_transfer_bytes
+    assert m.get("engine.modeled_bytes").total() == res.total_transfer_bytes
     assert (m.get("engine.mispredictions").total()
             == res.total_mispredictions)
     picks = m.get("engine.picks")
@@ -230,3 +200,204 @@ def test_reconcile_detects_mismatch(graph):
     run_hytm(graph, SSSP, source=0, config=CFG, obs=rec)
     rep = reconcile(rec, res)
     assert not rep["ok"]
+
+
+# --------------------------------------------------------------------------
+# live spans on the profiler's clock
+# --------------------------------------------------------------------------
+
+PHASES = ("hytm.init", "hytm.compile", "hytm.dispatch", "hytm.wait",
+          "hytm.drain", "hytm.result")
+
+
+def _profiled(tmp_path, body):
+    """Host events of the thread that ran ``body`` under a jax.profiler
+    trace, as (name, start_ns, end_ns)."""
+    import jax
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        with jax.profiler.TraceAnnotation("caller"):
+            body()
+    finally:
+        jax.profiler.stop_trace()
+    path = sorted(tmp_path.rglob("*.xplane.pb"))[-1]
+    for plane in jax.profiler.ProfileData.from_file(str(path)).planes:
+        for line in plane.lines:
+            events = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+                      for e in line.events]
+            if any(name == "caller" for name, _, _ in events):
+                return events
+    raise AssertionError("no host line holds the caller's annotation")
+
+
+def _inside(inner, outer):
+    return outer[1] <= inner[1] and inner[2] <= outer[2]
+
+
+@pytest.mark.parametrize("sync_every", [4, 1], ids=["chunked", "K=1"])
+def test_profiler_trace_holds_driver_spans(graph, tmp_path, sync_every):
+    # a config no other test dispatches, so the first run compiles
+    cfg = HyTMConfig(n_partitions=8, sync_every=sync_every, max_iters=9_001)
+    events = _profiled(tmp_path, lambda: [
+        run_hytm(graph, SSSP, source=0, config=cfg) for _ in range(2)])
+    caller = next(e for e in events if e[0] == "caller")
+    runs = [e for e in events if e[0] == "hytm.run"]
+    assert len(runs) == 2 and all(_inside(r, caller) for r in runs)
+    for run, first in zip(runs, (True, False)):
+        names = [e[0] for e in events if e[0] in PHASES and _inside(e, run)]
+        assert names[0] == "hytm.init" and names[-1] == "hytm.result"
+        assert {"hytm.wait", "hytm.drain"} <= set(names)
+        assert ("hytm.compile" in names) == first
+        assert "hytm.dispatch" in names or first
+
+
+def test_timed_writes_a_profiler_span(tmp_path):
+    rec = TraceRecorder()
+
+    def body():
+        with rec.timed("probe", track="t", vt=3.0) as ev:
+            ev.vt_dur = 2.0
+            ev.args["rows"] = 7
+
+    events = _profiled(tmp_path, body)
+    probe = [e for e in events if e[0] == "probe"]
+    assert len(probe) == 1 and _inside(probe[0], next(
+        e for e in events if e[0] == "caller"))
+    (recorded,) = rec.events
+    assert (recorded.name, recorded.ph, recorded.track) == ("probe", "X", "t")
+    assert (recorded.vt, recorded.vt_dur, recorded.args) == (3.0, 2.0, {"rows": 7})
+    assert recorded.wall_dur >= 0.0
+
+
+def test_span_records_only_with_a_recorder():
+    with span("bare"):  # a profiler annotation alone
+        pass
+    rec = TraceRecorder()
+    with span("outer", rec, track="t"):
+        with span("inner", rec, track="t", n=1):
+            pass
+    # spans are pushed as they close: the inner one first
+    assert [e.name for e in rec.events] == ["inner", "outer"]
+    inner, outer = rec.events
+    assert inner.args == {"n": 1}
+    assert outer.wall <= inner.wall
+    assert inner.wall + inner.wall_dur <= outer.wall + outer.wall_dur
+
+
+def test_modeled_counters_say_so():
+    from repro.obs.record import record_ici
+
+    rec = TraceRecorder()
+    record_ici(rec, track="mesh", it=0, bytes_=96.0, seconds=1e-6, engine=0,
+               merged_entries=12.0, halo_entries=5.0)
+    m = rec.metrics
+    assert m.get("ici.modeled_bytes").total() == 96.0
+    assert m.get("ici.modeled_halo_bytes").total() == 40.0
+    assert m.get("ici.bytes") is None and m.get("ici.halo_bytes") is None
+
+
+# --------------------------------------------------------------------------
+# device scopes: compiled op -> innermost scope
+# --------------------------------------------------------------------------
+
+# ops that move no data through the device's compute: loop and tuple
+# plumbing, and constants
+PLUMBING = ("parameter", "get-tuple-element", "tuple", "constant", "bitcast",
+            "copy")
+
+
+def _computation(text, name):
+    """The instruction lines of computation ``name`` in HLO text."""
+    lines = text.splitlines()
+    start = next(i for i, line in enumerate(lines)
+                 if re.match(rf"^(ENTRY )?%{re.escape(name)} ", line))
+    end = next(i for i in range(start, len(lines)) if lines[i] == "}")
+    return [line.strip().removeprefix("ROOT ") for line in lines[start + 1:end]]
+
+
+@pytest.mark.parametrize("sync_every", [4, 1], ids=["chunked", "K=1"])
+def test_op_scopes_cover_the_program(sync_every):
+    """At scale 10 with the kernels (interpret mode here), the program a
+    run dispatched maps to every scope, and >= 95 % of the work in its
+    loop body (the chunk's while body; the iteration's entry for K=1)
+    maps to one.  Its registered signature compiles to the program the
+    concrete arguments compile to."""
+    import jax
+
+    from repro.core.cost_model import init_history_buffers
+    from repro.core.hytm import (
+        HyTMState, _iteration_impl, build_runtime, hytm_chunk, hytm_iteration)
+
+    g = rmat_graph(1 << 10, 16 << 10, seed=3)
+    cfg = HyTMConfig(n_partitions=8, sync_every=sync_every, use_kernels=True,
+                     max_iters=9_002)
+    rt = build_runtime(g, cfg)
+    run_hytm(g, SSSP, source=0, config=cfg, runtime=rt)
+    got = scopes.op_scopes()
+    assert set(scopes.SCOPES) <= {s for _, s in got}
+
+    state = HyTMState(*SSSP.init_state(g.n_nodes, 0))
+    args = (rt.csr, rt.parts, rt.zc_req, rt.inv_deg, SSSP, cfg,
+            rt.n_hub_partitions)
+    if sync_every > 1:
+        info = jax.eval_shape(lambda s: _iteration_impl(s, *args)[1], state)
+        text = hytm_chunk.lower(state, init_history_buffers(info, sync_every),
+                                *args, sync_every).compile().as_text()
+        entry = _computation(text, re.search(r"ENTRY %(\S+) ", text).group(1))
+        outer = next(line for line in entry if " while(" in line)
+        body = _computation(text, re.search(r"body=%([\w.-]+)", outer).group(1))
+    else:
+        text = hytm_iteration.lower(state, *args).compile().as_text()
+        body = _computation(text, re.search(r"ENTRY %(\S+) ", text).group(1))
+    mine = dict(scopes.instruction_scopes(text))
+    assert set(mine.items()) <= set(got)
+    work = [line for line in (scopes._TAIL.split(b, maxsplit=1)[0] for b in body)
+            if re.search(r"\s([a-z][\w-]*)\(", line.split(" = ", 1)[1]).group(1)
+            not in PLUMBING]
+    scoped = [line for line in work if mine[line] is not None]
+    assert len(work) > 20 and len(scoped) >= 0.95 * len(work)
+
+
+def test_instruction_scopes_fall_back_to_fused_root_and_users():
+    text = """HloModule m
+
+%fused_computation.1 (p: f32[8]) -> f32[8] {
+  %p = f32[8]{0} parameter(0)
+  ROOT %sort.1 = f32[8]{0} sort(%p), metadata={op_name="jit(f)/sweep/engine.filter/jit(g)/filter.order/sort"}
+}
+
+ENTRY %main.9 (a: f32[8]) -> (f32[8], f32[8]) {
+  %a = f32[8]{0} parameter(0)
+  %broadcast.2 = f32[8]{0} broadcast(%constant.1), dimensions={}
+  %fusion.3 = f32[8]{0} fusion(%a), kind=kLoop, calls=%fused_computation.1
+  %add.4 = f32[8]{0} add(%fusion.3, %broadcast.2), metadata={op_name="jit(f)/while/body/update/add"}
+  %custom-call.5 = f32[8]{0} custom-call(%add.4), custom_call_target="tpu_custom_call", metadata={op_name="jit(f)/sweep/engine.filter/pallas_call"}
+  ROOT %tuple.6 = (f32[8]{0}, f32[8]{0}) tuple(%add.4, %custom-call.5)
+}
+"""
+    got = dict(scopes.instruction_scopes(text))
+    assert got == {
+        "%a = f32[8]{0} parameter(0)": "filter.order",
+        "%broadcast.2 = f32[8]{0} broadcast(%constant.1), dimensions={}": "update",
+        "%fusion.3 = f32[8]{0} fusion(%a), kind=kLoop, calls=%fused_computation.1":
+            "filter.order",
+        "%add.4 = f32[8]{0} add(%fusion.3, %broadcast.2)": "update",
+        "%custom-call.5 = f32[8]{0} custom-call(%add.4)": "engine.filter",
+        "%tuple.6 = (f32[8]{0}, f32[8]{0}) tuple(%add.4, %custom-call.5)": None,
+    }
+
+
+def test_scope_names_and_nesting():
+    assert scopes.innermost("jit(f)/while/body/sweep/engine.filter/"
+                            "jit(segment_spmm_pallas)/filter.order/sort") == "filter.order"
+    assert scopes.innermost("jit(f)/while/cond/lt") is None
+    assert scopes.within("filter.order", "engine.filter")
+    assert scopes.within("filter.order", "sweep")
+    assert not scopes.within("sweep.block", "engine.filter")
+    assert not scopes.within(None, "sweep")
+    assert all(scopes.within(e, "sweep") for e in scopes.ENGINE_SCOPES)
+    with pytest.raises(KeyError):
+        scopes.scope("sweep.nope")

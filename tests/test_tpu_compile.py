@@ -78,3 +78,39 @@ def test_hyb_gather_compiles_for_v5e(shape):
         lambda e, s, d: hyb_gather_pallas(e, s, d, interpret=False),
         shape((BLOCK, 4), jnp.float32), shape((windows,), jnp.int32),
         shape((windows,), jnp.int32))
+
+
+def test_chunk_program_names_its_kernels_and_scopes_for_v5e(topo, shape, monkeypatch):
+    """The whole chunk program as the chip compiles it: each Pallas call
+    keeps the name the device-trace metrics match, inside its engine's
+    scope, and the per-call sort of the block maps to ``filter.order``."""
+    import re
+
+    import repro.kernels.runtime as runtime
+    from repro.core.cost_model import init_history_buffers
+    from repro.core.hytm import HyTMConfig, HyTMState, _iteration_impl, build_runtime, hytm_chunk
+    from repro.graph.algorithms import SSSP
+    from repro.graph.generators import rmat_graph
+    from repro.obs import scopes
+
+    monkeypatch.setattr(runtime, "on_tpu", lambda: True)  # compiled kernels
+    g = rmat_graph(1 << 10, 16 << 10, seed=3)
+    cfg = HyTMConfig(n_partitions=8, sync_every=4)
+    rt = build_runtime(g, cfg)
+    state = HyTMState(*SSSP.init_state(g.n_nodes, 0))
+    args = (rt.csr, rt.parts, rt.zc_req, rt.inv_deg, SSSP, cfg, rt.n_hub_partitions)
+    info = jax.eval_shape(lambda s: _iteration_impl(s, *args)[1], state)
+    on_chip = lambda tree: jax.tree.map(  # noqa: E731
+        lambda x: shape(x.shape, x.dtype) if hasattr(x, "shape") else x, tree)
+    text = hytm_chunk.lower(on_chip(state), on_chip(init_history_buffers(info, 4)),
+                            *on_chip(args[:4]), *args[4:], 4).compile().as_text()
+    got = scopes.instruction_scopes(text)
+    assert set(scopes.SCOPES) <= {s for _, s in got}
+    kernels = {re.match(r"%([a-z_]+)", line).group(1): s for line, s in got
+               if "custom-call(" in line and "_pallas" in line}
+    assert kernels == {"segment_spmm_pallas": "engine.filter",
+                       "frontier_compact_pallas": "engine.compact",
+                       "hyb_gather_pallas": "engine.zerocopy"}
+    block_sorts = [s for line, s in got if re.search(
+        rf"= s32\[{rt.parts.block_size}\]\S* sort\(", line)]
+    assert block_sorts and set(block_sorts) == {"filter.order"}
