@@ -50,12 +50,19 @@ from repro.obs.scopes import ENGINE_SCOPES, scope
 
 
 class EdgeBlock(NamedTuple):
-    """One partition's (padded) edge block."""
+    """One partition's (padded) edge block.
+
+    A block read from ``Runtime.route`` is routed: its lanes are grouped
+    by destination block, and ``first``/``last`` give each output
+    block's rows, so FILTER's fold needs no sort.  Without them the
+    kernel routes the block on every call."""
 
     src: jax.Array      # (B,) int32
     dst: jax.Array      # (B,) int32
     weight: jax.Array   # (B,) float32
     active: jax.Array   # (B,) bool — source active AND edge in partition
+    first: jax.Array | None = None  # (n_blocks,) int32, routed blocks only
+    last: jax.Array | None = None   # (n_blocks,) int32
 
 
 class RelaxOut(NamedTuple):
@@ -89,17 +96,27 @@ def _combine_spmm(block: EdgeBlock, msg: jax.Array, n: int, program: VertexProgr
 
     MIN: the scatter-min kernel over the identity-masked messages —
     bit-identical to ``jax.ops.segment_min`` (order-free).  SUM: one
-    kernel call over the packed (B, 2) [message, active] columns — the
-    value column is tolerance-bounded (tiled reassociation), the 0/1
-    activity column sums exactly, so ``touched`` stays bit-exact.
+    kernel call over the packed [message, active] columns — the value
+    column is tolerance-bounded (tiled reassociation), the 0/1 activity
+    column sums exactly, so ``touched`` stays bit-exact.  A routed block
+    goes straight to the fold, its message columns already in place as
+    (d, rows, 128) tiles; any other block is routed by the kernel on
+    every call.
     """
-    from repro.kernels.segment_spmm.ops import segment_spmm
+    from repro.kernels.segment_spmm.ops import segment_spmm, segment_spmm_routed
+    from repro.kernels.segment_spmm.segment_spmm import LANES
 
+    cols = [msg] if program.combine == MIN else [msg, block.active.astype(msg.dtype)]
+    combine = "min" if program.combine == MIN else "sum"
+    if block.first is None:
+        out = segment_spmm(jnp.stack(cols, axis=-1), block.dst, n, combine=combine)
+    else:
+        tiles = (block.dst.shape[0] // LANES, LANES)
+        out = segment_spmm_routed(jnp.stack(cols).reshape(len(cols), *tiles),
+                                  block.dst.reshape(tiles), block.first,
+                                  block.last, n, combine=combine)
     if program.combine == MIN:
-        agg = segment_spmm(msg, block.dst, n, combine="min")
-        return RelaxOut(agg=agg, touched=jnp.isfinite(agg))
-    packed = jnp.stack([msg, block.active.astype(msg.dtype)], axis=-1)
-    out = segment_spmm(packed, block.dst, n)
+        return RelaxOut(agg=out[:, 0], touched=jnp.isfinite(out[:, 0]))
     return RelaxOut(agg=out[:, 0], touched=out[:, 1] > 0)
 
 

@@ -73,8 +73,10 @@ from repro.core.engines import EdgeBlock, relax_with_engine
 from repro.kernels.runtime import resolve_use_kernels
 from repro.core.partition import (
     DevicePartitions,
+    EdgeRoute,
     PartitionTable,
     partition_graph,
+    route_partitions,
     to_device_partitions,
 )
 from repro.core.scheduler import make_schedule
@@ -175,6 +177,11 @@ class Runtime:
     inv_deg: jax.Array         # (n,) float32 — 1/max(deg,1) (or 1/sum(w)
                                # for weighted accumulative programs: PHP)
     n_hub_partitions: int
+    # each partition's edges routed by destination block (build_runtime):
+    # the sweep reads its blocks from here and FILTER's fold skips the
+    # per-visit sort.  None — a view over a CSR that is patched in place
+    # (DeltaCSR.runtime_for) — slices the CSR and routes on every visit.
+    route: EdgeRoute | None = None
     # (program, config, shapes) -> iteration info ShapeDtypeStructs;
     # reusing a runtime across run_hytm calls — or sharing this dict
     # across runtime views, as DeltaCSR.runtime_for does — skips the
@@ -182,6 +189,16 @@ class Runtime:
     # include the specializing shapes, so a shared dict stays correct
     # when the underlying buffers are re-blocked (merge-compaction).
     info_shape_cache: dict = field(default_factory=dict, repr=False)
+
+    def route_args(self) -> dict:
+        """The ``hytm.run`` span's args: how FILTER's fold gets its blocks
+        routed (``prebuilt`` once, or ``per_call`` on every visit), and
+        the lanes a sweep visits per stored edge (P · block width / E)."""
+        width = self.parts.block_size if self.route is None else self.route.width
+        return {
+            "route": "per_call" if self.route is None else "prebuilt",
+            "padding": self.parts.n_partitions * width / max(self.csr.n_edges, 1),
+        }
 
 
 def build_runtime(
@@ -214,6 +231,7 @@ def build_runtime(
     return Runtime(
         csr=csr, parts=parts, zc_req=zc_req, inv_deg=inv_deg,
         n_hub_partitions=n_hub_parts,
+        route=route_partitions(g, table, parts.block_size),
     )
 
 
@@ -239,7 +257,8 @@ def _sweep(
 ) -> tuple[HyTMState, jax.Array]:
     """Scan partitions in priority order; returns new state + activated."""
     n = rt.csr.n_nodes
-    B = rt.parts.block_size
+    route = rt.route
+    B = rt.parts.block_size if route is None else route.width
     values0, delta0 = state.values, state.delta
 
     def combine(out, values, delta, activated, p, processed):
@@ -290,15 +309,22 @@ def _sweep(
         values, delta, activated = carry
         with scope(SWEEP_BLOCK):
             eng = engines[p]
-            start = rt.parts.edge_start[p]
             local = jnp.arange(B, dtype=jnp.int32)
+            # either way the partition's own edges fill lanes [0, E_p)
             in_range = local < rt.parts.part_edges[p]
-            src = _slice_block(rt.csr.edge_src, start, B)
-            dst = _slice_block(rt.csr.edge_dst, start, B)
-            w = _slice_block(rt.csr.edge_weight, start, B)
+            if route is None:
+                start = rt.parts.edge_start[p]
+                src = _slice_block(rt.csr.edge_src, start, B)
+                dst = _slice_block(rt.csr.edge_dst, start, B)
+                w = _slice_block(rt.csr.edge_weight, start, B)
+                first = last = None
+            else:
+                src, dst, w = route.src[p], route.dst[p], route.weight[p]
+                first, last = route.first[p], route.last[p]
             processed = eng != NONE
             active_lane = frontier[src] & in_range & processed
-            block = EdgeBlock(src=src, dst=dst, weight=w, active=active_lane)
+            block = EdgeBlock(src=src, dst=dst, weight=w, active=active_lane,
+                              first=first, last=last)
 
             if program.combine == SUM:
                 dsrc = delta if async_sweep else delta0
@@ -325,12 +351,15 @@ def _iteration_impl(
     config: HyTMConfig,
     n_hub_partitions: int,
     correction: jax.Array | None = None,
+    route: EdgeRoute | None = None,
 ) -> tuple[HyTMState, dict[str, Any]]:
     """Untraced single-iteration body.  ``hytm_iteration`` jits it as the
     public per-dispatch entry; ``hytm_chunk`` inlines it inside the
-    chunked ``lax.while_loop`` so K iterations share one dispatch."""
+    chunked ``lax.while_loop`` so K iterations share one dispatch.
+    ``route`` is ``Runtime.route``, an argument like the CSR (never a
+    baked-in constant); None routes FILTER's block on every visit."""
     rt = Runtime(csr=csr, parts=parts, zc_req=zc_req, inv_deg=inv_deg,
-                 n_hub_partitions=n_hub_partitions)
+                 n_hub_partitions=n_hub_partitions, route=route)
     n = csr.n_nodes
     frontier = state.frontier
     # trace-time resolution: config is static under jit, so the kernel
@@ -514,6 +543,7 @@ def hytm_chunk(
     n_hub_partitions: int,
     chunk: int,
     correction: jax.Array | None = None,
+    route: EdgeRoute | None = None,
 ) -> tuple[HyTMState, dict[str, jax.Array], jax.Array, jax.Array, jax.Array]:
     """Run up to ``chunk`` iterations inside one ``lax.while_loop``.
 
@@ -541,7 +571,7 @@ def hytm_chunk(
     return chunked_while(
         lambda st: _iteration_impl(
             st, csr, parts, zc_req, inv_deg, program, config,
-            n_hub_partitions, correction,
+            n_hub_partitions, correction, route,
         ),
         state, history, chunk,
     )
@@ -563,6 +593,7 @@ def hytm_batched_chunk(
     n_hub_partitions: int,
     chunk: int,
     correction: jax.Array | None = None,
+    route: EdgeRoute | None = None,
 ) -> tuple[HyTMState, jax.Array, jax.Array, jax.Array, jax.Array]:
     """Chunked *lane-batched* sweep: up to ``chunk`` vmapped iterations of
     ``_iteration_impl`` inside one ``lax.while_loop`` dispatch, over a
@@ -593,9 +624,10 @@ def hytm_batched_chunk(
     mispred_sum)``.
     """
     def one(s):
+        # the route is closed over, not vmapped: one copy for all lanes
         return _iteration_impl(
             s, csr, parts, zc_req, inv_deg, program, config,
-            n_hub_partitions, correction,
+            n_hub_partitions, correction, route,
         )
 
     def cond(carry):
@@ -771,7 +803,9 @@ def run_hytm(
     run always opens the live spans ``hytm.run`` > ``hytm.init``,
     ``chunk`` > ``hytm.dispatch`` (``hytm.compile`` when the program is
     new) / ``hytm.wait`` / ``hytm.drain``, and ``hytm.result`` as
-    profiler annotations (inert without a profile); a recorder records
+    profiler annotations (inert without a profile); ``hytm.run`` carries
+    ``Runtime.route_args()`` (a runtime it has to build is built before
+    it opens).  A recorder records
     them too, plus per-iteration events from the drained history rows
     and one run-summary span whose totals equal the returned
     ``HyTMResult`` fields exactly.  ``obs=None`` (the default) records
@@ -813,32 +847,32 @@ def run_hytm(
         raise ValueError(
             "on_chunk (checkpointing) requires the chunked driver — "
             "set sync_every >= 2")
-    with span("hytm.run", obs, track=_TRACK):
+    if runtime is None:
+        if program.symmetrize:
+            # WCC-family programs are defined on the underlying undirected
+            # graph; a prebuilt runtime is assumed already symmetrized
+            g = g.symmetrize()
+        runtime = build_runtime(
+            g, config, n_hubs=n_hubs,
+            weighted_norm=program.use_delta and program.weighted,
+        )
+    with span("hytm.run", obs, track=_TRACK, **runtime.route_args()):
         return _run_single_device(
-            g, program, source, config, n_hubs, runtime, initial_state,
+            program, source, config, runtime, initial_state,
             calibrator, obs, faults, retry, on_chunk)
 
 
-def _run_single_device(g, program, source, config, n_hubs, runtime,
-                       initial_state, calibrator, obs, faults, retry,
-                       on_chunk) -> HyTMResult:
-    """``run_hytm`` on one device, in the host spans ``hytm.init``,
-    ``hytm.dispatch`` (``hytm.compile`` for a program's first dispatch),
-    ``hytm.wait``, ``hytm.drain`` and ``hytm.result``."""
+def _run_single_device(program, source, config, rt, initial_state,
+                       calibrator, obs, faults, retry, on_chunk) -> HyTMResult:
+    """``run_hytm`` on one device over a built runtime, in the host spans
+    ``hytm.init``, ``hytm.dispatch`` (``hytm.compile`` for a program's
+    first dispatch), ``hytm.wait``, ``hytm.drain`` and ``hytm.result``."""
     # late imports: both modules import this one
     from repro.obs.record import record_history_rows, record_run
     if faults is not None:
         from repro.resilience.supervisor import guarded_dispatch
 
     with span("hytm.init", obs, track=_TRACK):
-        if runtime is None and program.symmetrize:
-            # WCC-family programs are defined on the underlying undirected
-            # graph; a prebuilt runtime is assumed already symmetrized
-            g = g.symmetrize()
-        rt = runtime if runtime is not None else build_runtime(
-            g, config, n_hubs=n_hubs,
-            weighted_norm=program.use_delta and program.weighted,
-        )
         if initial_state is None:
             if program.peel_k is not None:
                 # peeling seeds from the runtime's (symmetrized)
@@ -877,14 +911,15 @@ def _run_single_device(g, program, source, config, n_hubs, runtime,
         # must not feed the calibrator
         shapes = (program, config, rt.n_hub_partitions, rt.csr.n_nodes,
                   rt.csr.edge_src.shape[0], rt.parts.n_partitions,
-                  rt.parts.block_size)
+                  rt.parts.block_size,
+                  None if rt.route is None else rt.route.width)
         if config.sync_every > 1:
             info_shapes = rt.info_shape_cache.get(shapes)
             if info_shapes is None:
                 info_shapes = jax.eval_shape(
                     lambda s: _iteration_impl(
                         s, rt.csr, rt.parts, rt.zc_req, rt.inv_deg, program,
-                        config, rt.n_hub_partitions, correction,
+                        config, rt.n_hub_partitions, correction, rt.route,
                     ),
                     state,
                 )[1]
@@ -915,6 +950,7 @@ def _run_single_device(g, program, source, config, n_hubs, runtime,
                     return hytm_chunk(
                         st, h, rt.csr, rt.parts, rt.zc_req, rt.inv_deg,
                         program, config, rt.n_hub_partitions, chunk, corr,
+                        rt.route,
                     )
 
             with span("chunk", obs, cat=CAT_RUN, track=_TRACK,
@@ -926,7 +962,7 @@ def _run_single_device(g, program, source, config, n_hubs, runtime,
                         scopes.register(
                             signature, hytm_chunk, state, history, rt.csr,
                             rt.parts, rt.zc_req, rt.inv_deg, program, config,
-                            rt.n_hub_partitions, chunk, correction)
+                            rt.n_hub_partitions, chunk, correction, rt.route)
                     state, history, n_done, last_active, pe_sum = (
                         attempt() if faults is None else guarded_dispatch(
                             attempt, site="chunk_dispatch", faults=faults,
@@ -977,7 +1013,7 @@ def _run_single_device(g, program, source, config, n_hubs, runtime,
             def attempt(st=state, corr=correction):
                 return hytm_iteration(
                     st, rt.csr, rt.parts, rt.zc_req, rt.inv_deg,
-                    program, config, rt.n_hub_partitions, corr,
+                    program, config, rt.n_hub_partitions, corr, rt.route,
                 )
 
             with span("hytm.dispatch" if warm else "hytm.compile", obs,
@@ -986,7 +1022,7 @@ def _run_single_device(g, program, source, config, n_hubs, runtime,
                     scopes.register(
                         signature, hytm_iteration, state, rt.csr, rt.parts,
                         rt.zc_req, rt.inv_deg, program, config,
-                        rt.n_hub_partitions, correction)
+                        rt.n_hub_partitions, correction, rt.route)
                 state, info = attempt() if faults is None else guarded_dispatch(
                     attempt, site="chunk_dispatch", faults=faults,
                     policy=retry, obs=obs, mesh=False,

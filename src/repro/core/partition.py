@@ -10,6 +10,9 @@ analysis; the task combiner merges them at schedule time.
 ``DevicePartitions`` pads every partition's edge range to a common static
 ``block_size`` so jitted code can ``dynamic_slice`` fixed-size edge blocks
 — the JAX analogue of streaming one partition through the transfer engine.
+``EdgeRoute`` stores the same blocks once more, each partition's own edges
+grouped by destination block, so the FILTER engine's fold reads them in
+place instead of sorting its block on every visit.
 """
 
 from __future__ import annotations
@@ -22,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.graph.csr import CSRGraph
+from repro.kernels.segment_spmm.segment_spmm import LANES, TILE_LANES, TILE_N
 
 
 @dataclass(frozen=True)
@@ -107,4 +111,62 @@ def to_device_partitions(
         vertex_part_id=jnp.asarray(part_id),
         n_partitions=table.n_partitions,
         block_size=block,
+    )
+
+
+@jax.tree_util.register_dataclass
+@dataclass(frozen=True)
+class EdgeRoute:
+    """Each partition's edges in destination-block order, one row per
+    partition, built once on the host (:func:`route_partitions`).
+
+    Row ``p`` holds exactly partition ``p``'s edges in lanes
+    ``[0, part_edges[p])``, ordered stably by ``dst // TILE_N`` (so sources
+    stay ascending within a block), then pads: source 0, weight 0 and the
+    sentinel destination ``n_blocks · TILE_N``, past every output block.
+    ``first[p, b]`` / ``last[p, b]`` bound the 128-lane rows that hold
+    block ``b``'s edges (``first == last`` for a block with none), which
+    is what ``segment_spmm_routed`` scalar-prefetches."""
+
+    src: jax.Array     # (P, width) int32
+    dst: jax.Array     # (P, width) int32
+    weight: jax.Array  # (P, width) float32
+    first: jax.Array   # (P, n_blocks) int32
+    last: jax.Array    # (P, n_blocks) int32
+
+    @property
+    def width(self) -> int:
+        return self.src.shape[-1]
+
+
+def route_partitions(g: CSRGraph, table: PartitionTable, block_size: int) -> EdgeRoute:
+    """Route every partition's edges by destination block (NumPy, once).
+
+    ``width`` is ``block_size`` rounded up to whole (8, 128) tiles, so a
+    row reshapes to the fold's (rows, 128) view as laid out.  Keys and
+    bounds are int64 here, so no packing limit applies."""
+    n_parts = table.n_partitions
+    n_blocks = -(-g.n_nodes // TILE_N)
+    width = -(-block_size // TILE_LANES) * TILE_LANES
+    part = np.repeat(np.arange(n_parts, dtype=np.int64), table.edges_per_partition)
+    key = part * n_blocks + g.indices.astype(np.int64) // TILE_N
+    order = np.argsort(key, kind="stable")
+    # sorting by partition first keeps each partition's edges where they
+    # were, so a routed edge's lane is its rank past the partition start
+    lane = np.arange(g.n_edges, dtype=np.int64) - table.edge_start[part]
+    src = np.zeros((n_parts, width), np.int32)
+    dst = np.full((n_parts, width), n_blocks * TILE_N, np.int32)
+    weight = np.zeros((n_parts, width), np.float32)
+    src[part, lane] = g.edge_sources()[order]
+    dst[part, lane] = g.indices[order]
+    weight[part, lane] = 1.0 if g.weights is None else g.weights[order]
+
+    counts = np.bincount(key, minlength=n_parts * n_blocks).reshape(n_parts, n_blocks)
+    ends = np.cumsum(counts, axis=1)
+    first = (ends - counts) // LANES
+    last = np.where(counts > 0, -(-ends // LANES), first)
+    return EdgeRoute(
+        src=jnp.asarray(src), dst=jnp.asarray(dst), weight=jnp.asarray(weight),
+        first=jnp.asarray(first, dtype=jnp.int32),
+        last=jnp.asarray(last, dtype=jnp.int32),
     )

@@ -9,7 +9,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.runtime import interpret_mode, map_over_lanes
-from repro.kernels.segment_spmm.segment_spmm import segment_spmm_pallas
+from repro.kernels.segment_spmm.segment_spmm import segment_spmm_fold, segment_spmm_pallas
 
 
 def segment_spmm(
@@ -45,3 +45,27 @@ def segment_spmm(
             interpret=interpret_mode())
         out = map_over_lanes(combine_fn)(messages, seg_ids, valid)
     return out[:, 0] if squeeze else out
+
+
+def segment_spmm_routed(
+    messages: jax.Array,
+    seg: jax.Array,
+    first: jax.Array,
+    last: jax.Array,
+    n_segments: int,
+    combine: str = "sum",
+) -> jax.Array:
+    """``segment_spmm`` of a block whose lanes are already routed: grouped
+    by destination block, with each output block's row range in
+    ``first``/``last`` (``core.partition.route_partitions`` builds such
+    blocks).  No sort, no ``searchsorted``, no gather: the call is the
+    fold alone.
+
+    ``messages`` is (d, rows, 128) and gives (n_segments, d); ``seg`` is
+    the (rows, 128) destination view, with pad lanes at a sentinel id
+    past every output block.
+    """
+    combine_fn = functools.partial(
+        segment_spmm_fold, n_segments=n_segments, combine=combine,
+        interpret=interpret_mode())
+    return map_over_lanes(combine_fn)(messages, seg, first, last)

@@ -3,12 +3,16 @@
 The paper's filter engine streams whole partitions over the slow link and
 masks inactive edges in compute.  TPU adaptation (DESIGN.md §2):
 
-* the wrapper routes each edge to its output block: masked edges get a
-  sentinel block, one XLA sort groups the stream by destination block,
-  and a ``searchsorted`` over the block bounds gives each
-  (TILE_N,)-segment output block its range of 128-edge rows,
-  scalar-prefetched into SMEM;
-* the sorted stream is lane-dense and VMEM-resident for the call:
+* the edges reach the fold *routed*: grouped by destination block, so
+  each (TILE_N,)-segment output block has a range of 128-edge rows,
+  scalar-prefetched into SMEM.  ``segment_spmm_fold`` takes a block
+  routed ahead of time (``core.partition.route_partitions`` stores every
+  partition that way when the runtime is built) and goes straight to
+  the fold; ``segment_spmm_pallas`` routes on the device first: masked
+  edges get a sentinel block, one XLA sort groups the stream by
+  destination block, and a ``searchsorted`` over the block bounds gives
+  the row ranges;
+* the routed stream is lane-dense and VMEM-resident for the call:
   destination ids as an (R, 128) int32 array, messages as (d, R, 128);
 * destination combining cannot use atomics (TPU has none); instead each
   edge row of a block's range is tested against a (TILE_N, 128) tile of
@@ -21,8 +25,8 @@ The grid is one step per output block, and a block visits only the rows
 that hold its edges, so a call does O(B + n) work for a block of B edges
 and n segments.  The whole block sits in VMEM: about 8·(d + 1) bytes per
 edge with double buffering, well inside the scoped-VMEM limit for the
-partition blocks the engines build (B ≈ 26 k at 100 k vertices / 64
-partitions).
+partition blocks the engines build (B ≈ 94 k at 2^17 vertices / 64
+partitions of a Kronecker graph).
 
 Both combiners share the body: ``sum`` folds with ``+`` from 0, ``min``
 with ``minimum`` from ``+inf``.  The select keeps ±inf messages intact
@@ -47,6 +51,7 @@ from repro.obs.scopes import FILTER_ORDER, scope
 
 LANES = 128    # edges per row (one lane width)
 TILE_N = 128   # output segments per block (sublanes of the routing tile)
+TILE_LANES = 8 * LANES  # edges of one (8, 128) int32 tile: a routed block's width unit
 
 
 def _kernel(first_ref, last_ref, seg_ref, msg_ref, out_ref, acc_ref, *, combine):
@@ -73,6 +78,46 @@ def _kernel(first_ref, last_ref, seg_ref, msg_ref, out_ref, acc_ref, *, combine)
 
 
 @functools.partial(jax.jit, static_argnames=("n_segments", "combine", "interpret"))
+def segment_spmm_fold(
+    messages: jax.Array,   # (d, rows, LANES), in routed lane order
+    seg: jax.Array,        # (rows, LANES) int32 destinations; pads >= n_blocks·TILE_N
+    first: jax.Array,      # (n_blocks,) int32: output block's first row
+    last: jax.Array,       # (n_blocks,) int32: one past its last row
+    n_segments: int,
+    combine: str = "sum",
+    interpret: bool = True,
+) -> jax.Array:
+    """The fold alone over an already routed block: (n_segments, d).
+
+    Lanes are grouped by destination block ``seg // TILE_N`` in ascending
+    order, and output block ``b`` finds all of its edges in rows
+    ``[first[b], last[b])``; a row may hold edges of neighbouring blocks
+    and pads, whose ids match no segment of ``b``."""
+    if combine not in ("sum", "min"):
+        raise ValueError(f"combine must be 'sum' or 'min', got {combine!r}")
+    d, rows, _ = messages.shape
+    n_blocks = -(-n_segments // TILE_N)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(n_blocks,),
+        in_specs=[
+            pl.BlockSpec((rows, LANES), lambda oi, first, last: (0, 0)),
+            pl.BlockSpec((d, rows, LANES), lambda oi, first, last: (0, 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((TILE_N, d), lambda oi, first, last: (oi, 0)),
+        scratch_shapes=[pltpu.VMEM((d, TILE_N, LANES), jnp.float32)],
+    )
+    out = pl.pallas_call(
+        functools.partial(_kernel, combine=combine),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((n_blocks * TILE_N, d), jnp.float32),
+        interpret=interpret,
+        name="segment_spmm_pallas",
+    )(first, last, seg, messages.astype(jnp.float32))
+    return out[:n_segments].astype(messages.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("n_segments", "combine", "interpret"))
 def segment_spmm_pallas(
     messages: jax.Array,   # (m, d)
     seg_ids: jax.Array,    # (m,) int32
@@ -81,13 +126,13 @@ def segment_spmm_pallas(
     combine: str = "sum",
     interpret: bool = True,
 ) -> jax.Array:
-    if combine not in ("sum", "min"):
-        raise ValueError(f"combine must be 'sum' or 'min', got {combine!r}")
+    """Route an unordered block on the device, then the same fold as
+    ``segment_spmm_fold``: (n_segments, d)."""
     m, d = messages.shape
     n_blocks = -(-n_segments // TILE_N)
     n_pad = n_blocks * TILE_N
-    rows = -(-m // (8 * LANES)) * 8
-    m_pad = rows * LANES
+    m_pad = -(-m // TILE_LANES) * TILE_LANES
+    rows = m_pad // LANES
 
     # One single-key sort groups the edges by output block: the key packs
     # (block, edge index), and masked or out-of-range edges take the
@@ -116,22 +161,6 @@ def segment_spmm_pallas(
         msg = messages.astype(jnp.float32)[order].T
         msg = jnp.pad(msg, ((0, 0), (0, m_pad - m))).reshape(d, rows, LANES)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(n_blocks,),
-        in_specs=[
-            pl.BlockSpec((rows, LANES), lambda oi, first, last: (0, 0)),
-            pl.BlockSpec((d, rows, LANES), lambda oi, first, last: (0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((TILE_N, d), lambda oi, first, last: (oi, 0)),
-        scratch_shapes=[pltpu.VMEM((d, TILE_N, LANES), jnp.float32)],
-    )
-    out = pl.pallas_call(
-        functools.partial(_kernel, combine=combine),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_pad, d), jnp.float32),
-        interpret=interpret,
-        name="segment_spmm_pallas",
-    )(first, last, seg, msg)
-    with scope(FILTER_ORDER):
-        return out[:n_segments].astype(messages.dtype)
+    out = segment_spmm_fold(msg, seg, first, last, n_segments=n_segments,
+                            combine=combine, interpret=interpret)
+    return out.astype(messages.dtype)

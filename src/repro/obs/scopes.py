@@ -14,8 +14,12 @@ with the scope it nests in:
 ``engine.*``       one engine's whole relax (``filter``, ``compact``,
                    ``zerocopy``); ``NONE`` partitions clip to FILTER,
                    so ``engine.filter`` holds their skipped visits too
-``filter.order``   ``segment_spmm``'s sort, ``searchsorted``, the
-                   ``[order]`` gathers and pads: all but its Pallas call
+``filter.order``   the per-call route of ``segment_spmm``: its sort,
+                   ``searchsorted``, the ``[order]`` gathers and pads, all
+                   but its Pallas call.  Only blocks that come unrouted
+                   reach it (a ``DeltaCSR`` view, the sharded sweep); a
+                   runtime from ``build_runtime`` routes its partitions
+                   once, and its programs have no ``filter.order``
 ``sweep.combine``  a visit's n-wide value, Δ and ``activated`` update
 ``update``         the next frontier, the info rows, the history writes
 =================  ====================================================

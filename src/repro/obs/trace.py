@@ -137,19 +137,20 @@ def span(
     name: str, obs: TraceRecorder | None = None, *, cat: str = "host",
     track: str = "main", vt: float = 0.0, **args: Any,
 ):
-    """A live span around the body: a profiler annotation always, and a
-    recorded span when ``obs`` is given.  With ``obs``, the context
+    """A live span around the body: a profiler annotation always (the
+    ``args`` given here are its stats), and a recorded span when ``obs``
+    is given.  With ``obs``, the context
     yields the event it will record, so the body can set ``vt_dur`` and
     ``args`` it learns while the span is open."""
     if obs is None:
-        return jax.profiler.TraceAnnotation(name)
+        return jax.profiler.TraceAnnotation(name, **args)
     return _recorded_span(
         obs, TraceEvent(name, PH_SPAN, cat, track, 0.0, vt, args=args))
 
 
 @contextlib.contextmanager
 def _recorded_span(obs: TraceRecorder, ev: TraceEvent) -> Iterator[TraceEvent]:
-    with jax.profiler.TraceAnnotation(ev.name):
+    with jax.profiler.TraceAnnotation(ev.name, **ev.args):
         ev.wall = obs.wall()
         try:
             yield ev

@@ -183,3 +183,91 @@ def test_hybrid_never_worse_than_worst_engine():
         res = run_hytm(g, SSSP, source=0, config=cfg)
         times[eng] = res.modeled_seconds
     assert times[None] <= max(times[FILTER], times[COMPACT], times[ZEROCOPY]) + 1e-9
+
+
+# ------------------------------------------------------------ prebuilt route
+
+def _kron_like(scale=10, seed=5):
+    """A small undirected GAP-kron-like graph: Graph500 quadrant
+    probabilities, both directions of every edge, duplicates kept."""
+    from repro.graph.csr import csr_from_edges
+
+    g = rmat_graph(1 << scale, 8 << scale, seed=seed)
+    src = g.edge_sources()
+    return csr_from_edges(g.n_nodes, np.concatenate([src, g.indices]),
+                          np.concatenate([g.indices, src]),
+                          np.concatenate([g.weights, g.weights]))
+
+
+def test_route_layout_per_partition():
+    """Each routed row is a permutation of exactly its partition's edges,
+    destination blocks non-decreasing and sources ascending within a
+    block, pads at the sentinel, and first/last equal a searchsorted over
+    the sorted block keys."""
+    from repro.core.partition import partition_graph, route_partitions, to_device_partitions
+    from repro.kernels.segment_spmm.segment_spmm import LANES, TILE_LANES, TILE_N
+
+    g = _kron_like()
+    table = partition_graph(g, n_partitions=16)
+    B = to_device_partitions(table, g.n_nodes, g.n_edges).block_size
+    route = route_partitions(g, table, B)
+    n_blocks = -(-g.n_nodes // TILE_N)
+    assert route.width % TILE_LANES == 0 and B <= route.width < B + TILE_LANES
+    src_all = g.edge_sources()
+    for p in range(table.n_partitions):
+        e0, e1 = table.edge_start[p], table.edge_start[p + 1]
+        k = e1 - e0
+        src, dst = np.asarray(route.src[p]), np.asarray(route.dst[p])
+        w = np.asarray(route.weight[p])
+        assert sorted(zip(src[:k], dst[:k], w[:k])) == sorted(
+            zip(src_all[e0:e1], g.indices[e0:e1], g.weights[e0:e1]))
+        block = dst[:k] // TILE_N
+        assert np.all(np.diff(block) >= 0)
+        same = np.diff(block) == 0
+        assert np.all(np.diff(src[:k])[same] >= 0)
+        assert np.all(dst[k:] == n_blocks * TILE_N)
+        assert np.all(src[k:] == 0) and np.all(w[k:] == 0)
+        bounds = np.searchsorted(block, np.arange(n_blocks + 1))
+        first = bounds[:-1] // LANES
+        last = np.where(bounds[1:] > bounds[:-1], -(-bounds[1:] // LANES), first)
+        np.testing.assert_array_equal(np.asarray(route.first[p]), first)
+        np.testing.assert_array_equal(np.asarray(route.last[p]), last)
+
+
+def test_prebuilt_route_whole_run_matches_oracles():
+    """run_hytm over a built runtime (routed blocks, kernels in interpret
+    mode) against the oracle engines: SSSP and BFS values, iteration
+    counts and engine picks bit-identical, Δ-PageRank within tolerance;
+    a DeltaCSR view (no route, the per-call sort) gives the same
+    answers."""
+    from repro.core.hytm import build_runtime
+    from repro.graph.algorithms import BFS
+    from repro.stream.delta_csr import DeltaCSR
+
+    g = _kron_like()
+    cfg = HyTMConfig(n_partitions=16, use_kernels=True)
+    rt = build_runtime(g, cfg)
+    assert rt.route is not None and rt.route_args()["route"] == "prebuilt"
+    view = DeltaCSR(g, cfg).runtime_for(SSSP)
+    assert view.route is None and view.route_args()["route"] == "per_call"
+    for prog in (SSSP, BFS):
+        on = run_hytm(g, prog, source=3, config=cfg, runtime=rt)
+        off = run_hytm(g, prog, source=3,
+                       config=dataclasses.replace(cfg, use_kernels=False))
+        np.testing.assert_array_equal(on.values, off.values)
+        assert on.iterations == off.iterations
+        np.testing.assert_array_equal(on.history["engines"], off.history["engines"])
+        per_call = run_hytm(None, prog, source=3, config=cfg,
+                            runtime=DeltaCSR(g, cfg).runtime_for(prog))
+        np.testing.assert_array_equal(on.values, per_call.values)
+    prog = dataclasses.replace(PAGERANK, tolerance=1e-6)
+    pr_cfg = dataclasses.replace(cfg, cds_mode="delta")
+    on = run_hytm(g, prog, source=None, config=pr_cfg)
+    off = run_hytm(g, prog, source=None,
+                   config=dataclasses.replace(pr_cfg, use_kernels=False))
+    per_call = run_hytm(None, prog, source=None, config=pr_cfg,
+                        runtime=DeltaCSR(g, pr_cfg).runtime_for(prog))
+    ref = reference_pagerank(g)
+    for res in (on, off, per_call):
+        assert np.max(np.abs(res.values + res.delta - ref)) < 1e-3
+    assert np.max(np.abs(on.values - off.values)) < 1e-4

@@ -131,6 +131,60 @@ def test_segment_spmm_min_inf_messages():
         np.asarray(got), np.asarray([1.0, -np.inf, np.inf, np.inf], np.float32))
 
 
+# ------------------------------------------------ prebuilt route (routed fold)
+
+def _routed_graph():
+    """A skewed graph whose destinations skip output blocks 1 and 3 and
+    whose partitions are all shorter than the block but one."""
+    from repro.graph.csr import csr_from_edges
+
+    rng = np.random.default_rng(7)
+    n, m = 5 * 128 + 17, 3000
+    src = (rng.pareto(1.2, m) * 40).astype(np.int64) % n
+    dst = rng.integers(0, n, m)
+    dst = np.where((dst // 128) % 2 == 1, dst - 128, dst)  # blocks 1, 3 empty
+    return csr_from_edges(n, src, dst, rng.random(m).astype(np.float32))
+
+
+@pytest.mark.parametrize("d", [1, 2])
+@pytest.mark.parametrize("combine", ["min", "sum"])
+def test_segment_spmm_routed_matches_per_call(combine, d):
+    """The fold over a block routed when the runtime is built equals the
+    per-call route over the same partition's CSR slice: bit-identical for
+    min, within the sum sweep's tolerance for sum.  Pad lanes hold
+    garbage messages the fold must ignore; blocks 1 and 3 receive no
+    edge; every partition but the largest is shorter than B."""
+    from repro.core.partition import partition_graph, route_partitions, to_device_partitions
+    from repro.kernels.segment_spmm.ops import segment_spmm_routed
+    from repro.kernels.segment_spmm.segment_spmm import LANES
+
+    g = _routed_graph()
+    n = g.n_nodes
+    table = partition_graph(g, n_partitions=6)
+    B = to_device_partitions(table, n, g.n_edges).block_size
+    route = route_partitions(g, table, B)
+    assert len(set(table.edges_per_partition)) > 1 and route.width > B
+    src_all = g.edge_sources().astype(np.float32)
+    for p in range(table.n_partitions):
+        e0, e1 = table.edge_start[p], table.edge_start[p + 1]
+        count = e1 - e0
+        cols = [np.asarray(route.weight[p]), np.asarray(route.src[p], np.float32)][:d]
+        routed = np.stack(cols).reshape(d, -1, LANES)
+        routed[:, np.arange(route.width).reshape(-1, LANES) >= count] = -7.0
+        per_call = np.stack([g.weights[e0:e1], src_all[e0:e1]][:d], axis=-1)
+        got = segment_spmm_routed(
+            jnp.asarray(routed), route.dst[p].reshape(-1, LANES), route.first[p],
+            route.last[p], n, combine=combine)
+        want = segment_spmm(jnp.asarray(per_call), jnp.asarray(g.indices[e0:e1]), n,
+                            combine=combine)
+        if combine == "min":
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        else:
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want), **_tol(jnp.float32))
+        empty = np.asarray(got)[128:256]
+        assert np.all(empty == (np.inf if combine == "min" else 0.0))
+
+
 # -------------------------------------------- degenerate shapes (regressions)
 
 def test_segment_spmm_empty_edge_stream():

@@ -254,6 +254,33 @@ def test_profiler_trace_holds_driver_spans(graph, tmp_path, sync_every):
         assert "hytm.dispatch" in names or first
 
 
+def test_run_span_names_its_route(graph, tmp_path):
+    """``hytm.run`` carries the route as profiler stats: ``prebuilt`` over
+    a built runtime, ``per_call`` over a DeltaCSR view, with the lanes a
+    sweep visits per stored edge."""
+    import jax
+
+    from repro.core.hytm import build_runtime
+    from repro.stream.delta_csr import DeltaCSR
+
+    cfg = HyTMConfig(n_partitions=8)
+    runtimes = (build_runtime(graph, cfg), DeltaCSR(graph, cfg).runtime_for(SSSP))
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        for rt in runtimes:
+            run_hytm(graph, SSSP, source=0, config=cfg, runtime=rt)
+    finally:
+        jax.profiler.stop_trace()
+    path = sorted(tmp_path.rglob("*.xplane.pb"))[-1]
+    got = [dict(e.stats) for plane in jax.profiler.ProfileData.from_file(str(path)).planes
+           for line in plane.lines for e in line.events if e.name == "hytm.run"]
+    assert [s["route"] for s in got] == ["prebuilt", "per_call"]
+    assert got == [rt.route_args() for rt in runtimes]
+    assert got[0]["padding"] >= 1.0
+
+
 def test_timed_writes_a_profiler_span(tmp_path):
     rec = TraceRecorder()
 
@@ -320,11 +347,16 @@ def _computation(text, name):
 
 @pytest.mark.parametrize("sync_every", [4, 1], ids=["chunked", "K=1"])
 def test_op_scopes_cover_the_program(sync_every):
-    """At scale 10 with the kernels (interpret mode here), the program a
-    run dispatched maps to every scope, and >= 95 % of the work in its
-    loop body (the chunk's while body; the iteration's entry for K=1)
-    maps to one.  Its registered signature compiles to the program the
-    concrete arguments compile to."""
+    """At scale 10 with the kernels (interpret mode here), the programs
+    runs dispatched map to every scope, and >= 95 % of the work in the
+    loop body (the chunk's while body; the iteration's entry for K=1) of
+    a run over a built runtime maps to one.  That run's blocks are routed
+    when the runtime is built, so its program has no ``filter.order``;
+    a runtime without the route sorts under it.  The registered
+    signature compiles to the program the concrete arguments compile
+    to."""
+    import dataclasses
+
     import jax
 
     from repro.core.cost_model import init_history_buffers
@@ -336,6 +368,8 @@ def test_op_scopes_cover_the_program(sync_every):
                      max_iters=9_002)
     rt = build_runtime(g, cfg)
     run_hytm(g, SSSP, source=0, config=cfg, runtime=rt)
+    run_hytm(g, SSSP, source=0, config=cfg,
+             runtime=dataclasses.replace(rt, route=None))
     got = scopes.op_scopes()
     assert set(scopes.SCOPES) <= {s for _, s in got}
 
@@ -345,15 +379,16 @@ def test_op_scopes_cover_the_program(sync_every):
     if sync_every > 1:
         info = jax.eval_shape(lambda s: _iteration_impl(s, *args)[1], state)
         text = hytm_chunk.lower(state, init_history_buffers(info, sync_every),
-                                *args, sync_every).compile().as_text()
+                                *args, sync_every, None, rt.route).compile().as_text()
         entry = _computation(text, re.search(r"ENTRY %(\S+) ", text).group(1))
         outer = next(line for line in entry if " while(" in line)
         body = _computation(text, re.search(r"body=%([\w.-]+)", outer).group(1))
     else:
-        text = hytm_iteration.lower(state, *args).compile().as_text()
+        text = hytm_iteration.lower(state, *args, None, rt.route).compile().as_text()
         body = _computation(text, re.search(r"ENTRY %(\S+) ", text).group(1))
     mine = dict(scopes.instruction_scopes(text))
     assert set(mine.items()) <= set(got)
+    assert scopes.FILTER_ORDER not in mine.values()
     work = [line for line in (scopes._TAIL.split(b, maxsplit=1)[0] for b in body)
             if re.search(r"\s([a-z][\w-]*)\(", line.split(" = ", 1)[1]).group(1)
             not in PLUMBING]

@@ -6,7 +6,9 @@ kernels with ``interpret=False`` at the widths of
 ``configs/hytgraph_paper.py`` (100,000 vertices, 64 partitions, so an
 edge block of B = 26,368), which catches what interpret mode accepts and
 the chip refuses: unaligned slices, layouts Mosaic cannot infer, scoped
-VMEM overruns.  Nothing runs, so these say nothing about results or time.
+VMEM overruns; the fold over a prebuilt route compiles at the benchmark
+cells' widths too.  Nothing runs, so these say nothing about results or
+time.
 
 The topology is described inside a fixture: only the worker that runs
 this file loads the TPU library (one process at a time may hold it).
@@ -19,7 +21,13 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.frontier_compact.frontier_compact import frontier_compact_pallas
 from repro.kernels.hyb_gather.hyb_gather import PAD, hyb_gather_pallas
-from repro.kernels.segment_spmm.segment_spmm import segment_spmm_pallas
+from repro.kernels.segment_spmm.segment_spmm import (
+    LANES,
+    TILE_LANES,
+    TILE_N,
+    segment_spmm_fold,
+    segment_spmm_pallas,
+)
 
 N = 100_000
 BLOCK = 26_368
@@ -66,6 +74,23 @@ def test_segment_spmm_compiles_for_v5e(shape, combine, d):
         shape((BLOCK,), jnp.bool_))
 
 
+@pytest.mark.parametrize("combine,d", [("min", 1), ("sum", 2)])
+@pytest.mark.parametrize("block", [93_696, 65_664], ids=["kron17", "urand17"])
+def test_routed_fold_compiles_for_v5e(shape, block, combine, d):
+    """The fold over a prebuilt route at the benchmark cells' widths
+    (2^17 vertices, 64 partitions): B rounded up to whole (8, 128) tiles,
+    no sort in the program."""
+    n = 1 << 17
+    rows = -(-block // TILE_LANES) * TILE_LANES // LANES
+    text = jax.jit(
+        lambda m, s, f, l: segment_spmm_fold(m, s, f, l, n, combine=combine,
+                                             interpret=False)
+    ).lower(shape((d, rows, LANES), jnp.float32), shape((rows, LANES), jnp.int32),
+            shape((n // TILE_N,), jnp.int32), shape((n // TILE_N,), jnp.int32)
+            ).compile().as_text()
+    assert "tpu_custom_call" in text and " sort(" not in text
+
+
 def test_frontier_compact_compiles_for_v5e(shape):
     _compile_has_kernel(
         lambda x, k: frontier_compact_pallas(x, k, interpret=False),
@@ -80,12 +105,10 @@ def test_hyb_gather_compiles_for_v5e(shape):
         shape((windows,), jnp.int32))
 
 
-def test_chunk_program_names_its_kernels_and_scopes_for_v5e(topo, shape, monkeypatch):
-    """The whole chunk program as the chip compiles it: each Pallas call
-    keeps the name the device-trace metrics match, inside its engine's
-    scope, and the per-call sort of the block maps to ``filter.order``."""
-    import re
-
+def _chunk_program_for_v5e(shape, monkeypatch, routed: bool):
+    """The whole chunk program as the chip compiles it, at scale 10, with
+    or without the route built with the runtime: (instruction scopes,
+    block size)."""
     import repro.kernels.runtime as runtime
     from repro.core.cost_model import init_history_buffers
     from repro.core.hytm import HyTMConfig, HyTMState, _iteration_impl, build_runtime, hytm_chunk
@@ -102,15 +125,43 @@ def test_chunk_program_names_its_kernels_and_scopes_for_v5e(topo, shape, monkeyp
     info = jax.eval_shape(lambda s: _iteration_impl(s, *args)[1], state)
     on_chip = lambda tree: jax.tree.map(  # noqa: E731
         lambda x: shape(x.shape, x.dtype) if hasattr(x, "shape") else x, tree)
+    route = on_chip(rt.route) if routed else None
     text = hytm_chunk.lower(on_chip(state), on_chip(init_history_buffers(info, 4)),
-                            *on_chip(args[:4]), *args[4:], 4).compile().as_text()
-    got = scopes.instruction_scopes(text)
-    assert set(scopes.SCOPES) <= {s for _, s in got}
+                            *on_chip(args[:4]), *args[4:], 4, None, route
+                            ).compile().as_text()
+    return scopes.instruction_scopes(text), rt.parts.block_size
+
+
+def _kernels_and_block_sorts(got, block_size):
+    import re
+
     kernels = {re.match(r"%([a-z_]+)", line).group(1): s for line, s in got
                if "custom-call(" in line and "_pallas" in line}
     assert kernels == {"segment_spmm_pallas": "engine.filter",
                        "frontier_compact_pallas": "engine.compact",
                        "hyb_gather_pallas": "engine.zerocopy"}
-    block_sorts = [s for line, s in got if re.search(
-        rf"= s32\[{rt.parts.block_size}\]\S* sort\(", line)]
+    return [s for line, s in got if re.search(rf"= s32\[{block_size}\]\S* sort\(", line)]
+
+
+def test_chunk_program_names_its_kernels_and_scopes_for_v5e(topo, shape, monkeypatch):
+    """The whole chunk program as the chip compiles it, routing per call:
+    each Pallas call keeps the name the device-trace metrics match,
+    inside its engine's scope, and the per-call sort of the block maps
+    to ``filter.order``."""
+    from repro.obs import scopes
+
+    got, block_size = _chunk_program_for_v5e(shape, monkeypatch, routed=False)
+    assert set(scopes.SCOPES) <= {s for _, s in got}
+    block_sorts = _kernels_and_block_sorts(got, block_size)
     assert block_sorts and set(block_sorts) == {"filter.order"}
+
+
+def test_prebuilt_route_chunk_program_sorts_no_block_for_v5e(topo, shape, monkeypatch):
+    """Over the route built with the runtime the same program keeps its
+    kernels' names and scopes, sorts no block, and has no
+    ``filter.order``."""
+    from repro.obs import scopes
+
+    got, block_size = _chunk_program_for_v5e(shape, monkeypatch, routed=True)
+    assert {s for _, s in got} - {None} == set(scopes.SCOPES) - {scopes.FILTER_ORDER}
+    assert _kernels_and_block_sorts(got, block_size) == []
