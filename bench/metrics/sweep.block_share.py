@@ -6,28 +6,5 @@ percent.  A visit does this block-wide whatever its frontier."""
 import tracereduce
 
 
-def scope_shares(ctx, scopes):
-    """Percent of the traced runs' device busy time by innermost scope,
-    each op counting its own time (less the ops it encloses); None
-    without a trace or a registered program.  Kept on the trace, so the
-    scope metrics of one run reduce it once."""
-    if ctx.trace is None:
-        return None
-    memo = vars(ctx.trace)
-    if "scope_shares" not in memo:
-        spans = ctx.trace.runs()
-        inside = [e for s in spans for e in ctx.trace.ops if s.start <= e.start < s.end]
-        times = scopes.scope_times(
-            ((e.name, t) for e, t in tracereduce.self_times(inside)), tracereduce.op_label)
-        busy = sum(tracereduce.busy(inside, s.start, s.end) for s in spans)
-        memo["scope_shares"] = {s: 100.0 * t / busy for s, t in times.items()} or None
-    return memo["scope_shares"]
-
-
 def read(ctx):
-    try:
-        from repro.obs import scopes
-    except ImportError:  # a program that names no device scopes
-        return None
-    shares = scope_shares(ctx, scopes)
-    return None if shares is None else sum(v for s, v in shares.items() if scopes.within(s, scopes.SWEEP_BLOCK))
+    return tracereduce.scope_share(ctx.trace, lambda scopes, s: scopes.within(s, scopes.SWEEP_BLOCK))

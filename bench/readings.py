@@ -43,16 +43,16 @@ def main() -> int:
     cell = run.load_cell(args.workload)
     traffic = cell.traffic
     jax, device, _, meter = run.start_jax(cell, run.ROOT)
-    system = run.prepare(cell, jax)
+    edges, run_one, _ = run.prepare(cell, jax)
     program_runs = {}
     for seed in args.seeds:
-        keys = loadgen.draw_keys(traffic, system.edges, seed)
-        system.run_one(keys[0])
-        _, runs = run.window(system.run_one, keys[1:], args.seconds, None,
-                             lambda key: contextlib.nullcontext())
+        keys = loadgen.draw_keys(traffic, edges, seed)
+        run_one(keys[0])
+        _, runs = run.window(run_one, keys[1:], args.seconds, None,
+                             lambda index, key: contextlib.nullcontext())
         program_runs[seed] = [(r.key, r.values, r.delta) for r in runs]
-    edges, compiles = system.edges, meter.compiles
-    del system
+    compiles = meter.compiles
+    del run_one
     gc.collect()
     ref = Reference(edges)
 

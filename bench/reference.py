@@ -38,6 +38,7 @@ def to_bfloat16(x: np.ndarray) -> np.ndarray:
 
 class Reference:
     def __init__(self, edges: EdgeList):
+        self.edges = edges  # for a check that builds a reference of its own
         src, dst, w = edges.directed()
         order = np.argsort(dst, kind="stable")
         self.n = edges.n

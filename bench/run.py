@@ -5,15 +5,17 @@
 
 The cell (``BENCHMARK.json``'s ``workloads``) names a configuration
 (``configs/<name>.json``: the graph and the ``HyTMConfig`` overrides) and
-a traffic mix (``traffic/<name>.json``).  The run:
+a traffic mix (``traffic/<name>.json``); every name these give is a file
+of its own (``named``).  The run:
 
 1. fails, printing no result, unless JAX's backend is a TPU with as many
    chips as the cell asks for and a ``device_kind`` in ``peaks.json``;
-2. set-up: generates the configuration's graph, builds the runtime with
-   ``build_runtime`` and makes one untimed warm-up run, which compiles or
+2. set-up: generates the configuration's graph, sets the traffic's
+   driver up on it (``run_hytm`` on a runtime built once, where the mix
+   names no driver) and makes one untimed warm-up run, which compiles or
    loads from the compile cache at ``<checkout>/.jax_cache``;
-3. window: runs keys drawn from ``--seed`` back to back through
-   ``run_hytm`` for ``--seconds`` (a closed loop; every run is whole);
+3. window: runs keys drawn from ``--seed`` back to back through the
+   driver for ``--seconds`` (a closed loop; every run is whole);
    with ``--trace 1`` it profiles ``trace_runs`` whole runs instead;
 4. after the window: reads the device's peak bytes, frees the program's
    state, and compares the runs' answers with the NumPy reference.
@@ -34,7 +36,6 @@ import argparse  # noqa: E402
 import contextlib  # noqa: E402
 import dataclasses  # noqa: E402
 import gc  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
@@ -52,15 +53,8 @@ import graphs  # noqa: E402
 import loadgen  # noqa: E402
 import stats  # noqa: E402
 import tracereduce  # noqa: E402
+from named import Refused, load  # noqa: E402
 from reference import Reference  # noqa: E402
-
-
-class Refused(SystemExit):
-    """The run cannot measure what the cell asks for; no result."""
-
-    def __init__(self, why: str):
-        print(f"bench: {why}", file=sys.stderr, flush=True)
-        super().__init__(2)
 
 
 @dataclasses.dataclass
@@ -138,7 +132,7 @@ class CompileMeter:
 
 @dataclasses.dataclass
 class Run:
-    key: int | None
+    key: object
     start: float
     end: float
     values: np.ndarray
@@ -162,22 +156,25 @@ class Context:
 
 
 def read_metric(name: str, ctx: Context, root: Path = ROOT):
-    path = root / "bench" / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"metric_{name}", path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module.read(ctx)
+    return load("metrics", name, root / "bench").read(ctx)
+
+
+def span_key(index: int, key) -> int:
+    """The ``key`` a run's annotation carries: -1 for a whole-graph run
+    (``None``), the vertex for an integer key, else the run's index."""
+    return -1 if key is None else key if isinstance(key, int) else index
 
 
 def window(run_one, keys, seconds: float, max_runs: int | None, annotate):
     """Run keys back to back until ``seconds`` have passed (or
-    ``max_runs`` are done); every run started is finished."""
+    ``max_runs`` are done); every run started is finished.
+    ``annotate(index, key)`` is the context each run is made in."""
     runs, t0 = [], time.monotonic()
     for key in keys:
         if time.monotonic() - t0 >= seconds or (max_runs and len(runs) >= max_runs):
             break
         start = time.monotonic()
-        with annotate(key):
+        with annotate(len(runs), key):
             res = run_one(key)
         runs.append(Run(key, start, time.monotonic(), res.values, res.delta,
                         np.asarray(res.history["engines"])))
@@ -210,42 +207,18 @@ def start_jax(cell: Cell, root: Path):
     return jax, device, load_peaks(device["kind"], root), CompileMeter(jax)
 
 
-@dataclasses.dataclass
-class System:
-    """The program under test, set up for one cell."""
-
-    edges: graphs.EdgeList
-    run_one: object       # key -> HyTMResult, through run_hytm
-    block: int
-    partitions: int
-    timings: dict
-
-
-def prepare(cell: Cell, jax) -> System:
-    """Generate the configuration's graph and build the runtime once."""
+def prepare(cell: Cell, jax, root: Path = ROOT):
+    """Generate the configuration's graph and set the traffic's driver up
+    on it (``drivers/<name>.py``).  Returns the input edges, the driver's
+    ``run_one(key)`` and what set-up reports: seconds and sizes."""
     sys.path.insert(0, str(ROOT / "src"))
-    from repro.core.hytm import HyTMConfig, build_runtime, run_hytm
-    from repro.graph.algorithms import ALGORITHMS
-    from repro.graph.csr import csr_from_edges
-
-    traffic = cell.traffic
+    bench = root / "bench"
     t = time.monotonic()
-    edges = graphs.generate(cell.config["generator"])
-    g = csr_from_edges(edges.n, *edges.directed())
-    t_gen = time.monotonic() - t
-    program = dataclasses.replace(ALGORITHMS[traffic["program"]], **{
-        k: traffic[k] for k in ("tolerance", "damping") if k in traffic})
-    cfg = HyTMConfig(**cell.config["hytm"])
-    t = time.monotonic()
-    rt = build_runtime(g, cfg, weighted_norm=program.use_delta and program.weighted)
-    jax.block_until_ready((rt.csr, rt.parts))
-    t_rt = time.monotonic() - t
-
-    def run_one(key):
-        return run_hytm(g, program, source=key, config=cfg, runtime=rt)
-
-    return System(edges, run_one, rt.parts.block_size, rt.parts.n_partitions,
-                  {"generate_s": t_gen, "build_runtime_s": t_rt})
+    edges = graphs.generate(cell.config["generator"], bench)
+    setup = {"generate_s": time.monotonic() - t}
+    driver = load("drivers", cell.traffic.get("driver", "run_hytm"), bench)
+    run_one, more = driver.prepare(cell.config, cell.traffic, edges, jax)
+    return edges, run_one, {**setup, **more}
 
 
 def main(argv=None, root: Path = ROOT) -> int:
@@ -259,30 +232,30 @@ def main(argv=None, root: Path = ROOT) -> int:
     args = ap.parse_args(argv)
     cell = load_cell(args.workload, root)
     traffic = cell.traffic
+    bench = root / "bench"
     jax, device, peaks, meter = start_jax(cell, root)
-    system = prepare(cell, jax)
-    keys = loadgen.draw_keys(traffic, system.edges, args.seed)
+    edges, run_one, setup = prepare(cell, jax, root)
+    keys = loadgen.draw_keys(traffic, edges, args.seed, bench)
 
     t = time.monotonic()
-    system.run_one(keys[0])
-    system.timings["warmup_s"] = time.monotonic() - t
+    run_one(keys[0])
+    setup["warmup_s"] = time.monotonic() - t
     compiles_setup = meter.compiles
-    print("[setup] " + " ".join(f"{k}={v}" for k, v in system.timings.items())
+    print("[setup] " + " ".join(f"{k}={v}" for k, v in setup.items())
           + f" compile_s={meter.seconds} compiles={compiles_setup}"
-          f" cache_hits={meter.hits}/{meter.requests} block={system.block}"
-          f" partitions={system.partitions}", file=sys.stderr, flush=True)
+          f" cache_hits={meter.hits}/{meter.requests}", file=sys.stderr, flush=True)
 
-    annotate = lambda key: contextlib.nullcontext()  # noqa: E731
+    annotate = lambda index, key: contextlib.nullcontext()  # noqa: E731
     if args.trace:
         trace_dir = tempfile.mkdtemp(prefix="bench-trace-")
         options = jax.profiler.ProfileOptions()
         options.python_tracer_level = 0
         jax.profiler.start_trace(trace_dir, profiler_options=options)
-        annotate = lambda key: jax.profiler.TraceAnnotation(  # noqa: E731
-            tracereduce.RUN_SPAN, key=-1 if key is None else key)
+        annotate = lambda index, key: jax.profiler.TraceAnnotation(  # noqa: E731
+            tracereduce.RUN_SPAN, key=span_key(index, key))
 
     setup_s = time.monotonic() - T_START
-    t0, runs = window(system.run_one, keys[1:], args.seconds,
+    t0, runs = window(run_one, keys[1:], args.seconds,
                       traffic["trace_runs"] if args.trace else None, annotate)
     if args.trace:
         jax.profiler.stop_trace()
@@ -298,12 +271,11 @@ def main(argv=None, root: Path = ROOT) -> int:
           f"peak_bytes={device['memory_peak_bytes']}", file=sys.stderr, flush=True)
 
     # the program's state goes before the reference runs
-    edges = system.edges
-    del system
+    del run_one
     gc.collect()
     ref = Reference(edges)
     for r in runs:
-        r.edges = loadgen.covered_edges(traffic, edges, ref, r.key)
+        r.edges = loadgen.covered_edges(traffic, edges, ref, r.key, bench)
 
     if args.trace:
         ctx = Context(runs=runs, traffic=traffic, peaks=peaks, trace=trace)
@@ -318,7 +290,7 @@ def main(argv=None, root: Path = ROOT) -> int:
                for m in chosen if values[m["name"]] is not None}
 
     checks, wrong = loadgen.compare(
-        traffic, ref, [(r.key, r.values, r.delta) for r in runs], args.seed)
+        traffic, ref, [(r.key, r.values, r.delta) for r in runs], args.seed, bench)
     result = {
         "correct": not wrong and all(v <= lim for v, lim in checks.values()),
         "attempted": len(runs), "failed": len(wrong),

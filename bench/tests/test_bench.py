@@ -7,6 +7,7 @@ with the device check stubbed out.
 
 from __future__ import annotations
 
+import hashlib
 import json
 import os
 import re
@@ -20,6 +21,7 @@ import pytest
 
 import graphs
 import loadgen
+import named
 import run
 import stats
 import tracereduce
@@ -137,7 +139,8 @@ def test_pagerank_readings_of_run_hytm_are_within_limits(kron_graph):
 
     _, g, ref = kron_graph
     res = run_hytm(g, PAGERANK, source=None, config=HyTMConfig(n_partitions=8))
-    pending, gap = loadgen.pagerank_readings(ref, 0.85, res.values, res.delta)
+    readings = named.load("checks", "pagerank").readings
+    pending, gap = readings(ref, 0.85, res.values, res.delta)
     assert pending <= PAGERANK.tolerance
     assert gap < 1e-5
     # and the answer is near the exact fixpoint, as far as the pending
@@ -184,6 +187,68 @@ def test_controls_fail_the_comparison():
     exact_rank = ref.pagerank(0.85)
     checks, wrong = loadgen.compare(pr, ref, [(None, exact_rank, np.zeros_like(rank))], 1)
     assert not wrong
+
+
+# ------------------------------- pins: the cells' graphs, keys and checks
+
+def digest(a: np.ndarray) -> str:
+    a = np.ascontiguousarray(a)
+    return hashlib.sha256(a.dtype.str.encode() + a.tobytes()).hexdigest()[:16]
+
+
+def cell_inputs(traffic: str, scale: int):
+    """The traffic mix and the graph of the cell that runs it, at ``scale``."""
+    spec = json.loads((ROOT / "BENCHMARK.json").read_text())
+    cell = next(w for w in spec["workloads"] if w["traffic"] == traffic)
+    config = json.loads((BENCH / "configs" / f"{cell['config']}.json").read_text())
+    mix = json.loads((BENCH / "traffic" / f"{traffic}.json").read_text())
+    return mix, graphs.generate({**config["generator"], "scale": scale})
+
+
+@pytest.mark.parametrize("config,scale,want", [
+    ("gap-kron-s17", 10, ("603c05639dd74d05", "ca86ba7d72ea8d5a", "eb99f61bc68c0c08")),
+    ("gap-kron-s17", 17, ("d2ec5a68616268f3", "3887688f31e37418", "eaa546d3f81d9c31")),
+    ("gap-urand-s17", 10, ("9f0babdde9acf1a3", "4c42e13acafa7d92", "0c975419e3a0cbff")),
+    ("gap-urand-s17", 17, ("27720f24b9b35609", "278f0c611eee87e2", "7287208b13590c92")),
+])
+def test_pinned_graphs(config, scale, want):
+    """src, dst and weight of each configuration's graph, bit for bit."""
+    spec = json.loads((BENCH / "configs" / f"{config}.json").read_text())["generator"]
+    e = graphs.generate({**spec, "scale": scale})
+    assert (digest(e.src), digest(e.dst), digest(e.weight)) == want
+
+
+@pytest.mark.parametrize("traffic,seed,want", [
+    ("graph500-sssp", 7, "70fd5a5547bd05a3"),
+    ("graph500-sssp", 2**33 + 5, "53208c2a9797c1b8"),
+    ("graph500-bfs", 7, "d1bb67339ebcc1d3"),
+    ("graph500-bfs", 2**33 + 5, "351189cecc75e93b"),
+    ("delta-pagerank", 7, "03aff0b3004a28fb"),
+    ("delta-pagerank", 2**33 + 5, "03aff0b3004a28fb"),
+])
+def test_pinned_keys(traffic, seed, want):
+    """The warm-up key and the window's keys of each cell at its own size."""
+    mix, e = cell_inputs(traffic, 17)
+    keys = loadgen.draw_keys(mix, e, seed)
+    assert len(keys) == mix["key_pool"] + 1
+    assert hashlib.sha256(json.dumps(keys).encode()).hexdigest()[:16] == want
+
+
+@pytest.mark.parametrize("traffic,want,wrong", [
+    ("graph500-sssp", {"unreached": (0, 0), "mismatched": (825, 0)}, list(range(8))),
+    ("graph500-bfs", {"unreached": (6223, 0), "mismatched": (6223, 0)}, list(range(16))),
+    ("delta-pagerank", {"pending_max": (0.0, 0.0010000000474974513),
+                        "invariant_gap": (0.003708240875837696, 1e-05)}, [0]),
+])
+def test_pinned_control_checks(traffic, want, wrong):
+    """The ``[check]`` numbers of each traffic's control at scale 10."""
+    mix, e = cell_inputs(traffic, 10)
+    ref = Reference(e)
+    keys = loadgen.draw_keys(mix, e, 11)[1:1 + mix.get("check_runs", 1)]
+    answers = [(k, *loadgen.control(mix, ref, k)) for k in keys]
+    checks, found = loadgen.compare(mix, ref, answers, 11)
+    assert checks == want
+    assert sorted(found) == wrong
 
 
 # ---------------------------------------------------------- trace reduction
@@ -329,7 +394,13 @@ def test_benchmark_json_names_units_and_files():
         assert all(NAME.match(k) for k in c["reduced"])
     for w in spec["workloads"]:
         names += [w["name"], w["config"], w["traffic"]]
-        assert (BENCH / "traffic" / f"{w['traffic']}.json").exists()
+        mix = json.loads((BENCH / "traffic" / f"{w['traffic']}.json").read_text())
+        config = next(c for c in spec["configs"] if c["name"] == w["config"])
+        kind = json.loads((ROOT / config["file"]).read_text())["generator"]["kind"]
+        for where, name in (("generators", kind), ("keys", mix["keys"]),
+                            ("coverage", mix["coverage"]), ("checks", mix["check"]),
+                            ("drivers", mix.get("driver", "run_hytm"))):
+            assert (BENCH / where / f"{name}.py").is_file(), (where, name)
         assert w["chips"] in (1, 4) and len(w["why"]) <= 200
     for m in spec["end_to_end"] + spec["per_layer"]:
         names.append(m["name"])
@@ -408,6 +479,139 @@ def test_a_new_traffic_file_and_metric_need_no_code(tiny_root, capsys):
     assert run.read_metric("dummy.runs", ctx, tiny_root) == 2.0
     res = result_of(capsys, tiny_root, "kron17-dummy")
     assert res["correct"] is True
+
+
+# A deployment no cell has: WCC on a graph of disjoint blocks, through a
+# driver, keys, coverage and a check of its own, each a new file.
+DEPLOYMENT = {
+    "generators/blocks.py": """
+def generate(spec, rng):
+    n, m = 1 << spec["scale"], spec["edgefactor"] << spec["scale"]
+    src = rng.integers(0, n, m)
+    return src, src ^ rng.integers(0, spec["block"], m)  # within src's block
+""",
+    "keys/rounds.py": """
+def draw(traffic, edges, seed):
+    return [("round", i) for i in range(traffic["key_pool"] + 1)]
+""",
+    "coverage/whole.py": """
+def covered(traffic, edges, ref, key):
+    return edges.m
+""",
+    "drivers/wcc.py": """
+def prepare(config, traffic, edges, jax):
+    from repro.core.hytm import HyTMConfig, build_runtime, run_hytm
+    from repro.graph.algorithms import ALGORITHMS
+    from repro.graph.csr import csr_from_edges
+
+    g = csr_from_edges(edges.n, *edges.directed())
+    cfg = HyTMConfig(**config["hytm"])
+    rt = build_runtime(g, cfg)
+
+    def run_one(key):
+        return run_hytm(g, ALGORITHMS["wcc"], source=None, config=cfg, runtime=rt)
+
+    return run_one, {"partitions": rt.parts.n_partitions}
+""",
+    "checks/components.py": """
+import numpy as np
+
+
+def partition_gap(labels, components):
+    # columns (label, component) beyond as many as there are labels, and
+    # beyond as many as there are components: 0 when the labels define
+    # the components, whatever label each carries
+    pairs = np.unique(np.stack([labels, components]), axis=1).shape[1]
+    return (pairs - len(np.unique(labels))) + (pairs - len(np.unique(components)))
+
+
+def compare(traffic, ref, runs, seed):
+    limit = traffic["limits"]["partition_gap"]
+    gaps = [partition_gap(values, ref.components) for _, values, _ in runs]
+    return ({"partition_gap": (max(gaps), limit)},
+            {i for i, gap in enumerate(gaps) if gap > limit})
+
+
+def control(traffic, ref, key):
+    labels = ref.components.astype(np.float32)
+    labels[labels == labels.max()] = labels.min()  # two components merged
+    return labels, None
+""",
+    "traffic/wcc-rounds.json": json.dumps({
+        "program": "wcc", "driver": "wcc", "keys": "rounds", "key_pool": 4096,
+        "coverage": "whole", "bytes_per_edge": 4, "trace_runs": 2,
+        "check": "components", "limits": {"partition_gap": 0}}),
+    "configs/blocks-s10.json": json.dumps({
+        "generator": {"kind": "blocks", "scale": 10, "edgefactor": 16, "block": 16,
+                      "weights": [1, 1], "seed": 5},
+        "hytm": {"n_partitions": 8}}),
+}
+
+
+def add_deployment(root: Path) -> None:
+    for rel, text in DEPLOYMENT.items():
+        (root / "bench" / rel).write_text(text)
+    spec = json.loads((root / "BENCHMARK.json").read_text())
+    spec["configs"].append({"name": "blocks-s10", "source": "test",
+                            "file": "bench/configs/blocks-s10.json",
+                            "reduced": [], "why": "test"})
+    spec["workloads"].append({"name": "blocks-wcc", "config": "blocks-s10",
+                              "traffic": "wcc-rounds", "chips": 1, "why": "test"})
+    (root / "BENCHMARK.json").write_text(json.dumps(spec))
+
+
+def relabel_one(res):
+    """Vertex 0 takes the label of the last vertex, in another block."""
+    res.values = res.values.copy()
+    res.values[0] = res.values[-1]
+
+
+@pytest.mark.parametrize("fault", [None, relabel_one], ids=["sound", "one-label-altered"])
+def test_a_new_deployment_needs_no_code(tiny_root, capsys, monkeypatch, fault):
+    """A later PR adds a generator, keys, coverage, a driver and a check
+    as files, and the cell runs and is judged like any other."""
+    import repro.core.hytm as hytm
+
+    add_deployment(tiny_root)
+    bench = tiny_root / "bench"
+    cell = run.load_cell("blocks-wcc", tiny_root)
+    edges = graphs.generate(cell.config["generator"], bench)
+    ref = Reference(edges)
+    assert len(np.unique(ref.components)) > 1
+    key = loadgen.draw_keys(cell.traffic, edges, 1, bench)[1]
+    checks, wrong = loadgen.compare(
+        cell.traffic, ref, [(key, *loadgen.control(cell.traffic, ref, key, bench))], 1, bench)
+    assert checks["partition_gap"][0] > 0 and wrong == {0}
+    assert run.span_key(3, key) == 3
+    if fault:
+        monkeypatch.setattr(hytm, "run_hytm", altered(hytm.run_hytm, fault))
+    res = result_of(capsys, tiny_root, "blocks-wcc")
+    assert res["attempted"] > 1
+    assert res["metrics"]["edges_per_s"]["value"] > 0
+    assert res["correct"] is (fault is None)
+    assert res["failed"] == (0 if fault is None else res["attempted"])
+
+
+@pytest.mark.parametrize("kind", ["generators", "keys", "coverage", "checks", "drivers"])
+def test_an_unknown_name_is_refused_naming_its_file(tiny_root, capsys, kind):
+    field = {"keys": "keys", "coverage": "coverage", "checks": "check", "drivers": "driver"}
+    mix = tiny_root / "bench/traffic/graph500-bfs.json"
+    if kind == "generators":
+        mix = tiny_root / "bench/configs/gap-urand-s17.json"
+        config = json.loads(mix.read_text())
+        config["generator"]["kind"] = "nowhere"
+        mix.write_text(json.dumps(config))
+    else:
+        traffic = json.loads(mix.read_text())
+        traffic[field[kind]] = "nowhere"
+        mix.write_text(json.dumps(traffic))
+    with pytest.raises(SystemExit) as e:
+        run.main(["--workload", "urand17-bfs", "--seed", "1", "--seconds", "0.1"],
+                 root=tiny_root)
+    assert e.value.code == 2
+    out, err = capsys.readouterr()
+    assert "{" not in out
+    assert str(tiny_root / "bench" / kind / "nowhere.py") in err
 
 
 def altered(fn, how):

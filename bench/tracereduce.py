@@ -205,3 +205,46 @@ def summary(trace: Trace, k: int = 10):
         "idle_gaps": [[where, ns / 1e9] for where, ns in
                       sorted(idle.items(), key=lambda x: -x[1])[:k]],
     }
+
+
+# ------------------------------------------------ the program's own names
+
+def scope_share(trace: Trace | None, pick) -> float | None:
+    """Percent of the traced runs' device busy time whose op the program
+    maps to a named scope (``repro.obs.scopes``) that ``pick(scopes,
+    scope)`` selects.  Each op counts its own time (less the ops it
+    encloses) under the innermost scope of its HLO instruction in the
+    programs the run compiled.  None without a trace, without a
+    registered program, or from a program that names no scopes.  The
+    shares by scope are kept on the trace, so the scope metrics of one
+    run reduce it once."""
+    try:
+        from repro.obs import scopes
+    except ImportError:  # a program that names no device scopes
+        return None
+    if trace is None:
+        return None
+    memo = vars(trace)
+    if "scope_shares" not in memo:
+        spans = trace.runs()
+        inside = [e for s in spans for e in trace.ops if s.start <= e.start < s.end]
+        times = scopes.scope_times(((e.name, t) for e, t in self_times(inside)), op_label)
+        total = sum(busy(inside, s.start, s.end) for s in spans)
+        memo["scope_shares"] = {s: 100.0 * t / total for s, t in times.items()} or None
+    shares = memo["scope_shares"]
+    return None if shares is None else sum(v for s, v in shares.items() if pick(scopes, s))
+
+
+def phase_idle_ms(trace: Trace | None, phases) -> float | None:
+    """Device-idle time under the program's host spans named in
+    ``phases``, in ms per traced run; None where no run has such a span."""
+    if trace is None or not trace.ops:
+        return None
+    ops = [(e.start, e.end) for e in trace.ops]
+    runs, idle, found = trace.runs(), 0.0, False
+    for run in runs:
+        spans = [(e.start, e.end) for e in trace.host
+                 if e.name in phases and run.start <= e.start < run.end]
+        found = found or bool(spans)
+        idle += sum(covered(gaps(ops, run.start, run.end), lo, hi) for lo, hi in spans)
+    return idle / len(runs) / 1e6 if found else None
