@@ -6,7 +6,8 @@ One observability layer across the engine (``core.hytm``), mesh
 
 * :func:`span` — a live host span: a ``jax.profiler.TraceAnnotation``
   on the profiler's clock, and, given a :class:`TraceRecorder`, a span
-  in its ring too (``trace.py``);
+  in its ring too (``trace.py``); :func:`set_stats` adds the stats its
+  body learns while it is open;
 * ``scopes`` — the ``jax.named_scope`` names of the engine's layers,
   and ``op_scopes()``, the map from each compiled instruction to its
   scope that a device-trace reader needs (``scopes.py``);
@@ -35,7 +36,7 @@ trace.  Gated by ``benchmarks/obs_bench.py --selfcheck``.
 """
 
 from repro.obs.metrics import Counter, Gauge, Histogram, MetricsRegistry
-from repro.obs.trace import TraceEvent, TraceRecorder, span
+from repro.obs.trace import TraceEvent, TraceRecorder, set_stats, span
 from repro.obs.export import (
     reconcile,
     summary,
@@ -52,6 +53,7 @@ __all__ = [
     "TraceEvent",
     "TraceRecorder",
     "reconcile",
+    "set_stats",
     "span",
     "summary",
     "to_chrome_trace",

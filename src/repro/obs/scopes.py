@@ -4,7 +4,8 @@ ops to them.
 A scope is a ``jax.named_scope`` around one layer's traced code.  It
 changes only the ``op_name`` metadata of the ops traced inside it, never
 the compiled ops, so scopes are always on.  ``SCOPES`` lists every scope
-with the scope it nests in:
+of the iteration with the scope it nests in; ``OUTSIDE_SCOPES`` lists
+those of programs outside the iteration, each at the top of its program:
 
 =================  ====================================================
 ``select``         partition stats, Algorithm 1 (or the forced plan),
@@ -22,11 +23,14 @@ with the scope it nests in:
                    once, and its programs have no ``filter.order``
 ``sweep.combine``  a visit's n-wide value, Δ and ``activated`` update
 ``update``         the next frontier, the info rows, the history writes
+``stream.patch``   ``DeltaCSR``'s scatter of a batch's touched lanes
+                   into the device edge arrays (outside the iteration)
 =================  ====================================================
 
 A device trace names an op by its HLO instruction alone.  So the map
 from instruction to scope comes from the compiled program: ``run_hytm``
-registers the abstract signature of each program it compiles
+and ``DeltaCSR``'s patch register the abstract signature of each
+program they compile
 (:func:`register`: shapes, dtypes, shardings and statics, no arrays),
 and :func:`op_scopes` lowers and compiles those signatures when asked,
 which the in-memory or persistent compile cache answers, and reads
@@ -51,6 +55,7 @@ ENGINE_COMPACT = "engine.compact"
 ENGINE_ZEROCOPY = "engine.zerocopy"
 FILTER_ORDER = "filter.order"
 UPDATE = "update"
+STREAM_PATCH = "stream.patch"
 
 # scope -> the scope it nests in (None: directly in the iteration)
 SCOPES: dict[str, str | None] = {
@@ -65,12 +70,16 @@ SCOPES: dict[str, str | None] = {
     UPDATE: None,
 }
 ENGINE_SCOPES = (ENGINE_FILTER, ENGINE_COMPACT, ENGINE_ZEROCOPY)
+OUTSIDE_SCOPES = (STREAM_PATCH,)
+# every scope -> the scope it nests in
+_NESTS_IN = {**SCOPES, **dict.fromkeys(OUTSIDE_SCOPES)}
 
 
 def scope(name: str):
-    """``jax.named_scope(name)`` for one of ``SCOPES``."""
-    if name not in SCOPES:
-        raise KeyError(f"{name!r} is not one of {sorted(SCOPES)}")
+    """``jax.named_scope(name)`` for one of ``SCOPES`` or
+    ``OUTSIDE_SCOPES``."""
+    if name not in _NESTS_IN:
+        raise KeyError(f"{name!r} is not one of {sorted(_NESTS_IN)}")
     return jax.named_scope(name)
 
 
@@ -79,7 +88,7 @@ def within(name: str | None, outer: str) -> bool:
     while name is not None:
         if name == outer:
             return True
-        name = SCOPES[name]
+        name = _NESTS_IN[name]
     return False
 
 
@@ -149,7 +158,7 @@ _TAIL = re.compile(r", (?:metadata|backend_config|custom_call_target)=")
 def innermost(op_name: str) -> str | None:
     """The last component of an ``op_name`` path that is a scope."""
     for part in reversed(op_name.split("/")):
-        if part in SCOPES:
+        if part in _NESTS_IN:
             return part
     return None
 

@@ -148,6 +148,16 @@ def span(
         obs, TraceEvent(name, PH_SPAN, cat, track, 0.0, vt, args=args))
 
 
+def set_stats(opened, **stats: Any) -> None:
+    """Stats a span's body learns while the span is open, on what
+    :func:`span` yielded: the recorded event's ``args`` with a recorder,
+    else the profiler annotation's metadata."""
+    if isinstance(opened, TraceEvent):
+        opened.args.update(stats)
+    else:
+        opened.set_metadata(**stats)
+
+
 @contextlib.contextmanager
 def _recorded_span(obs: TraceRecorder, ev: TraceEvent) -> Iterator[TraceEvent]:
     with jax.profiler.TraceAnnotation(ev.name, **ev.args):

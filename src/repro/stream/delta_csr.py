@@ -28,6 +28,11 @@ Versioning contract (consumed by repro.stream.service's result cache):
 is valid iff the container is still at v.  ``dirty_partitions`` in each
 ``UpdateReport`` names the blocks a batch touched — the granularity at
 which Totem-style hybrid systems track what an update dirties.
+
+Tracing (``repro.obs``): ``apply`` is the host span ``stream.apply``
+(stats ``ops``, ``touched`` lanes, ``merged``), with ``stream.merge``
+around a merge-compaction; the scatter of the touched lanes runs in the
+device scope ``stream.patch``.
 """
 
 from __future__ import annotations
@@ -46,8 +51,22 @@ from repro.core.hytm import HyTMConfig, Runtime
 from repro.core.partition import DevicePartitions, PartitionTable, partition_graph
 from repro.graph.algorithms import VertexProgram
 from repro.graph.csr import CSRGraph, DeviceCSR, csr_from_edges
+from repro.obs import scopes
+from repro.obs.trace import set_stats, span
 
 OP_INSERT, OP_DELETE, OP_REWEIGHT = 0, 1, 2
+
+# the recorder track of the container's host spans
+_TRACK = "stream"
+
+
+@jax.jit
+def _scatter_lanes(edge_src, edge_dst, edge_weight, edge_valid,
+                   idx, src, dst, w, valid):
+    """Write a batch's touched lanes into the four device edge arrays."""
+    with scopes.scope(scopes.STREAM_PATCH):
+        return (edge_src.at[idx].set(src), edge_dst.at[idx].set(dst),
+                edge_weight.at[idx].set(w), edge_valid.at[idx].set(valid))
 
 
 class InvalidBatchError(ValueError):
@@ -364,7 +383,7 @@ class DeltaCSR:
                 counts[(u, v)] = 1  # reweight-of-absent inserts
 
     def apply(self, batch: EdgeBatch, batch_id=None,
-              faults=None) -> UpdateReport:
+              faults=None, obs=None) -> UpdateReport:
         """Apply one batch; patch device buffers (or merge-compact on
         overflow); bump ``version``; return the report.
 
@@ -391,9 +410,22 @@ class DeltaCSR:
         ``resilience.supervisor.deliver_update`` contract).  ``faults``
         injects delivery drops (site ``update_delivery``): a dropped
         batch raises ``UpdateLost`` before validation, exactly as if it
-        never arrived."""
+        never arrived.
+
+        The call is the host span ``stream.apply`` (stats: ``ops``, the
+        batch's length; ``touched``, the lanes written; ``merged``),
+        with ``stream.merge`` around a merge-compaction.  ``obs``, an
+        optional ``repro.obs.TraceRecorder``, records them too;
+        ``obs=None`` changes nothing else."""
+        with span("stream.apply", obs, track=_TRACK, ops=len(batch)) as opened:
+            report, touched = self._apply(batch, batch_id, faults, obs)
+            set_stats(opened, touched=touched, merged=report.merged)
+        return report
+
+    def _apply(self, batch, batch_id, faults, obs) -> tuple[UpdateReport, int]:
+        """``apply``'s body: the report and the number of lanes written."""
         if batch_id is not None and batch_id in self._applied:
-            return self._applied[batch_id]
+            return self._applied[batch_id], 0
         if faults is not None and faults.fire("update_delivery") == "drop":
             from repro.resilience.faults import UpdateLost
 
@@ -437,17 +469,18 @@ class DeltaCSR:
 
         merged = any(extra.values())
         if merged:
-            s, d, w = self.live_edges()
-            for p, lst in extra.items():
-                if not lst:
-                    continue
-                es = np.array([e[0] for e in lst], np.int64)
-                ed = np.array([e[1] for e in lst], np.int64)
-                ew = np.array([e[2] for e in lst], np.float32)
-                s = np.concatenate([s.astype(np.int64), es])
-                d = np.concatenate([d.astype(np.int64), ed])
-                w = np.concatenate([w, ew])
-            self._build_layout(csr_from_edges(self.n_nodes, s, d, w))
+            with span("stream.merge", obs, track=_TRACK):
+                s, d, w = self.live_edges()
+                for p, lst in extra.items():
+                    if not lst:
+                        continue
+                    es = np.array([e[0] for e in lst], np.int64)
+                    ed = np.array([e[1] for e in lst], np.int64)
+                    ew = np.array([e[2] for e in lst], np.float32)
+                    s = np.concatenate([s.astype(np.int64), es])
+                    d = np.concatenate([d.astype(np.int64), ed])
+                    w = np.concatenate([w, ew])
+                self._build_layout(csr_from_edges(self.n_nodes, s, d, w))
             self.layout_version += 1
             self.dirty = set()
             dirty = set(range(self.n_partitions))
@@ -477,7 +510,7 @@ class DeltaCSR:
             self._applied[batch_id] = report
             while len(self._applied) > self.dedup_window:
                 self._applied.pop(next(iter(self._applied)))
-        return report
+        return report, len(touched)
 
     def _insert(self, u, v, wt, p, touched, extra):
         B = self.block_size
@@ -549,12 +582,15 @@ class DeltaCSR:
             # similar size reuse one compiled scatter instead of retracing
             bucket = 1 << int(np.ceil(np.log2(len(idx))))
             idx = np.pad(idx, (0, bucket - len(idx)), mode="edge")
+            c = self.csr
+            lanes = (c.edge_src, c.edge_dst, c.edge_weight, c.edge_valid,
+                     idx.astype(np.int32), self._src[idx], self._dst[idx],
+                     self._w[idx], self._valid[idx])
+            scopes.register((scopes.STREAM_PATCH, bucket, len(self._src)),
+                            _scatter_lanes, *lanes)
+            src, dst, w, valid = _scatter_lanes(*lanes)
             self.csr = dataclasses.replace(
-                self.csr,
-                edge_src=self.csr.edge_src.at[idx].set(self._src[idx]),
-                edge_dst=self.csr.edge_dst.at[idx].set(self._dst[idx]),
-                edge_weight=self.csr.edge_weight.at[idx].set(self._w[idx]),
-                edge_valid=self.csr.edge_valid.at[idx].set(self._valid[idx]),
+                c, edge_src=src, edge_dst=dst, edge_weight=w, edge_valid=valid,
                 out_degree=jnp.asarray(self.out_deg, jnp.int32),
             )
         else:
@@ -577,6 +613,14 @@ class DeltaCSR:
         self._inv_deg_cache.clear()
         for key, rt in self._sharded_views.items():
             self._patch_sharded_view(rt, key[2], idx)
+
+    def precompile_patch(self, lanes: int) -> None:
+        """Compile the patch scatter for a batch that touches ``lanes``
+        lanes (its power-of-two bucket), as a long-running service has
+        before its first update, by writing the first ``lanes`` lanes
+        with what they hold: the edge log, counts and ``version`` stay
+        as they are."""
+        self._patch_device(set(range(min(lanes, len(self._src)))))
 
     def _refresh_seg_start(self, dirty) -> None:
         """Recompute ``seg_start`` for ``dirty`` partitions: vertex v's

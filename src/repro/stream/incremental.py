@@ -31,6 +31,11 @@ Seeding rules by program family:
   core frontiers propagate ``|Δ| > tolerance`` so negative corrections
   travel like positive ones.
 
+The seeding is the host span ``stream.seed`` (stats ``reset``, the
+vertices reset to their init values, and ``seeded``, the frontier it
+seeds), and, given a recorder, the counters ``stream.reset`` and
+``stream.seeded``.
+
 Equivalence contract (tests/test_stream.py): the warm-started run matches
 a from-scratch ``run_hytm`` on the post-update graph — bit-exact for MIN
 programs (the converged fixpoint is unique and each value is the same
@@ -46,7 +51,8 @@ import numpy as np
 
 from repro.core.hytm import HyTMConfig, HyTMResult, HyTMState, run_hytm
 from repro.graph.algorithms import MIN, VertexProgram
-from repro.stream.delta_csr import DeltaCSR, UpdateReport
+from repro.obs.trace import set_stats, span
+from repro.stream.delta_csr import _TRACK, DeltaCSR, UpdateReport
 
 
 def _routed_through(
@@ -74,8 +80,9 @@ def seed_min(
     reports: Sequence[UpdateReport],
     dcsr: DeltaCSR,
     source: int | None,
-) -> HyTMState:
-    """Frontier/state seed for traversal programs (see module docstring)."""
+) -> tuple[HyTMState, int, int]:
+    """Frontier/state seed for traversal programs (see module docstring),
+    with the number of vertices reset and the seeded frontier's size."""
     n = dcsr.n_nodes
     values = np.asarray(values, np.float32).copy()
     init_vals = np.asarray(program.init_state(n, source)[0])
@@ -117,7 +124,7 @@ def seed_min(
         values=jnp.asarray(new_vals),
         delta=jnp.zeros(n, jnp.float32),
         frontier=jnp.asarray(frontier),
-    )
+    ), int(suspect.sum()), int(frontier.sum())
 
 
 def seed_sum(
@@ -126,8 +133,9 @@ def seed_sum(
     delta: np.ndarray,
     reports: Sequence[UpdateReport],
     dcsr: DeltaCSR,
-) -> HyTMState:
-    """Correction-delta seed for accumulative programs."""
+) -> tuple[HyTMState, int, int]:
+    """Correction-delta seed for accumulative programs, with the number of
+    vertices reset (none) and the seeded frontier's size."""
     n = dcsr.n_nodes
     values = np.asarray(values, np.float32)
     new_delta = np.asarray(delta, np.float64).copy()
@@ -159,7 +167,7 @@ def seed_sum(
         values=jnp.asarray(values),
         delta=jnp.asarray(new_delta),
         frontier=jnp.asarray(frontier),
-    )
+    ), 0, int(frontier.sum())
 
 
 def incremental_state(
@@ -169,11 +177,22 @@ def incremental_state(
     reports: Iterable[UpdateReport],
     dcsr: DeltaCSR,
     source: int | None,
+    obs=None,
 ) -> HyTMState:
+    """The warm state to converge from, seeded in the host span
+    ``stream.seed``; ``obs`` (a ``repro.obs.TraceRecorder``) records the
+    span and the counters ``stream.reset`` and ``stream.seeded``."""
     reports = list(reports)
-    if program.combine == MIN:
-        return seed_min(program, values, reports, dcsr, source)
-    return seed_sum(program, values, delta, reports, dcsr)
+    with span("stream.seed", obs, track=_TRACK) as opened:
+        if program.combine == MIN:
+            state, reset, seeded = seed_min(program, values, reports, dcsr, source)
+        else:
+            state, reset, seeded = seed_sum(program, values, delta, reports, dcsr)
+        set_stats(opened, reset=reset, seeded=seeded)
+        if obs is not None:
+            obs.counter("stream.reset", reset, track=_TRACK)
+            obs.counter("stream.seeded", seeded, track=_TRACK)
+    return state
 
 
 def run_incremental(
@@ -220,7 +239,8 @@ def run_incremental(
     device arrays), so the chunked driver's state donation never
     invalidates the caller's cached warm (values, Δ) buffers."""
     config = config if config is not None else dcsr.config
-    state = incremental_state(program, values, delta, reports, dcsr, source)
+    state = incremental_state(program, values, delta, reports, dcsr, source,
+                              obs=obs)
     if config.mesh_axis is not None:
         runtime = dcsr.sharded_runtime_for(
             program, mesh=mesh, axis=config.mesh_axis)

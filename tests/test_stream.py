@@ -232,3 +232,126 @@ def test_incremental_fewer_iterations_on_small_batches():
         np.testing.assert_array_equal(inc.values, fs.values)
         assert inc.iterations < fs.iterations, (inc.iterations, fs.iterations)
         warm = inc
+
+
+# --------------------------------------------------------------------------
+# Tracing: host spans, recorder counters, the patch scatter's device scope
+# --------------------------------------------------------------------------
+
+def _traced_stream(obs, slack=0.0):
+    """A small batch, then one that floods vertex 0 past its block (a
+    merge-compaction), each applied and converged from the last answer;
+    with ``obs`` given to both entry points.  Returns the container and
+    per batch (batch, report, result)."""
+    g = rmat_graph(300, 2400, seed=2)
+    dc = DeltaCSR(g, CFG, slack=slack, min_slack=1)
+    warm = run_hytm(None, SSSP, source=0, config=CFG, runtime=dc.runtime_for(SSSP))
+    small = random_batch(dc, np.random.default_rng(2), n_insert=6, n_delete=6)
+    k = dc.block_size + 8
+    flood = EdgeBatch.inserts(np.zeros(k, np.int64), np.arange(k) % 300,
+                              np.full(k, 7.0, np.float32))
+    out = []
+    for batch in (small, flood):
+        rep = dc.apply(batch, obs=obs)
+        warm = run_incremental(dc, SSSP, [rep], warm.values, warm.delta,
+                               source=0, config=CFG, obs=obs)
+        out.append((batch, rep, warm))
+    return dc, out
+
+
+def test_stream_spans_and_counters_are_recorded():
+    from repro.obs import TraceRecorder
+
+    rec = TraceRecorder()
+    _, steps = _traced_stream(rec)
+    spans = [e for e in rec.events if e.ph == "X" and e.name.startswith("stream.")]
+    assert [e.name for e in spans] == [
+        "stream.apply", "stream.seed", "stream.merge", "stream.apply", "stream.seed"]
+    applies = [e for e in spans if e.name == "stream.apply"]
+    merge = next(e for e in spans if e.name == "stream.merge")
+    for e, (batch, rep, _) in zip(applies, steps):
+        assert e.track == "stream"
+        assert e.args["ops"] == len(batch) and e.args["merged"] == rep.merged
+        assert e.args["touched"] > 0
+    assert [rep.merged for _, rep, _ in steps] == [False, True]
+    assert applies[1].wall <= merge.wall
+    assert merge.wall + merge.wall_dur <= applies[1].wall + applies[1].wall_dur
+    seeds = [e for e in spans if e.name == "stream.seed"]
+    counters = {}
+    for e in rec.events:
+        if e.ph == "C" and e.name.startswith("stream."):
+            counters.setdefault(e.name, []).append(e.args["value"])
+    assert counters["stream.reset"] == [float(e.args["reset"]) for e in seeds]
+    assert counters["stream.seeded"] == [float(e.args["seeded"]) for e in seeds]
+    assert all(e.args["seeded"] > 0 for e in seeds)
+
+
+def test_traced_and_untraced_stream_runs_are_bit_identical():
+    from repro.obs import TraceRecorder
+
+    dc_a, traced = _traced_stream(TraceRecorder())
+    dc_b, bare = _traced_stream(None)
+    for (_, rep_a, res_a), (_, rep_b, res_b) in zip(traced, bare):
+        np.testing.assert_array_equal(res_a.values, res_b.values)
+        np.testing.assert_array_equal(res_a.delta, res_b.delta)
+        assert res_a.iterations == res_b.iterations
+        np.testing.assert_array_equal(rep_a.dirty_partitions, rep_b.dirty_partitions)
+    assert _edge_multiset(dc_a) == _edge_multiset(dc_b)
+    for f in ("edge_src", "edge_dst", "edge_weight", "edge_valid", "out_degree"):
+        np.testing.assert_array_equal(np.asarray(getattr(dc_a.csr, f)),
+                                      np.asarray(getattr(dc_b.csr, f)))
+
+
+def test_stream_patch_maps_to_its_ops():
+    """A patch (no merge) registers its scatter, and ``op_scopes()`` maps
+    the scatter's instructions to ``stream.patch``; the device arrays
+    mirror the host log after it."""
+    from repro.obs import scopes
+
+    g = rmat_graph(300, 2400, seed=3)
+    dc = DeltaCSR(g, CFG)
+    rep = dc.apply(random_batch(dc, np.random.default_rng(3), n_insert=9, n_delete=9))
+    assert not rep.merged
+    patch = [line for line, s in scopes.op_scopes() if s == scopes.STREAM_PATCH]
+    assert sum("scatter" in line.split(" = ", 1)[0] for line in patch) >= 4
+    np.testing.assert_array_equal(np.asarray(dc.csr.edge_src), dc._src)
+    np.testing.assert_array_equal(np.asarray(dc.csr.edge_weight), dc._w)
+    np.testing.assert_array_equal(np.asarray(dc.csr.edge_valid), dc._valid)
+
+
+def test_precompile_patch_changes_nothing():
+    g = rmat_graph(300, 2400, seed=4)
+    dc = DeltaCSR(g, CFG)
+    before = _edge_multiset(dc), dc.version, dc.counts.copy()
+    for lanes in (16, 64):
+        dc.precompile_patch(lanes)
+    assert (_edge_multiset(dc), dc.version) == before[:2]
+    np.testing.assert_array_equal(dc.counts, before[2])
+    np.testing.assert_array_equal(np.asarray(dc.csr.edge_dst), dc._dst)
+    np.testing.assert_array_equal(np.asarray(dc.parts.part_edges), dc.counts)
+
+
+def test_stream_spans_carry_their_stats_on_the_profiler(tmp_path):
+    """Without a recorder the spans are profiler annotations, and their
+    stats (known only when the work is done) are on them."""
+    import jax
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        _, steps = _traced_stream(None)
+    finally:
+        jax.profiler.stop_trace()
+    path = sorted(tmp_path.rglob("*.xplane.pb"))[-1]
+    got = {}
+    for plane in jax.profiler.ProfileData.from_file(str(path)).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("stream."):
+                    got.setdefault(e.name, []).append(dict(e.stats))
+    assert len(got["stream.apply"]) == 2 and len(got["stream.merge"]) == 1
+    for stats, (batch, rep, _) in zip(got["stream.apply"], steps):
+        assert int(stats["ops"]) == len(batch)
+        assert int(stats["touched"]) > 0 and bool(stats["merged"]) == rep.merged
+    assert all({"reset", "seeded"} <= set(s) for s in got["stream.seed"])

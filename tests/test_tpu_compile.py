@@ -105,21 +105,22 @@ def test_hyb_gather_compiles_for_v5e(shape):
         shape((windows,), jnp.int32))
 
 
-def _chunk_program_for_v5e(shape, monkeypatch, routed: bool):
+def _chunk_program_for_v5e(shape, monkeypatch, routed: bool, stream: bool = False):
     """The whole chunk program as the chip compiles it, at scale 10, with
-    or without the route built with the runtime: (instruction scopes,
-    block size)."""
+    or without the route built with the runtime, or over a ``DeltaCSR``
+    view (``stream``): (instruction scopes, block size)."""
     import repro.kernels.runtime as runtime
     from repro.core.cost_model import init_history_buffers
     from repro.core.hytm import HyTMConfig, HyTMState, _iteration_impl, build_runtime, hytm_chunk
     from repro.graph.algorithms import SSSP
     from repro.graph.generators import rmat_graph
     from repro.obs import scopes
+    from repro.stream import DeltaCSR
 
     monkeypatch.setattr(runtime, "on_tpu", lambda: True)  # compiled kernels
     g = rmat_graph(1 << 10, 16 << 10, seed=3)
     cfg = HyTMConfig(n_partitions=8, sync_every=4)
-    rt = build_runtime(g, cfg)
+    rt = DeltaCSR(g, cfg).runtime_for(SSSP) if stream else build_runtime(g, cfg)
     state = HyTMState(*SSSP.init_state(g.n_nodes, 0))
     args = (rt.csr, rt.parts, rt.zc_req, rt.inv_deg, SSSP, cfg, rt.n_hub_partitions)
     info = jax.eval_shape(lambda s: _iteration_impl(s, *args)[1], state)
@@ -165,3 +166,36 @@ def test_prebuilt_route_chunk_program_sorts_no_block_for_v5e(topo, shape, monkey
     got, block_size = _chunk_program_for_v5e(shape, monkeypatch, routed=True)
     assert {s for _, s in got} - {None} == set(scopes.SCOPES) - {scopes.FILTER_ORDER}
     assert _kernels_and_block_sorts(got, block_size) == []
+
+
+def test_delta_csr_view_chunk_program_routes_per_call_for_v5e(topo, shape, monkeypatch):
+    """The chunk program over a ``DeltaCSR`` view, as the stream cell
+    runs it: its slack blocks carry no route, so the kernels keep their
+    names and scopes and the per-call sort of the block maps to
+    ``filter.order``."""
+    from repro.obs import scopes
+
+    got, block_size = _chunk_program_for_v5e(shape, monkeypatch, routed=False, stream=True)
+    assert set(scopes.SCOPES) <= {s for _, s in got}
+    block_sorts = _kernels_and_block_sorts(got, block_size)
+    assert block_sorts and set(block_sorts) == {"filter.order"}
+
+
+@pytest.mark.parametrize("lanes", [512, 1024, 2048, 4096])
+def test_stream_patch_compiles_for_v5e(shape, lanes):
+    """``DeltaCSR``'s patch scatter at the stream cell's widths (2^17
+    vertices, P = 64 blocks of B = 140,544 lanes) for each bucket of
+    touched lanes its batches reach: the fusions that scatter the lanes
+    map to ``stream.patch``."""
+    from repro.obs import scopes
+    from repro.stream.delta_csr import _scatter_lanes
+
+    cap = 64 * 140_544
+    text = _scatter_lanes.lower(
+        shape((cap,), jnp.int32), shape((cap,), jnp.int32),
+        shape((cap,), jnp.float32), shape((cap,), jnp.bool_),
+        shape((lanes,), jnp.int32), shape((lanes,), jnp.int32),
+        shape((lanes,), jnp.int32), shape((lanes,), jnp.float32),
+        shape((lanes,), jnp.bool_)).compile().as_text()
+    fusions = {s for line, s in scopes.instruction_scopes(text) if " fusion(" in line}
+    assert " scatter(" in text and fusions == {scopes.STREAM_PATCH}
