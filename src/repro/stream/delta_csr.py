@@ -29,14 +29,23 @@ is valid iff the container is still at v.  ``dirty_partitions`` in each
 ``UpdateReport`` names the blocks a batch touched — the granularity at
 which Totem-style hybrid systems track what an update dirties.
 
+Host cost of a batch: ``apply`` reads the live prefix of each block that
+holds one of the batch's sources once, in NumPy, and keeps from it a
+batch-local lane index (``_LaneIndex``): every lane whose source the
+batch names, and for each (src, dst) pair the batch names its live
+lanes in ascending order.  Validation, the per-op slot lookups and the
+``pre_adj``/``post_adj`` snapshots all read that index, so the Python
+work per batch is per op, not per lane of a hub's adjacency.
+
 Tracing (``repro.obs``): ``apply`` is the host span ``stream.apply``
-(stats ``ops``, ``touched`` lanes, ``merged``), with ``stream.merge``
-around a merge-compaction; the scatter of the touched lanes runs in the
-device scope ``stream.patch``.
+(stats ``ops``, ``touched`` lanes, ``merged``, ``scanned`` log lanes
+read), with ``stream.merge`` around a merge-compaction; the scatter of
+the touched lanes runs in the device scope ``stream.patch``.
 """
 
 from __future__ import annotations
 
+import bisect
 import dataclasses
 from collections import defaultdict
 from dataclasses import dataclass, field
@@ -151,6 +160,28 @@ class UpdateReport:
         return np.unique(
             np.concatenate([self.ins_src, self.ins_dst, self.del_src, self.del_dst])
         )
+
+
+@dataclass
+class _LaneIndex:
+    """One batch's view of the edge log, read before any mutation.
+
+    ``lanes`` holds every live block lane whose source is one of the
+    batch's ``sources`` (sorted, unique; ``is_src`` marks them), grouped
+    by that source (``src``, ascending) with ascending lanes within a
+    source; ``scanned`` counts the live lanes read to find them.
+    ``codes`` names each op's (src, dst) pair as ``src * n + dst``, and
+    ``slots`` maps each named pair to its live block lanes in ascending
+    order — the first is the pair's "first live" copy — which ``apply``
+    keeps true as it inserts and swap-removes."""
+
+    sources: np.ndarray
+    is_src: np.ndarray
+    scanned: int
+    lanes: np.ndarray
+    src: np.ndarray
+    codes: np.ndarray
+    slots: dict[int, list[int]]
 
 
 class DeltaCSR:
@@ -312,31 +343,99 @@ class DeltaCSR:
         return csr_from_edges(self.n_nodes, s.astype(np.int64),
                               d.astype(np.int64), w)
 
-    def _out_edges(self, u: int, extra=None) -> tuple[np.ndarray, np.ndarray]:
-        p = int(self.vertex_part[u])
-        lo = p * self.block_size
-        hi = lo + int(self.counts[p])
-        m = self._src[lo:hi] == u
-        dsts, ws = self._dst[lo:hi][m].copy(), self._w[lo:hi][m].copy()
-        if extra and extra.get(p):
-            ex = [(v, ew) for (eu, v, ew) in extra[p] if eu == u]
-            if ex:
-                dsts = np.concatenate([dsts, np.array([v for v, _ in ex], dsts.dtype)])
-                ws = np.concatenate([ws, np.array([ew for _, ew in ex], np.float32)])
-        return dsts, ws
+    def _source_lanes(self, parts: np.ndarray,
+                      is_src: np.ndarray) -> np.ndarray:
+        """The live lanes of ``parts``' blocks whose source ``is_src``
+        marks, ascending: one NumPy pass over each block's live prefix."""
+        B = self.block_size
+        found = [np.zeros(0, np.int64)]
+        for p in parts.tolist():
+            lo = p * B
+            live = self._src[lo:lo + int(self.counts[p])]
+            found.append(lo + np.flatnonzero(is_src[live]))
+        return np.concatenate(found)
+
+    def _lane_index(self, batch: EdgeBatch) -> _LaneIndex:
+        """Read the log once for ``batch`` (its endpoints in range): the
+        lanes of its sources, grouped, and the live lanes of each pair it
+        names."""
+        n = self.n_nodes
+        sources = np.unique(batch.src)
+        is_src = np.zeros(n, bool)
+        is_src[sources] = True
+        parts = np.unique(self.vertex_part[sources])
+        lanes = self._source_lanes(parts, is_src)
+        src = self._src[lanes]
+        order = np.argsort(src, kind="stable")
+        lanes, src = lanes[order], src[order]
+        dst = self._dst[lanes]
+        codes = batch.src * n + batch.dst
+        named = np.unique(codes)
+        pair_src, pair_dst = np.divmod(named, n)
+        pair_src = pair_src.astype(src.dtype)  # no cast of the haystack
+        lo = np.searchsorted(src, pair_src, "left").tolist()
+        hi = np.searchsorted(src, pair_src, "right").tolist()
+        # within a source's run the lanes ascend, so each list is sorted
+        slots = {c: lanes[a:b][dst[a:b] == v].tolist()
+                 for c, v, a, b in zip(named.tolist(), pair_dst.tolist(),
+                                       lo, hi)}
+        return _LaneIndex(sources, is_src, int(self.counts[parts].sum()),
+                          lanes, src, codes, slots)
+
+    def _regrouped(self, index: _LaneIndex, touched: set[int]
+                   ) -> tuple[np.ndarray, np.ndarray]:
+        """``index``'s grouped (lanes, sources) as the log now holds them:
+        only ``touched`` lanes changed, so drop those and merge back the
+        ones that now hold a live edge of one of the batch's sources."""
+        t = np.fromiter(sorted(touched), np.int64, len(touched))
+        hit = np.zeros(len(self._src), bool)
+        hit[t] = True
+        keep = ~hit[index.lanes]
+        lanes, src = index.lanes[keep], index.src[keep]
+        t = t[self._valid[t] & index.is_src[self._src[t]]]
+        ts = self._src[t]
+        order = np.argsort(ts, kind="stable")
+        t, ts = t[order], ts[order]
+        cap = len(self._src)
+        at = np.searchsorted(src.astype(np.int64) * cap + lanes,
+                             ts.astype(np.int64) * cap + t)
+        return np.insert(lanes, at, t), np.insert(src, at, ts)
+
+    def _adjacency(self, lanes: np.ndarray, src: np.ndarray,
+                   sources: np.ndarray, extra=None
+                   ) -> dict[int, tuple[np.ndarray, np.ndarray]]:
+        """Out-adjacency (dsts, weights) of each of ``sources`` (sorted)
+        from ``lanes`` grouped by their sources ``src`` (ascending lanes
+        within a source): its block lanes in lane order, then its spilled
+        ``extra`` edges in the order they spilled."""
+        dst, w = self._dst[lanes], self._w[lanes]
+        keys = sources.astype(src.dtype)
+        lo = np.searchsorted(src, keys, "left").tolist()
+        hi = np.searchsorted(src, keys, "right").tolist()
+        adj = {u: (dst[a:b], w[a:b])
+               for u, a, b in zip(sources.tolist(), lo, hi)}
+        spill: dict[int, list] = defaultdict(list)
+        for lst in (extra or {}).values():
+            for u, v, wt in lst:
+                spill[u].append((v, wt))
+        for u, ex in spill.items():
+            d, ws = adj[u]
+            adj[u] = (
+                np.concatenate([d, np.array([v for v, _ in ex], d.dtype)]),
+                np.concatenate([ws, np.array([x for _, x in ex], np.float32)]))
+        return adj
 
     # ---------------------------------------------------------------- updates
-    def validate_batch(self, batch: EdgeBatch) -> None:
+    def validate_batch(self, batch: EdgeBatch) -> _LaneIndex:
         """Reject a malformed batch *before any mutation*: unknown ops,
         negative/out-of-range endpoints, non-finite weights on
         INSERT/REWEIGHT, and delete-of-absent-edge (checked against the
         live multiset with the batch's own earlier entries applied, so
         insert-then-delete within one batch is legal).  Raises
         :class:`InvalidBatchError`; on return ``apply`` is guaranteed to
-        succeed without partial effects."""
+        succeed without partial effects.  Returns the batch's lane index,
+        which ``apply`` goes on to use."""
         n = self.n_nodes
-        if len(batch) == 0:
-            return
         bad = np.nonzero(~np.isin(batch.op, (OP_INSERT, OP_DELETE,
                                              OP_REWEIGHT)))[0]
         if bad.size:
@@ -356,31 +455,25 @@ class DeltaCSR:
             i = int(bad[0])
             raise InvalidBatchError(
                 f"non-finite weight {float(batch.weight[i])}", i)
-        # delete-of-absent: walk the batch against lazily-seeded live
-        # (u, v) multiset counts — mirrors apply's multigraph semantics
-        # (DELETE matches one live parallel copy; REWEIGHT of an absent
-        # edge degenerates to an insert)
-        counts: dict[tuple[int, int], int] = {}
-        seeded: set[int] = set()
-        for i in range(len(batch)):
-            u, v = int(batch.src[i]), int(batch.dst[i])
-            if u not in seeded:
-                seeded.add(u)
-                dsts, _ = self._out_edges(u)
-                for d in dsts:
-                    key = (u, int(d))
-                    counts[key] = counts.get(key, 0) + 1
-            o = int(batch.op[i])
+        index = self._lane_index(batch)
+        # delete-of-absent: walk the batch against the live multiset
+        # counts of the (u, v) pairs it names — mirrors apply's multigraph
+        # semantics (DELETE matches one live parallel copy; REWEIGHT of an
+        # absent edge degenerates to an insert)
+        counts = {c: len(lanes) for c, lanes in index.slots.items()}
+        for i, (o, c) in enumerate(zip(batch.op.tolist(),
+                                       index.codes.tolist())):
             if o == OP_INSERT:
-                counts[(u, v)] = counts.get((u, v), 0) + 1
+                counts[c] += 1
             elif o == OP_DELETE:
-                c = counts.get((u, v), 0)
-                if c <= 0:
+                if counts[c] <= 0:
                     raise InvalidBatchError(
-                        f"delete of absent edge ({u}, {v})", i)
-                counts[(u, v)] = c - 1
-            elif counts.get((u, v), 0) == 0:
-                counts[(u, v)] = 1  # reweight-of-absent inserts
+                        f"delete of absent edge ({int(batch.src[i])}, "
+                        f"{int(batch.dst[i])})", i)
+                counts[c] -= 1
+            elif counts[c] == 0:
+                counts[c] = 1  # reweight-of-absent inserts
+        return index
 
     def apply(self, batch: EdgeBatch, batch_id=None,
               faults=None, obs=None) -> UpdateReport:
@@ -413,59 +506,63 @@ class DeltaCSR:
         never arrived.
 
         The call is the host span ``stream.apply`` (stats: ``ops``, the
-        batch's length; ``touched``, the lanes written; ``merged``),
-        with ``stream.merge`` around a merge-compaction.  ``obs``, an
-        optional ``repro.obs.TraceRecorder``, records them too;
-        ``obs=None`` changes nothing else."""
+        batch's length; ``touched``, the lanes written; ``merged``;
+        ``scanned``, the live lanes of the blocks that hold the batch's
+        sources, which its lane index read), with ``stream.merge``
+        around a merge-compaction.  ``obs``, an optional
+        ``repro.obs.TraceRecorder``, records them too; ``obs=None``
+        changes nothing else."""
         with span("stream.apply", obs, track=_TRACK, ops=len(batch)) as opened:
-            report, touched = self._apply(batch, batch_id, faults, obs)
-            set_stats(opened, touched=touched, merged=report.merged)
+            report, touched, scanned = self._apply(batch, batch_id, faults, obs)
+            set_stats(opened, touched=touched, merged=report.merged,
+                      scanned=scanned)
         return report
 
-    def _apply(self, batch, batch_id, faults, obs) -> tuple[UpdateReport, int]:
-        """``apply``'s body: the report and the number of lanes written."""
+    def _apply(self, batch, batch_id, faults, obs
+               ) -> tuple[UpdateReport, int, int]:
+        """``apply``'s body: the report, the number of lanes written and
+        the number of live lanes the lane index read."""
         if batch_id is not None and batch_id in self._applied:
-            return self._applied[batch_id], 0
+            return self._applied[batch_id], 0, 0
         if faults is not None and faults.fire("update_delivery") == "drop":
             from repro.resilience.faults import UpdateLost
 
             raise UpdateLost("update_delivery", 0,
                              f"injected drop of batch {batch_id!r}")
-        self.validate_batch(batch)
+        index = self.validate_batch(batch)
+        pre_adj = self._adjacency(index.lanes, index.src, index.sources)
 
-        affected = np.unique(batch.src)
-        pre_adj = {int(u): self._out_edges(int(u)) for u in affected}
-
+        slots = index.slots
         touched: set[int] = set()
         dirty: set[int] = set()
         extra: dict[int, list] = defaultdict(list)
         ins_rec: list[tuple] = []
         del_rec: list[tuple] = []
 
-        for i in range(len(batch)):
-            o = int(batch.op[i])
-            u, v = int(batch.src[i]), int(batch.dst[i])
-            wt = float(batch.weight[i])
+        for o, u, v, wt, c in zip(batch.op.tolist(), batch.src.tolist(),
+                                  batch.dst.tolist(), batch.weight.tolist(),
+                                  index.codes.tolist()):
             p = int(self.vertex_part[u])
             dirty.add(p)
             if o == OP_INSERT:
-                self._insert(u, v, wt, p, touched, extra)
+                self._insert(u, v, wt, p, touched, extra, slots[c])
                 ins_rec.append((u, v, wt))
             elif o == OP_DELETE:
-                old = self._delete(u, v, p, touched, extra)
+                old = self._delete(u, v, p, touched, extra, slots, c)
                 if old is not None:
                     del_rec.append((u, v, old))
             elif o == OP_REWEIGHT:
-                old = self._reweight(u, v, wt, p, touched, extra)
+                old = self._reweight(u, v, wt, p, touched, extra, slots[c])
                 if old is None:  # absent edge: reweight degenerates to insert
-                    self._insert(u, v, wt, p, touched, extra)
+                    self._insert(u, v, wt, p, touched, extra, slots[c])
                 else:
                     del_rec.append((u, v, old))
                 ins_rec.append((u, v, wt))
             else:
                 raise ValueError(f"unknown op {o}")
 
-        post_adj = {int(u): self._out_edges(int(u), extra) for u in affected}
+        post_adj = self._adjacency(*self._regrouped(index, touched),
+                                   index.sources, extra)
 
         merged = any(extra.values())
         if merged:
@@ -510,9 +607,11 @@ class DeltaCSR:
             self._applied[batch_id] = report
             while len(self._applied) > self.dedup_window:
                 self._applied.pop(next(iter(self._applied)))
-        return report, len(touched)
+        return report, len(touched), index.scanned
 
-    def _insert(self, u, v, wt, p, touched, extra):
+    def _insert(self, u, v, wt, p, touched, extra, lanes):
+        """Append (u, v, wt) to p's block, or spill it; ``lanes`` is the
+        pair's slot list."""
         B = self.block_size
         if int(self.counts[p]) < B and not extra.get(p):
             slot = p * B + int(self.counts[p])
@@ -520,20 +619,15 @@ class DeltaCSR:
             self._w[slot], self._valid[slot] = wt, True
             self.counts[p] += 1
             touched.add(slot)
+            lanes.append(slot)  # the block's new highest live lane
         else:
             # block full (or already spilling): spill to the merge log
             extra[p].append((u, v, wt))
         self.out_deg[u] += 1
 
-    def _find_slot(self, u, v, p) -> int | None:
-        lo = p * self.block_size
-        hi = lo + int(self.counts[p])
-        hits = np.nonzero((self._src[lo:hi] == u) & (self._dst[lo:hi] == v))[0]
-        return int(lo + hits[0]) if len(hits) else None
-
-    def _delete(self, u, v, p, touched, extra) -> float | None:
-        slot = self._find_slot(u, v, p)
-        if slot is None:
+    def _delete(self, u, v, p, touched, extra, slots, code) -> float | None:
+        lanes = slots[code]
+        if not lanes:
             for j, (eu, ev, ew) in enumerate(extra.get(p, ())):
                 if eu == u and ev == v:
                     extra[p].pop(j)
@@ -542,8 +636,17 @@ class DeltaCSR:
             # unreachable after validate_batch (delete-of-absent is
             # rejected up front); kept as a defensive no-op
             return None
+        slot = lanes.pop(0)  # the first live (u, v)
         old = float(self._w[slot])
         last = p * self.block_size + int(self.counts[p]) - 1
+        if last != slot:
+            # ``last`` is the block's highest live lane, so it ends its
+            # pair's slot list; it moves down to ``slot``
+            moved = slots.get(int(self._src[last]) * self.n_nodes
+                              + int(self._dst[last]))
+            if moved is not None:
+                moved.pop()
+                bisect.insort(moved, slot)
         # swap-remove keeps the live prefix dense (edge order is free)
         self._src[slot], self._dst[slot] = self._src[last], self._dst[last]
         self._w[slot] = self._w[last]
@@ -555,14 +658,14 @@ class DeltaCSR:
         self.out_deg[u] -= 1
         return old
 
-    def _reweight(self, u, v, wt, p, touched, extra) -> float | None:
-        slot = self._find_slot(u, v, p)
-        if slot is None:
+    def _reweight(self, u, v, wt, p, touched, extra, lanes) -> float | None:
+        if not lanes:
             for j, (eu, ev, ew) in enumerate(extra.get(p, ())):
                 if eu == u and ev == v:
                     extra[p][j] = (u, v, wt)
                     return float(ew)
             return None
+        slot = lanes[0]  # the first live (u, v)
         old = float(self._w[slot])
         self._w[slot] = wt
         touched.add(slot)

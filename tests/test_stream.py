@@ -109,6 +109,446 @@ def test_delta_csr_overflow_merges():
 
 
 # --------------------------------------------------------------------------
+# apply's batch-local lane index against the per-op scan it replaced
+# --------------------------------------------------------------------------
+
+class _ScanLog:
+    """The per-op algorithm ``DeltaCSR.apply`` replaced, kept as the
+    oracle, on a copy of a container's host log: validation seeds each
+    source's (u, v) counts from a walk over its whole live adjacency,
+    every DELETE/REWEIGHT scans the block for the first live (u, v), and
+    the adjacency snapshots scan the block once per source."""
+
+    def __init__(self, dc):
+        self.n, self.B = dc.n_nodes, dc.block_size
+        self.part = dc.vertex_part
+        self.src, self.dst, self.w, self.valid = (
+            a.copy() for a in (dc._src, dc._dst, dc._w, dc._valid))
+        self.counts, self.out_deg = dc.counts.copy(), dc.out_deg.copy()
+
+    def out_edges(self, u, extra=None):
+        p = int(self.part[u])
+        lo = p * self.B
+        hi = lo + int(self.counts[p])
+        m = self.src[lo:hi] == u
+        dsts, ws = self.dst[lo:hi][m].copy(), self.w[lo:hi][m].copy()
+        ex = [(v, ew) for (eu, v, ew) in (extra or {}).get(p, ()) if eu == u]
+        if ex:
+            dsts = np.concatenate([dsts, np.array([v for v, _ in ex], dsts.dtype)])
+            ws = np.concatenate([ws, np.array([ew for _, ew in ex], np.float32)])
+        return dsts, ws
+
+    def validate(self, batch):
+        n = self.n
+        bad = np.nonzero(~np.isin(batch.op, (0, 1, 2)))[0]
+        if bad.size:
+            i = int(bad[0])
+            raise InvalidBatchError(f"unknown op {int(batch.op[i])}", i)
+        bad = np.nonzero((batch.src < 0) | (batch.src >= n)
+                         | (batch.dst < 0) | (batch.dst >= n))[0]
+        if bad.size:
+            i = int(bad[0])
+            raise InvalidBatchError(
+                f"edge endpoint out of range: ({int(batch.src[i])}, "
+                f"{int(batch.dst[i])}) with n_nodes={n} (vertex set is "
+                "fixed)", i)
+        writes = (batch.op == 0) | (batch.op == 2)
+        bad = np.nonzero(writes & ~np.isfinite(batch.weight))[0]
+        if bad.size:
+            i = int(bad[0])
+            raise InvalidBatchError(
+                f"non-finite weight {float(batch.weight[i])}", i)
+        counts, seeded = {}, set()
+        for i in range(len(batch)):
+            u, v = int(batch.src[i]), int(batch.dst[i])
+            if u not in seeded:
+                seeded.add(u)
+                for d in self.out_edges(u)[0]:
+                    counts[(u, int(d))] = counts.get((u, int(d)), 0) + 1
+            o = int(batch.op[i])
+            if o == 0:
+                counts[(u, v)] = counts.get((u, v), 0) + 1
+            elif o == 1:
+                c = counts.get((u, v), 0)
+                if c <= 0:
+                    raise InvalidBatchError(f"delete of absent edge ({u}, {v})", i)
+                counts[(u, v)] = c - 1
+            elif counts.get((u, v), 0) == 0:
+                counts[(u, v)] = 1
+
+    def find_slot(self, u, v, p):
+        lo = p * self.B
+        hi = lo + int(self.counts[p])
+        hits = np.nonzero((self.src[lo:hi] == u) & (self.dst[lo:hi] == v))[0]
+        return int(lo + hits[0]) if len(hits) else None
+
+    def insert(self, u, v, wt, p, touched, extra):
+        if int(self.counts[p]) < self.B and not extra.get(p):
+            slot = p * self.B + int(self.counts[p])
+            self.src[slot], self.dst[slot] = u, v
+            self.w[slot], self.valid[slot] = wt, True
+            self.counts[p] += 1
+            touched.add(slot)
+        else:
+            extra[p].append((u, v, wt))
+        self.out_deg[u] += 1
+
+    def delete(self, u, v, p, touched, extra):
+        slot = self.find_slot(u, v, p)
+        if slot is None:
+            for j, (eu, ev, ew) in enumerate(extra.get(p, ())):
+                if eu == u and ev == v:
+                    extra[p].pop(j)
+                    self.out_deg[u] -= 1
+                    return float(ew)
+            raise AssertionError("validated batches delete live edges")
+        old = float(self.w[slot])
+        last = p * self.B + int(self.counts[p]) - 1
+        self.src[slot], self.dst[slot] = self.src[last], self.dst[last]
+        self.w[slot] = self.w[last]
+        self.src[last], self.dst[last] = 0, 0
+        self.w[last], self.valid[last] = np.float32(np.inf), False
+        self.counts[p] -= 1
+        touched.update((slot, last))
+        self.out_deg[u] -= 1
+        return old
+
+    def reweight(self, u, v, wt, p, touched, extra):
+        slot = self.find_slot(u, v, p)
+        if slot is None:
+            for j, (eu, ev, ew) in enumerate(extra.get(p, ())):
+                if eu == u and ev == v:
+                    extra[p][j] = (u, v, wt)
+                    return float(ew)
+            return None
+        old = float(self.w[slot])
+        self.w[slot] = wt
+        touched.add(slot)
+        return old
+
+    def apply(self, batch):
+        """What ``apply`` must do to the log: the report's fields, the
+        touched lanes, the spilled edges and the dirty partitions."""
+        from collections import defaultdict
+
+        self.validate(batch)
+        affected = np.unique(batch.src)
+        pre = {int(u): self.out_edges(int(u)) for u in affected}
+        touched, dirty, extra = set(), set(), defaultdict(list)
+        ins, dels = [], []
+        for i in range(len(batch)):
+            o, u, v = int(batch.op[i]), int(batch.src[i]), int(batch.dst[i])
+            wt, p = float(batch.weight[i]), int(self.part[u])
+            dirty.add(p)
+            if o == 0:
+                self.insert(u, v, wt, p, touched, extra)
+                ins.append((u, v, wt))
+            elif o == 1:
+                dels.append((u, v, self.delete(u, v, p, touched, extra)))
+            else:
+                old = self.reweight(u, v, wt, p, touched, extra)
+                if old is None:
+                    self.insert(u, v, wt, p, touched, extra)
+                else:
+                    dels.append((u, v, old))
+                ins.append((u, v, wt))
+        post = {int(u): self.out_edges(int(u), extra) for u in affected}
+        return {"pre_adj": pre, "post_adj": post, "ins": ins, "dels": dels,
+                "touched": touched, "dirty": dirty, "extra": extra}
+
+    def merged_graph(self, extra):
+        """The graph a merge-compaction rebuilds from: live log, then spills."""
+        from repro.graph.csr import csr_from_edges
+
+        s, d, w = (a[self.valid] for a in (self.src, self.dst, self.w))
+        s, d = s.astype(np.int64), d.astype(np.int64)
+        for lst in extra.values():
+            if lst:
+                s = np.concatenate([s, np.array([e[0] for e in lst], np.int64)])
+                d = np.concatenate([d, np.array([e[1] for e in lst], np.int64)])
+                w = np.concatenate([w, np.array([e[2] for e in lst], np.float32)])
+        return csr_from_edges(self.n, s, d, w)
+
+
+def _same_array(got, want):
+    assert got.dtype == want.dtype, (got.dtype, want.dtype)
+    np.testing.assert_array_equal(got, want)
+
+
+def _apply_against_scan(dc, batches):
+    """Apply each batch to ``dc`` and to a ``_ScanLog`` of its log; the
+    host log, counts, degrees, versions, report and touched lanes (or,
+    on a merge, the graph it rebuilds from) must be identical."""
+    seen = {}
+    patch, build = dc._patch_device, dc._build_layout
+
+    def recording_patch(touched, dirty=frozenset()):
+        seen["touched"] = set(touched)
+        return patch(touched, dirty)
+
+    def recording_build(g):
+        seen["merged_from"] = g
+        return build(g)
+
+    dc._patch_device, dc._build_layout = recording_patch, recording_build
+    merges = 0
+    for batch in batches:
+        ref = _ScanLog(dc)
+        want = ref.apply(batch)
+        version, layout, dirty = dc.version, dc.layout_version, set(dc.dirty)
+        seen.clear()
+        rep = dc.apply(batch)
+        for side in ("pre_adj", "post_adj"):
+            got, exp = getattr(rep, side), want[side]
+            assert list(got) == list(exp)
+            for u in exp:
+                for a, b in zip(got[u], exp[u]):
+                    _same_array(a, b)
+        for name, rec, j, dt in (("ins_src", "ins", 0, np.int64),
+                                 ("ins_dst", "ins", 1, np.int64),
+                                 ("ins_w", "ins", 2, np.float32),
+                                 ("del_src", "dels", 0, np.int64),
+                                 ("del_dst", "dels", 1, np.int64),
+                                 ("del_w", "dels", 2, np.float32)):
+            _same_array(getattr(rep, name),
+                        np.array([r[j] for r in want[rec]], dt))
+        merged = any(want["extra"].values())
+        assert rep.merged == merged and dc.version == version + 1 == rep.version
+        if merged:
+            merges += 1
+            assert "touched" not in seen and dc.layout_version == layout + 1
+            g, exp = seen["merged_from"], ref.merged_graph(want["extra"])
+            for f in ("indptr", "indices", "weights"):
+                _same_array(getattr(g, f), getattr(exp, f))
+            _same_array(rep.dirty_partitions, np.arange(dc.n_partitions))
+            assert dc.dirty == set()
+        else:
+            assert seen["touched"] == want["touched"]
+            assert dc.layout_version == layout
+            for got, exp in ((dc._src, ref.src), (dc._dst, ref.dst),
+                             (dc._w, ref.w), (dc._valid, ref.valid),
+                             (dc.counts, ref.counts), (dc.out_deg, ref.out_deg)):
+                _same_array(got, exp)
+            _same_array(rep.dirty_partitions,
+                        np.array(sorted(want["dirty"]), np.int64))
+            assert dc.dirty == dirty | want["dirty"]
+            np.testing.assert_array_equal(np.asarray(dc.csr.edge_src), dc._src)
+            np.testing.assert_array_equal(np.asarray(dc.csr.edge_weight), dc._w)
+    return merges
+
+
+def _graph(n, edges):
+    from repro.graph.csr import csr_from_edges
+
+    s, d, w = (np.array(c) for c in zip(*edges))
+    return csr_from_edges(n, s, d, w.astype(np.float32))
+
+
+def _ops(*rows):
+    """An ``EdgeBatch`` from (op, src, dst, weight) rows."""
+    op, s, d, w = zip(*rows)
+    return EdgeBatch(np.array(op), np.array(s), np.array(d),
+                     np.array(w, np.float32))
+
+
+def _background(n, k, seed):
+    rng = np.random.default_rng(seed)
+    return [(int(a), int(b), float(c)) for a, b, c in zip(
+        rng.integers(0, n, k), rng.integers(0, n, k), rng.integers(1, 9, k))]
+
+
+def _parallel_copies():
+    # (0, 1) three times with different weights: the lowest lane goes first
+    g = _graph(12, [(0, 1, 5.0), (0, 1, 3.0), (0, 1, 7.0), (0, 2, 1.0)]
+               + _background(12, 40, 1))
+    batches = [_ops((1, 0, 1, 0), (2, 0, 1, 2.0), (1, 0, 1, 0), (0, 0, 1, 9.0),
+                    (1, 0, 1, 0), (2, 0, 1, 4.0)),
+               _ops((1, 0, 1, 0), (1, 0, 2, 0), (0, 0, 2, 6.0))]
+    return DeltaCSR(g, HyTMConfig(n_partitions=2)), batches
+
+
+def _swap_moves_named_pair():
+    # the delete of the block's first lane moves the just-inserted copy
+    # of another named pair below that pair's original copy
+    dc = DeltaCSR(_graph(16, _background(16, 60, 2)), HyTMConfig(n_partitions=2))
+    first = (int(dc._src[0]), int(dc._dst[0]))
+    other = next((int(s), int(d)) for s, d in zip(dc._src[1:dc.counts[0]],
+                                                  dc._dst[1:dc.counts[0]])
+                 if (int(s), int(d)) != first)
+    batch = _ops((0, *other, 99.0), (1, *first, 0), (2, *other, 42.0),
+                 (1, *other, 0), (1, *other, 0), (0, *other, 11.0),
+                 (1, *other, 0))
+    return dc, [batch]
+
+
+def _insert_then_delete():
+    dc = DeltaCSR(_graph(16, _background(16, 60, 3)), HyTMConfig(n_partitions=3))
+    s, d = int(dc._src[0]), int(dc._dst[0])
+    batch = _ops((0, 3, 15, 4.0), (0, 3, 15, 5.0), (1, 3, 15, 0), (1, s, d, 0),
+                 (0, s, d, 8.0), (1, s, d, 0), (1, 3, 15, 0))
+    return dc, [batch]
+
+
+def _reweight_absent():
+    dc = DeltaCSR(_graph(16, _background(16, 60, 4)), HyTMConfig(n_partitions=2))
+    absent = next((u, v) for u in range(16) for v in range(16)
+                  if not np.any((dc._src == u) & (dc._dst == v) & dc._valid))
+    batch = _ops((2, *absent, 3.0), (2, *absent, 6.0), (0, *absent, 1.0),
+                 (1, *absent, 0), (2, *absent, 2.0))
+    return dc, [batch]
+
+
+def _overflow_spills_and_merges():
+    # no slack: one source floods its block, spills, deletes and reweights
+    # among its spilled edges, and the batch merges
+    dc = DeltaCSR(_graph(20, _background(20, 80, 5)), HyTMConfig(n_partitions=2),
+                  slack=0.0, min_slack=1)
+    room = dc.block_size - int(dc.counts[0])
+    u = int(dc._src[0])
+    rows = [(0, u, j % 20, float(1 + j % 7)) for j in range(room + 6)]
+    rows += [(1, int(dc._src[1]), int(dc._dst[1]), 0), (0, u, 19, 13.0),
+             (2, u, 19, 14.0), (1, u, 19, 0), (1, u, 19, 0), (0, u, 3, 2.0)]
+    return dc, [_ops(*rows), _ops((1, u, 0, 0), (0, u, 1, 3.0))]
+
+
+def _spill_undone():
+    # a spill deleted within its batch leaves nothing to merge; the
+    # block takes inserts again once it has room
+    dc = DeltaCSR(_graph(20, _background(20, 80, 6)), HyTMConfig(n_partitions=2),
+                  slack=0.0, min_slack=1)
+    room = dc.block_size - int(dc.counts[0])
+    u, s0, d0 = int(dc._src[0]), int(dc._src[1]), int(dc._dst[1])
+    rows = [(0, u, 7, 1.0)] * room + [(0, u, 8, 2.0), (1, s0, d0, 0),
+                                      (0, u, 9, 3.0), (1, u, 8, 0), (1, u, 9, 0),
+                                      (0, u, 10, 4.0)]
+    return dc, [_ops(*rows)]
+
+
+def _hub_source():
+    # vertex 0 holds most of its block: three copies of an edge to every
+    # other vertex, so each of its pairs has parallel copies far apart
+    n = 64
+    hub = [(0, v, float(1 + (v + r) % 5)) for r in range(3) for v in range(1, n)]
+    dc = DeltaCSR(_graph(n, hub + _background(n, 30, 7)),
+                  HyTMConfig(n_partitions=2), slack=0.1, min_slack=1)
+    rng = np.random.default_rng(7)
+    batches = []
+    for _ in range(3):
+        rows = []
+        for v in rng.integers(1, n, 12).tolist():
+            rows += [(1, 0, v, 0), (0, 0, int(rng.integers(1, n)), 9.0),
+                     (2, 0, v, float(rng.integers(1, 9)))]
+        batches.append(_ops(*rows))
+    return dc, batches
+
+
+def _random_multigraph(seed):
+    """Mixed batches on a small dense multigraph: many parallel copies,
+    deletes and reweights of live pairs, reweights of absent ones."""
+    n = 24
+    rng = np.random.default_rng(seed)
+    dc = DeltaCSR(_graph(n, _background(n, 300, seed)),
+                  HyTMConfig(n_partitions=3), slack=0.0, min_slack=1)
+    batches = []
+    live = [(int(s), int(d)) for s, d in zip(*dc.live_edges()[:2])]
+    for _ in range(4):
+        rows = []
+        for _ in range(int(rng.integers(20, 120))):
+            kind = int(rng.integers(0, 4))
+            if kind == 0 or not live:
+                e = (int(rng.integers(0, n)), int(rng.integers(0, 4)))
+                rows.append((0, *e, float(rng.integers(1, 9))))
+                live.append(e)
+            elif kind == 1:
+                rows.append((1, *live.pop(int(rng.integers(len(live)))), 0))
+            elif kind == 2:
+                rows.append((2, *live[int(rng.integers(len(live)))],
+                             float(rng.integers(1, 9))))
+            else:
+                e = (int(rng.integers(0, n)), int(rng.integers(0, n)))
+                rows.append((2, *e, float(rng.integers(1, 9))))
+                if e not in live:
+                    live.append(e)
+        batches.append(_ops(*rows))
+    return dc, batches
+
+
+@pytest.mark.parametrize("case", [
+    _parallel_copies, _swap_moves_named_pair, _insert_then_delete,
+    _reweight_absent, _overflow_spills_and_merges, _spill_undone, _hub_source,
+    *(lambda s=s: _random_multigraph(s) for s in range(3)),
+], ids=["parallel_copies", "swap_moves_named_pair", "insert_then_delete",
+        "reweight_absent", "overflow_spills_and_merges", "spill_undone",
+        "hub_source", "random_0", "random_1", "random_2"])
+def test_apply_matches_the_per_op_scan(case):
+    dc, batches = case()
+    merges = _apply_against_scan(dc, batches)
+    if case is _overflow_spills_and_merges:
+        assert merges == 1
+    if case in (_spill_undone, _parallel_copies, _hub_source):
+        assert merges == 0
+
+
+def _hub_graph_container():
+    n = 64
+    hub = [(0, v, 1.0) for v in range(2, n)] * 2
+    return DeltaCSR(_graph(n, hub + _background(n, 30, 8)),
+                    HyTMConfig(n_partitions=2))
+
+
+@pytest.mark.parametrize("rows", [
+    [(0, 1, 2, 1.0), (1, 1, 2, 0), (7, 0, 1, 1.0)],
+    [(0, 1, 2, 1.0), (0, 1, 64, 1.0), (1, -1, 2, 0)],
+    [(0, 1, 2, 1.0), (2, 1, 3, 1.0), (0, 4, 5, float("nan"))],
+    [(0, 5, 6, 1.0), (1, 5, 6, 0), (1, 5, 6, 0)],
+    [(1, 0, 2, 0), (1, 0, 2, 0), (0, 0, 3, 2.0), (1, 0, 1, 0)],
+    [(2, 0, 1, 4.0), (1, 0, 1, 0), (1, 0, 1, 0)],
+], ids=["unknown_op", "endpoint_out_of_range", "nan_weight",
+        "delete_absent_after_insert_delete", "hub_delete_absent",
+        "hub_reweight_absent_then_double_delete"])
+def test_invalid_batch_matches_the_per_op_scan(rows):
+    """A bad batch raises where the per-op scan raises, with its message,
+    and leaves the log, version, device buffers and dirty set alone."""
+    dc = _hub_graph_container()
+    dc.apply(_ops((1, 0, 5, 0), (0, 0, 5, 3.0), (0, 9, 10, 2.0)))
+    batch = _ops(*rows)
+    with pytest.raises(InvalidBatchError) as want:
+        _ScanLog(dc).apply(batch)
+    before = ([a.copy() for a in (dc._src, dc._dst, dc._w, dc._valid,
+                                  dc.counts, dc.out_deg)],
+              dc.version, dc.layout_version, set(dc.dirty), dc.csr, dc.parts)
+    with pytest.raises(InvalidBatchError) as got:
+        dc.apply(batch)
+    assert got.value.index == want.value.index
+    assert str(got.value) == str(want.value)
+    for a, b in zip(before[0], (dc._src, dc._dst, dc._w, dc._valid,
+                                dc.counts, dc.out_deg)):
+        _same_array(b, a)
+    assert (dc.version, dc.layout_version, dc.dirty) == before[1:4]
+    assert dc.csr is before[4] and dc.parts is before[5]
+
+
+@pytest.mark.parametrize("rows,blocks", [
+    ([(0, 0, 3, 1.0)], 1),
+    ([(0, 0, 3, 1.0), (1, 0, 2, 0), (0, 40, 41, 2.0), (2, 63, 1, 5.0)], 2),
+], ids=["one_op", "both_blocks"])
+def test_apply_scanned_counts_the_blocks_of_its_sources(rows, blocks):
+    """``stream.apply``'s ``scanned`` stat is the live lanes of the blocks
+    that hold the batch's sources, as the log stood before the batch."""
+    from repro.obs import TraceRecorder
+
+    dc = _hub_graph_container()
+    batch = _ops(*rows)
+    parts = np.unique(dc.vertex_part[batch.src])
+    assert len(parts) == blocks
+    want = int(dc.counts[parts].sum())
+    rec = TraceRecorder()
+    dc.apply(batch, obs=rec)
+    (e,) = [e for e in rec.events if e.ph == "X" and e.name == "stream.apply"]
+    assert e.args["scanned"] == want
+
+
+# --------------------------------------------------------------------------
 # Incremental equivalence (property)
 # --------------------------------------------------------------------------
 
