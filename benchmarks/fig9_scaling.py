@@ -83,6 +83,7 @@ _DEVICE_SWEEP_SCRIPT = """
         import dataclasses
         import numpy as np
         from repro.core.cost_model import vertex_state_bytes
+        from repro.core.hytm import HyTMState
         from repro.dist.graph_shard import _owner_place_state
 
         cfg_o = dataclasses.replace(cfg, vertex_sharding="owner")
@@ -94,7 +95,8 @@ _DEVICE_SWEEP_SCRIPT = """
         assert res_o.total_transfer_bytes == res.total_transfer_bytes
         # measured bytes: what each device actually holds for one placed
         # (values, delta, frontier) triple — peak = the max over devices
-        st = _owner_place_state(rt_o, SSSP, *SSSP.init_state(g.n_nodes, 0))
+        st = _owner_place_state(rt_o, SSSP,
+                                HyTMState(*SSSP.init_state(g.n_nodes, 0)))
         per_dev = {{}}
         for arr in (st.values, st.delta, st.frontier):
             for sh in arr.addressable_shards:

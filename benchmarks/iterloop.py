@@ -1,27 +1,27 @@
-"""Iteration-driver benchmark: chunked vs per-iteration convergence loop.
+"""Iteration-driver benchmark: chunks of K iterations vs chunks of one.
 
 The workload is deliberately **dispatch-bound**, not transfer-bound: BFS
 on a tall, narrow grid graph (diameter ~= height), so the frontier is a
 thin wave that needs ~height iterations of almost no per-iteration work.
-This is the regime where the per-iteration driver's fixed costs — one
-``hytm_iteration`` dispatch plus two device->host syncs (loop condition +
-history pull) per iteration — dominate wall time, and where the chunked
-``lax.while_loop`` driver (``HyTMConfig.sync_every = K``) wins by paying
-those costs once per K iterations instead (the high-diameter BFS/SSSP
-tail the ISSUE's EMOGI/Gunrock persistent-kernel comparison targets).
+This is the regime where the driver's fixed costs per chunk — one
+``hytm_chunk`` dispatch plus the device->host syncs (loop-exit flag +
+history drain) — dominate wall time when every chunk holds one
+iteration, and where ``HyTMConfig.sync_every = K`` wins by paying those
+costs once per K iterations instead (the high-diameter BFS/SSSP tail of
+the EMOGI/Gunrock persistent-kernel comparison).
 
 Rows:
 
-* ``iterloop-periter`` — ``sync_every=1`` (legacy one-dispatch-per-
-  iteration loop);
+* ``iterloop-periter`` — ``sync_every=1`` (a chunk of one iteration per
+  dispatch);
 * ``iterloop-chunked`` — ``sync_every=K``; ``derived`` records the
   dispatch counts and the wall-clock speedup.
 
 ``--selfcheck`` is the CI gate: it monkeypatch-counts driver dispatches
 and asserts the chunked run batches for real — chunk dispatches
-<= iterations/K + 1 (vs exactly ``iterations`` single-iteration
-dispatches for the per-iteration driver), bit-identical values, and a
-strictly faster chunked wall time on the smoke graph.
+<= iterations/K + 1 (vs exactly ``iterations`` chunks of one at K=1),
+bit-identical values, and a strictly faster chunked wall time on the
+smoke graph.
 """
 
 from __future__ import annotations
@@ -72,7 +72,7 @@ def run(fast: bool = False, height: int | None = None, width: int = 3,
     runs = repeats + 1  # + warmup
     emit(
         "iterloop-periter", t1 * 1e6,
-        f"iters={res1.iterations};dispatches_per_run={c1['iteration'] // runs}",
+        f"iters={res1.iterations};dispatches_per_run={c1['chunk'] // runs}",
     )
     emit(
         "iterloop-chunked", tK * 1e6,
@@ -87,9 +87,8 @@ def run(fast: bool = False, height: int | None = None, width: int = 3,
         per_run_chunks = cK["chunk"] // runs
         bound = resK.iterations // sync_every + 1
         assert per_run_chunks <= bound, (per_run_chunks, bound)
-        assert c1["iteration"] // runs == res1.iterations
-        assert cK["iteration"] == 0, "chunked driver dispatched single iterations"
-        assert tK < t1, f"chunked {tK:.3f}s not faster than per-iteration {t1:.3f}s"
+        assert c1["chunk"] // runs == res1.iterations
+        assert tK < t1, f"chunked {tK:.3f}s not faster than chunks of one {t1:.3f}s"
         print(f"OK iterloop selfcheck: {per_run_chunks} chunk dispatches "
               f"<= {bound} for {resK.iterations} iters (K={sync_every}), "
               f"speedup {t1 / tK:.2f}x")

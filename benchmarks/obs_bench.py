@@ -12,8 +12,8 @@ Measures what tracing costs and proves what it must not change:
   history, so this should be noise).
 
 ``--selfcheck`` gates (CI):
-  1. **bit-identical** — a traced MIN run (both the chunked
-     ``sync_every>1`` driver and the K=1 legacy loop) returns values,
+  1. **bit-identical** — a traced MIN run (at ``sync_every=4`` and in
+     chunks of one, ``sync_every=1``) returns values,
      iterations, and transfer accounting identical to the untraced run;
   2. **exact reconciliation** — the run-summary span totals and the
      per-iteration event count equal the returned ``HyTMResult`` fields
@@ -122,7 +122,7 @@ def run(fast: bool = False, selfcheck: bool = False, seed: int = 5,
 def _selfcheck(g, cfg, cfg1, base, traced, rec_one, traced_one, doc,
                rows) -> None:
     # 1. bit-identical: tracing must not perturb the computation —
-    # chunked driver (the repeats above) and the K=1 legacy loop
+    # chunks of four (the repeats above) and chunks of one
     np.testing.assert_array_equal(base.values, traced.values)
     assert base.iterations == traced.iterations
     assert base.total_transfer_bytes == traced.total_transfer_bytes
@@ -132,7 +132,7 @@ def _selfcheck(g, cfg, cfg1, base, traced, rec_one, traced_one, doc,
     np.testing.assert_array_equal(base1.values, traced1.values)
     assert base1.iterations == traced1.iterations
 
-    # 2. exact reconciliation on both drivers: span totals == HyTMResult
+    # 2. exact reconciliation at both chunk sizes: span totals == HyTMResult
     for r, result, tag in ((rec_one, traced_one, "chunked"),
                            (rec1, traced1, "K=1")):
         rep = reconcile(r, result)
@@ -159,7 +159,7 @@ def _selfcheck(g, cfg, cfg1, base, traced, rec_one, traced_one, doc,
         tiny.instant("e", vt=float(i))
     assert len(tiny) == 8 and tiny.dropped == 42, (len(tiny), tiny.dropped)
     assert rows["overhead_ratio"] < OVERHEAD_RATIO, rows
-    print(f"# SELFCHECK OK: traced == untraced (both drivers); "
+    print(f"# SELFCHECK OK: traced == untraced (both chunk sizes); "
           f"reconcile exact over {rows['iterations']} iterations; "
           f"schema valid ({len(doc['traceEvents'])} chrome events); "
           f"overhead ratio {rows['overhead_ratio']:.2f} < {OVERHEAD_RATIO}")

@@ -1,13 +1,13 @@
 """Online feedback: per-engine cost corrections from measured sweep times.
 
 Offline calibration (calibrate.py) fixes the *profile*; this module
-closes the loop at run time.  Each HyTM iteration yields one noisy linear
-observation
+closes the loop at run time.  Each chunk of HyTM iterations yields one
+noisy linear observation
 
-    measured_iteration_seconds ~= sum_e  c_e * modeled_e
+    measured_chunk_seconds ~= sum_e  c_e * modeled_e
 
-where ``modeled_e`` is the modeled time the plan attributed to engine
-``e`` this iteration.  :class:`OnlineCalibrator` maintains the
+where ``modeled_e`` is the modeled time the plans attributed to engine
+``e`` over the chunk's iterations.  :class:`OnlineCalibrator` maintains the
 exponentially-forgotten normal equations of that regression (EWMA
 recursive least squares) and solves for the correction vector ``c``.
 
@@ -19,7 +19,7 @@ Algorithm 1, which compares engines against each other.  Engines with no
 accumulated evidence stay at 1.0.
 
 The correction multiplies the per-engine selection costs
-(``cost_model.apply_correction``) inside ``hytm_iteration``, steers the
+(``cost_model.apply_correction``) inside each iteration's selection, steers the
 sharded path's ICI-level exchange choice (``graph_shard.ici_level_cost``),
 and persists across queries in ``stream.service.GraphService`` so lane
 scheduling keeps learning over a service's lifetime.
@@ -113,15 +113,18 @@ class OnlineCalibrator:
         c = np.where(obs, np.clip(c, *self.clip), 1.0)
         return c.astype(float)
 
-    def observe_iteration(self, sync_ref, per_engine_modeled, t_start: float,
-                          skip: bool = False):
-        """The per-iteration wiring shared by ``run_hytm``,
-        ``run_hytm_sharded`` and ``GraphService``: block on ``sync_ref``
-        (so the elapsed wall time covers the whole iteration), fold the
-        measurement against the (3,) modeled per-engine seconds — unless
-        ``skip``, for first iterations whose wall time is compile, not
-        sweep — and return the refreshed correction as a (3,) float32
-        jax array ready to feed the next iteration."""
+    def observe(self, sync_ref, per_engine_modeled, t_start: float,
+                skip: bool = False):
+        """The per-chunk wiring shared by ``run_hytm`` (one device and the
+        mesh) and ``GraphService``: block on ``sync_ref`` (so the elapsed
+        wall time covers the whole chunk), fold the measurement against
+        the (3,) per-engine modeled seconds summed over the chunk's
+        executed iterations — unless ``skip``, for chunks whose dispatch
+        compiled (wall time measures XLA, not the sweep) — and return the
+        refreshed correction as a (3,) float32 jax array ready to feed
+        the next chunk.  Summing iterations only aggregates observations
+        of a model linear in the per-engine regressors, so a chunk
+        identifies the same correction vector as its iterations would."""
         import jax
         import jax.numpy as jnp
 
@@ -132,25 +135,3 @@ class OnlineCalibrator:
                 time.monotonic() - t_start,
             )
         return jnp.asarray(self.correction(), jnp.float32)
-
-    def observe_chunk(self, sync_ref, per_engine_modeled_sum, t_start: float,
-                      skip: bool = False):
-        """Chunk-granularity observation for the device-resident drivers
-        (``HyTMConfig.sync_every > 1``): the regression target moves from
-        one iteration to one chunk —
-
-            measured_chunk_seconds ~= sum_e c_e * (sum over the chunk's
-                                      iterations of modeled_e)
-
-        which identifies the same correction vector (the model is linear
-        in the per-engine regressors; summing iterations just aggregates
-        observations) while costing one measurement per dispatch instead
-        of per iteration.  ``per_engine_modeled_sum`` is the (3,)
-        per-engine modeled seconds summed over the chunk's *executed*
-        iterations (drained history rows ``[:n_done]``); ``skip`` marks
-        chunks whose dispatch compiled (wall time measures XLA, not the
-        sweep).  Returns the refreshed (3,) float32 correction for the
-        next chunk."""
-        return self.observe_iteration(
-            sync_ref, per_engine_modeled_sum, t_start, skip=skip,
-        )

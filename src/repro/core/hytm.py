@@ -24,26 +24,25 @@ while-condition checks the *previous* iteration's frontier population
 and never executes a single iteration past convergence; the host only
 syncs once per chunk — to drain the ``(K, ...)`` history rows actually
 written and to read the loop-exit flag — instead of twice per iteration.
-``K = 1`` keeps the legacy one-dispatch-per-iteration loop (whose
-per-iteration device->host sync on the frontier population is the same
-sync real GPU frameworks pay), reproducing the pre-chunk dataflow
-bit-for-bit; ``K > 1`` is bit-identical for min-combine programs and
-tolerance-bounded for sum-combine (XLA may fuse the loop body
-differently than the standalone iteration).  The drained history feeds
-the Fig-7 execution path, Table-VI transfer volume, and Table-V runtime
-analyses exactly as before — chunking changes *when* history reaches the
-host, never what it records.
+``K = 1`` is a chunk of one iteration through the same loop.  Any K is
+bit-identical for min-combine programs and tolerance-bounded for
+sum-combine (XLA may fuse the loop body differently).  One host loop,
+``drive_chunks``, drives the chunks of one device and of the mesh
+(``repro.dist.graph_shard``), and both iteration bodies share their
+selection, pass-2 frontier, next-state and info stages; only the sweeps
+differ.  The drained history feeds the Fig-7 execution path, Table-VI
+transfer volume, and Table-V runtime analyses — chunking changes *when*
+history reaches the host, never what it records.
 """
 
 from __future__ import annotations
 
 import contextlib
-import dataclasses
 import time
 import warnings
 from dataclasses import dataclass, field
 from functools import partial
-from typing import Any
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -51,19 +50,19 @@ import numpy as np
 
 from repro.core.constants import PCIE3, TPU_V5E_ICI, LinkModel
 from repro.core.cost_model import (
-    COMPACT,
-    FILTER,
     HISTORY_KEYS,
     KEY_ACTIVE_EDGES,
     KEY_ACTIVE_VERTICES,
     KEY_ENGINES,
+    KEY_ICI_BYTES,
+    KEY_ICI_TIME,
     KEY_MISPREDICTIONS,
     KEY_N_TASKS,
     KEY_PER_ENGINE_TIME,
     KEY_TRANSFER_BYTES,
     KEY_TRANSFER_TIME,
     NONE,
-    ZEROCOPY,
+    PartitionStats,
     init_history_buffers,
     partition_stats,
     selection_diagnostics,
@@ -79,7 +78,7 @@ from repro.core.partition import (
     route_partitions,
     to_device_partitions,
 )
-from repro.core.scheduler import make_schedule
+from repro.core.scheduler import Schedule, make_schedule
 from repro.core.task_generation import TaskPlan, forced_engine_plan, generate_tasks
 from repro.graph.algorithms import MIN, SUM, VertexProgram
 from repro.graph.csr import CSRGraph, DeviceCSR, to_device_csr
@@ -102,9 +101,8 @@ class HyTMConfig:
     # Convergence-loop chunk size K: each device dispatch runs up to K
     # iterations inside one compiled lax.while_loop (early-exiting the
     # moment the frontier drains), and the host syncs once per chunk
-    # instead of twice per iteration.  K=1 keeps the legacy
-    # one-dispatch-per-iteration loop (bit-for-bit the pre-chunk
-    # dataflow); the default is tuned for dispatch-bound many-iteration
+    # instead of twice per iteration; K=1 dispatches a chunk of one
+    # iteration.  The default is tuned for dispatch-bound many-iteration
     # workloads (benchmarks/iterloop.py) — large enough to amortize
     # dispatch+sync, small enough that history draining and the online
     # calibrator keep a useful cadence.
@@ -268,7 +266,7 @@ def _sweep(
             # of newly-removed in-neighbors — its remaining degree drops
             # by that much.  Δ (the removed flag) is not consumed here;
             # removal updates happen once per iteration in
-            # ``_iteration_impl``.  Counts are additive, so the async and
+            # ``next_state``.  Counts are additive, so the async and
             # sync sweeps are identical.
             values = values - out.agg
             activated = activated | out.touched
@@ -341,6 +339,124 @@ def _sweep(
     return HyTMState(values=values, delta=delta, frontier=state.frontier), activated
 
 
+class Selection(NamedTuple):
+    """One iteration's selection stage (``select_partitions``)."""
+
+    stats: PartitionStats
+    plan: TaskPlan
+    delta_mass: jax.Array    # (P,) pending |Δ| of each partition's frontier
+    sched: Schedule
+
+
+def select_partitions(
+    state: HyTMState,
+    out_degree: jax.Array,
+    zc_req: jax.Array,
+    parts: DevicePartitions,
+    program: VertexProgram,
+    config: HyTMConfig,
+    n_hub_partitions: int,
+    correction: jax.Array | None,
+) -> Selection:
+    """(1-4) stats -> costs -> engines -> combined tasks, the Δ mass and
+    the contribution-driven priority schedule, under ``scope(SELECT)``.
+    Both iteration bodies (``_iteration_impl`` and the sharded one in
+    ``repro.dist.graph_shard``) start here."""
+    with scope(SELECT):
+        stats = partition_stats(state.frontier, out_degree, zc_req, parts)
+        if config.forced_engine is None:
+            plan = generate_tasks(
+                stats, config.link, combine_k=config.combine_k,
+                enable_combination=config.enable_task_combination,
+                correction=correction,
+            )
+        else:
+            plan = forced_engine_plan(
+                stats, config.link, config.forced_engine,
+                enable_combination=config.enable_task_combination,
+                combine_k=config.combine_k,
+            )
+
+        # Only the 'delta' CDS mode reads the per-partition |Δ| mass, and
+        # min-combine programs carry an identically-zero Δ — in both cases
+        # the (n,)->(P,) segment-sum would reduce zeros (or feed a
+        # schedule that ignores it), so skip it.
+        if program.combine == MIN or config.cds_mode != "delta":
+            delta_mass = jnp.zeros(parts.n_partitions, jnp.float32)
+        else:
+            delta_mass = jax.ops.segment_sum(
+                jnp.abs(state.delta) * state.frontier, parts.vertex_part_id,
+                num_segments=parts.n_partitions,
+            )
+        sched = make_schedule(
+            plan.engines, delta_mass, n_hub_partitions, config.cds_mode,
+            config.recompute_once,
+        )
+    return Selection(stats, plan, delta_mass, sched)
+
+
+def pass2_frontier(program: VertexProgram, frontier: jax.Array,
+                   activated: jax.Array, delta: jax.Array) -> jax.Array:
+    """The sources of the recompute-once pass, from pass 1's outcome."""
+    if program.peel_k is not None:
+        # peeling re-relaxation would re-subtract the same removal counts
+        # (double-count); an empty frontier makes pass 2 a harmless no-op
+        return jnp.zeros_like(frontier)
+    if program.combine == MIN:
+        return frontier | activated
+    # |Δ|: pending deltas are non-negative on a cold start, but the
+    # incremental path (repro.stream) injects *signed* correction deltas
+    # after edge deletions — negative mass must propagate too.
+    return jnp.abs(delta) > program.tolerance
+
+
+def next_state(program: VertexProgram, values: jax.Array, delta: jax.Array,
+               activated: jax.Array, activated2: jax.Array) -> HyTMState:
+    """The state after both passes, with the next frontier, under
+    ``scope(UPDATE)``."""
+    with scope(UPDATE):
+        activated = activated | activated2
+        if program.peel_k is not None:
+            # removal update: alive vertices whose remaining degree fell
+            # below k are removed now and become the next round's frontier
+            alive = delta < 0.5
+            newly = alive & (values < program.peel_k)
+            return HyTMState(values=values,
+                             delta=delta + newly.astype(jnp.float32),
+                             frontier=newly)
+        if program.combine == MIN:
+            frontier = activated
+        else:
+            frontier = jnp.abs(delta) > program.tolerance
+        return HyTMState(values=values, delta=delta, frontier=frontier)
+
+
+def iteration_info(sel: Selection, frontier: jax.Array,
+                   next_frontier: jax.Array, config: HyTMConfig,
+                   correction: jax.Array | None) -> dict[str, Any]:
+    """An iteration's history row: the selection's diagnostics (under
+    ``scope(SELECT)``) and its accounting (under ``scope(UPDATE)``)."""
+    plan = sel.plan
+    with scope(SELECT):
+        per_engine_time, mispredictions = selection_diagnostics(
+            plan.engines, plan.transfer_time, sel.stats, plan.costs,
+            correction,
+        )
+    with scope(UPDATE):
+        return {
+            KEY_ENGINES: plan.engines,
+            KEY_TRANSFER_BYTES: plan.transfer_bytes,
+            KEY_TRANSFER_TIME: jnp.sum(plan.transfer_time)
+            + plan.n_tasks.astype(jnp.float32) * config.link.launch_overhead_s,
+            KEY_N_TASKS: plan.n_tasks,
+            KEY_ACTIVE_VERTICES: jnp.sum(frontier.astype(jnp.int32)),
+            KEY_ACTIVE_EDGES: jnp.sum(sel.stats.active_edges),
+            "next_active": jnp.sum(next_frontier.astype(jnp.int32)),
+            KEY_PER_ENGINE_TIME: per_engine_time,
+            KEY_MISPREDICTIONS: mispredictions,
+        }
+
+
 def _iteration_impl(
     state: HyTMState,
     csr: DeviceCSR,
@@ -353,125 +469,41 @@ def _iteration_impl(
     correction: jax.Array | None = None,
     route: EdgeRoute | None = None,
 ) -> tuple[HyTMState, dict[str, Any]]:
-    """Untraced single-iteration body.  ``hytm_iteration`` jits it as the
-    public per-dispatch entry; ``hytm_chunk`` inlines it inside the
-    chunked ``lax.while_loop`` so K iterations share one dispatch.
-    ``route`` is ``Runtime.route``, an argument like the CSR (never a
-    baked-in constant); None routes FILTER's block on every visit."""
+    """Untraced single-iteration body: the shared selection stage, two
+    ``_sweep`` passes, and the shared next-state and info stages.
+    ``hytm_chunk`` runs it inside its ``lax.while_loop``;
+    ``hytm_batched_chunk`` vmaps it over lanes.  ``route`` is
+    ``Runtime.route``, an argument like the CSR (never a baked-in
+    constant); None routes FILTER's block on every visit."""
     rt = Runtime(csr=csr, parts=parts, zc_req=zc_req, inv_deg=inv_deg,
                  n_hub_partitions=n_hub_partitions, route=route)
-    n = csr.n_nodes
     frontier = state.frontier
     # trace-time resolution: config is static under jit, so the kernel
     # dispatch is a Python-level branch — no runtime cost either way
     use_kernels = resolve_use_kernels(config.use_kernels)
-
-    with scope(SELECT):
-        # (1-3) stats -> costs -> engines -> combined tasks
-        stats = partition_stats(frontier, csr.out_degree, zc_req, parts)
-        if config.forced_engine is None:
-            plan: TaskPlan = generate_tasks(
-                stats, config.link, combine_k=config.combine_k,
-                enable_combination=config.enable_task_combination,
-                correction=correction,
-            )
-        else:
-            plan = forced_engine_plan(
-                stats, config.link, config.forced_engine,
-                enable_combination=config.enable_task_combination,
-                combine_k=config.combine_k,
-            )
-
-        # (4) contribution-driven priority schedule.  Only the 'delta' CDS
-        # mode reads the per-partition |Δ| mass, and min-combine programs
-        # carry an identically-zero Δ — in both cases the (n,)->(P,)
-        # segment-sum would reduce zeros (or feed a schedule that ignores
-        # it), so skip it.
-        if program.combine == MIN or config.cds_mode != "delta":
-            delta_mass = jnp.zeros(parts.n_partitions, jnp.float32)
-        else:
-            delta_mass = jax.ops.segment_sum(
-                jnp.abs(state.delta) * frontier, parts.vertex_part_id,
-                num_segments=parts.n_partitions,
-            )
-        mode = config.cds_mode
-        sched = make_schedule(
-            plan.engines, delta_mass, n_hub_partitions, mode, config.recompute_once,
-        )
+    sel = select_partitions(state, csr.out_degree, zc_req, parts, program,
+                            config, n_hub_partitions, correction)
+    engines, order = sel.plan.engines, sel.sched.order
 
     # (5) asynchronous sweep in priority order
     with scope(SWEEP):
         state1, activated = _sweep(
-            state, rt, program, plan.engines, sched.order, frontier,
+            state, rt, program, engines, order, frontier,
             config.async_sweep, consume="all", use_kernels=use_kernels,
         )
 
     # (6) recompute-once: loaded priority partitions, zero extra transfer.
     with scope(SWEEP):
-        engines2 = jnp.where(sched.second_pass, plan.engines, NONE)
-        if program.peel_k is not None:
-            # peeling re-relaxation would re-subtract the same removal counts
-            # (double-count); an empty frontier makes pass 2 a harmless no-op
-            frontier2 = jnp.zeros_like(frontier)
-        elif program.combine == MIN:
-            frontier2 = frontier | activated
-        else:
-            # |Δ|: pending deltas are non-negative on a cold start, but the
-            # incremental path (repro.stream) injects *signed* correction
-            # deltas after edge deletions — negative mass must propagate too.
-            frontier2 = jnp.abs(state1.delta) > program.tolerance
+        engines2 = jnp.where(sel.sched.second_pass, engines, NONE)
+        frontier2 = pass2_frontier(program, frontier, activated, state1.delta)
         state2, activated2 = _sweep(
-            state1, rt, program, engines2, sched.order, frontier2,
+            state1, rt, program, engines2, order, frontier2,
             config.async_sweep, consume="processed", use_kernels=use_kernels,
         )
-    with scope(UPDATE):
-        # next frontier
-        activated = activated | activated2
-        if program.peel_k is not None:
-            # removal update: alive vertices whose remaining degree fell
-            # below k are removed now and become the next round's frontier
-            alive = state2.delta < 0.5
-            newly = alive & (state2.values < program.peel_k)
-            next_frontier = newly
-            new_state = HyTMState(
-                values=state2.values,
-                delta=state2.delta + newly.astype(jnp.float32),
-                frontier=next_frontier,
-            )
-        else:
-            if program.combine == MIN:
-                next_frontier = activated
-            else:
-                next_frontier = jnp.abs(state2.delta) > program.tolerance
-            new_state = HyTMState(values=state2.values, delta=state2.delta,
-                                  frontier=next_frontier)
-
-    with scope(SELECT):
-        per_engine_time, mispredictions = selection_diagnostics(
-            plan.engines, plan.transfer_time, stats, plan.costs, correction,
-        )
-
-    with scope(UPDATE):
-        info = {
-            KEY_ENGINES: plan.engines,
-            KEY_TRANSFER_BYTES: plan.transfer_bytes,
-            KEY_TRANSFER_TIME: jnp.sum(plan.transfer_time)
-            + plan.n_tasks.astype(jnp.float32) * config.link.launch_overhead_s,
-            KEY_N_TASKS: plan.n_tasks,
-            KEY_ACTIVE_VERTICES: jnp.sum(frontier.astype(jnp.int32)),
-            KEY_ACTIVE_EDGES: jnp.sum(stats.active_edges),
-            "next_active": jnp.sum(next_frontier.astype(jnp.int32)),
-            KEY_PER_ENGINE_TIME: per_engine_time,
-            KEY_MISPREDICTIONS: mispredictions,
-        }
-        return new_state, info
-
-
-# Public per-dispatch entry: one jitted iteration (the K=1 driver and the
-# vmapped service lanes dispatch through this).
-hytm_iteration = partial(
-    jax.jit, static_argnames=("program", "config", "n_hub_partitions"),
-)(_iteration_impl)
+    new_state = next_state(program, state2.values, state2.delta, activated,
+                           activated2)
+    return new_state, iteration_info(sel, frontier, new_state.frontier,
+                                     config, correction)
 
 
 # --------------------------------------------------------------------------
@@ -499,8 +531,8 @@ def chunked_while(iter_fn, state: HyTMState, history: dict, chunk: int):
     iteration ``i``'s info rows into ``history[k][i]`` and accumulating
     the (3,) per-engine modeled seconds, with the early-exit condition on
     the *previous* iteration's ``next_active`` (sentinel 1: the first
-    iteration of a chunk always runs, matching the K=1 loop, which runs
-    one iteration even on an empty frontier).
+    iteration of a chunk always runs, so a run executes one iteration
+    even on an empty frontier).
 
     Returns ``(state, history, n_done, last_next_active,
     per_engine_sum)``.  ``per_engine_sum`` rides in the carry so the
@@ -554,7 +586,7 @@ def hytm_chunk(
     * the while-condition tests the *previous* iteration's
       ``next_active``, so the loop stops immediately after the converging
       iteration — a converged run never executes an iteration past
-      convergence, and the iteration count is identical to the K=1 loop;
+      convergence, and the iteration count is the same at every K;
     * iteration ``i``'s info rows land in ``history[k][i]``; rows at
       index >= the returned ``n_done`` are stale garbage (possibly from a
       previous chunk through the same donated buffer) and must be sliced
@@ -646,8 +678,7 @@ def hytm_batched_chunk(
         )
 
     n_lanes = state.values.shape[0]
-    # sentinel ones: the first iteration always runs, matching the K=1
-    # loop (which runs one iteration even on an empty frontier)
+    # sentinel ones: the first iteration always runs, as in hytm_chunk
     init = (state, jnp.int32(0), jnp.ones(n_lanes, jnp.int32),
             jnp.zeros(3, jnp.float32), jnp.int32(0))
     state, n_done, lane_active, pe_sum, mp_sum = jax.lax.while_loop(
@@ -673,19 +704,15 @@ def dead_lane_state(program: VertexProgram, n: int) -> tuple:
 
 @contextlib.contextmanager
 def count_driver_dispatches():
-    """Count convergence-driver dispatches by swapping the module-global
-    entry points (``run_hytm`` resolves both at call time, so the swap
-    sees every dispatch).  Yields a live ``{"iteration": n, "chunk": n}``
-    dict — the regression seam ``tests/test_chunked.py`` and
-    ``benchmarks/iterloop.py --selfcheck`` share to prove the chunked
-    loop really batches (chunk dispatches ≤ iterations/K + 1)."""
+    """Count ``hytm_chunk`` dispatches by swapping the module-global entry
+    point (``run_hytm`` resolves it at call time, so the swap sees every
+    dispatch).  Yields a live ``{"chunk": n}`` dict — the regression seam
+    ``tests/test_chunked.py`` and ``benchmarks/iterloop.py --selfcheck``
+    share to prove the chunked loop really batches (chunk dispatches
+    ≤ iterations/K + 1)."""
     mod = __import__("repro.core.hytm", fromlist=["hytm"])
-    counts = {"iteration": 0, "chunk": 0}
-    orig_iter, orig_chunk = mod.hytm_iteration, mod.hytm_chunk
-
-    def count_iter(*a, **kw):
-        counts["iteration"] += 1
-        return orig_iter(*a, **kw)
+    counts = {"chunk": 0}
+    orig_chunk = mod.hytm_chunk
 
     def count_chunk(*a, **kw):
         counts["chunk"] += 1
@@ -693,12 +720,12 @@ def count_driver_dispatches():
 
     # a first dispatch registers the program it ran (obs.scopes), which
     # has to lower like the real one
-    count_iter.lower, count_chunk.lower = orig_iter.lower, orig_chunk.lower
-    mod.hytm_iteration, mod.hytm_chunk = count_iter, count_chunk
+    count_chunk.lower = orig_chunk.lower
+    mod.hytm_chunk = count_chunk
     try:
         yield counts
     finally:
-        mod.hytm_iteration, mod.hytm_chunk = orig_iter, orig_chunk
+        mod.hytm_chunk = orig_chunk
 
 
 # the recorder track of the single-device driver's events
@@ -785,8 +812,8 @@ def run_hytm(
     (values, Δ, frontier) triple instead of ``program.init_state`` — the
     entry point of the incremental path (repro.stream.incremental).  With
     both ``runtime`` and ``initial_state`` given, ``g`` may be ``None``.
-    With ``config.sync_every > 1`` the state is *donated* to the chunked
-    driver (``hytm_chunk``): on accelerator backends the caller's
+    The state is *donated* to the chunked driver (``hytm_chunk``): on
+    accelerator backends the caller's
     ``initial_state`` buffers are invalidated by the first chunk — pass a
     copy if they must survive the run.  Warm-start composes with
     ``config.mesh_axis``: the sharded driver replicates the triple over
@@ -799,13 +826,14 @@ def run_hytm(
     ``GraphService`` keeps one feedback loop across queries.  Only read
     when ``config.autotune`` is set.
 
-    ``obs``: an optional ``repro.obs.TraceRecorder``.  A single-device
-    run always opens the live spans ``hytm.run`` > ``hytm.init``,
-    ``chunk`` > ``hytm.dispatch`` (``hytm.compile`` when the program is
-    new) / ``hytm.wait`` / ``hytm.drain``, and ``hytm.result`` as
-    profiler annotations (inert without a profile); ``hytm.run`` carries
-    ``Runtime.route_args()`` (a runtime it has to build is built before
-    it opens).  A recorder records
+    ``obs``: an optional ``repro.obs.TraceRecorder``.  A run always
+    opens the live spans ``hytm.run`` > ``hytm.init``, ``chunk`` >
+    ``hytm.dispatch`` (``hytm.compile`` when the program is new) /
+    ``hytm.wait`` / ``hytm.drain``, and ``hytm.result`` as profiler
+    annotations (inert without a profile), on the track ``device0`` or,
+    with ``config.mesh_axis``, ``mesh``; a single-device ``hytm.run``
+    carries ``Runtime.route_args()`` (a runtime it has to build is built
+    before it opens).  A recorder records
     them too, plus per-iteration events from the drained history rows
     and one run-summary span whose totals equal the returned
     ``HyTMResult`` fields exactly.  ``obs=None`` (the default) records
@@ -824,8 +852,7 @@ def run_hytm(
     drain, before the convergence check) with ``state`` (live device
     state), ``iterations``, ``rows`` (drained host history so far),
     ``calibrator``, and ``last_active`` — the attachment point for
-    ``repro.resilience.CheckpointHook``.  Chunked driver only
-    (``sync_every > 1``).
+    ``repro.resilience.CheckpointHook``.
     """
     if config.mesh_axis is not None:
         # late import: graph_shard depends on this module's dataclasses
@@ -839,14 +866,6 @@ def run_hytm(
         )
     if g is None and runtime is None:
         raise ValueError("run_hytm needs a graph or a prebuilt runtime")
-    # raised (not asserted): under ``python -O`` an assert vanishes and a
-    # zero/negative chunk size would silently run the wrong driver
-    if config.sync_every < 1:
-        raise ValueError(f"sync_every must be >= 1, got {config.sync_every}")
-    if on_chunk is not None and config.sync_every == 1:
-        raise ValueError(
-            "on_chunk (checkpointing) requires the chunked driver — "
-            "set sync_every >= 2")
     if runtime is None:
         if program.symmetrize:
             # WCC-family programs are defined on the underlying undirected
@@ -856,42 +875,93 @@ def run_hytm(
             g, config, n_hubs=n_hubs,
             weighted_norm=program.use_delta and program.weighted,
         )
-    with span("hytm.run", obs, track=_TRACK, **runtime.route_args()):
-        return _run_single_device(
-            program, source, config, runtime, initial_state,
-            calibrator, obs, faults, retry, on_chunk)
+    rt = runtime
+    args = (rt.csr, rt.parts, rt.zc_req, rt.inv_deg)
+    # the warm signature mirrors the jit cache key: statics + every shape
+    # the trace specializes on (node/edge capacity, partition grid) — a
+    # dispatch not seen here compiles, and its wall time must not feed
+    # the calibrator
+    shapes = (program, config, rt.n_hub_partitions, rt.csr.n_nodes,
+              rt.csr.edge_src.shape[0], rt.parts.n_partitions,
+              rt.parts.block_size,
+              None if rt.route is None else rt.route.width)
+
+    def init():
+        if initial_state is not None:
+            return initial_state
+        return start_state(program, rt.csr.out_degree, rt.csr.n_nodes,
+                           source)
+
+    def info_shapes(state, correction):
+        if shapes not in rt.info_shape_cache:
+            rt.info_shape_cache[shapes] = jax.eval_shape(
+                lambda s: _iteration_impl(
+                    s, *args, program, config, rt.n_hub_partitions,
+                    correction, rt.route),
+                state,
+            )[1]
+        return rt.info_shape_cache[shapes]
+
+    def prepare(chunk, correction):
+        signature = ("chunk", *shapes, chunk, correction is not None)
+        return (hytm_chunk, (*args, program, config, rt.n_hub_partitions,
+                             chunk, correction, rt.route),
+                _consume_warm(signature), signature)
+
+    with span("hytm.run", obs, track=_TRACK, **rt.route_args()):
+        return drive_chunks(
+            program, config, init, info_shapes=info_shapes, prepare=prepare,
+            track=_TRACK, n_nodes=rt.csr.n_nodes, calibrator=calibrator,
+            obs=obs, faults=faults, retry=retry, on_chunk=on_chunk)
 
 
-def _run_single_device(program, source, config, rt, initial_state,
-                       calibrator, obs, faults, retry, on_chunk) -> HyTMResult:
-    """``run_hytm`` on one device over a built runtime, in the host spans
-    ``hytm.init``, ``hytm.dispatch`` (``hytm.compile`` for a program's
-    first dispatch), ``hytm.wait``, ``hytm.drain`` and ``hytm.result``."""
+def start_state(program: VertexProgram, out_degree: jax.Array, n: int,
+                source: int | None) -> HyTMState:
+    """A cold run's starting (values, Δ, frontier) over ``n`` vertices
+    with the runtime's (n,) out-degrees."""
+    if program.peel_k is not None:
+        # peeling seeds from the runtime's (symmetrized) out-degrees,
+        # which init_state cannot see: values = remaining degree, Δ =
+        # removed flag, frontier = the initially-removed set
+        deg = out_degree.astype(jnp.float32)
+        removed = deg < program.peel_k
+        return HyTMState(values=deg, delta=removed.astype(jnp.float32),
+                         frontier=removed)
+    return HyTMState(*program.init_state(n, source))
+
+
+def drive_chunks(program, config, init, *, info_shapes, prepare, track,
+                 n_nodes, calibrator=None, obs=None, faults=None,
+                 retry=None, on_chunk=None, keys=HISTORY_KEYS, on_rows=None,
+                 mesh=False, label="run") -> HyTMResult:
+    """The host convergence loop of one device and of the mesh: one chunk
+    dispatch per ``config.sync_every`` iterations, one host sync per
+    chunk, in the spans ``hytm.init``, ``chunk`` > ``hytm.dispatch``
+    (``hytm.compile`` for a program's first dispatch) / ``hytm.wait`` /
+    ``hytm.drain``, and ``hytm.result`` on ``track``.
+
+    What the caller's layout supplies: ``init()``, the starting state;
+    ``info_shapes(state, correction)``, one iteration's info
+    ShapeDtypeStructs; ``prepare(chunk, correction)`` -> ``(fn, args,
+    warm, signature)``, where ``fn(state, history, *args)`` runs a chunk,
+    ``warm`` says (and records) that it is compiled, and a ``signature``
+    registers a cold program with ``repro.obs.scopes``; ``keys``, the
+    history rows a chunk writes; ``on_rows(drained, n_done, first,
+    correction)``, which sees each chunk's drained rows with the
+    correction the chunk ran under and returns further per-iteration
+    rows for the result's history; ``n_nodes``, the length the returned
+    values are cut to."""
     # late imports: both modules import this one
     from repro.obs.record import record_history_rows, record_run
     if faults is not None:
         from repro.resilience.supervisor import guarded_dispatch
+    # raised (not asserted): under ``python -O`` an assert vanishes and a
+    # zero/negative chunk size would silently run no iteration
+    if config.sync_every < 1:
+        raise ValueError(f"sync_every must be >= 1, got {config.sync_every}")
 
-    with span("hytm.init", obs, track=_TRACK):
-        if initial_state is None:
-            if program.peel_k is not None:
-                # peeling seeds from the runtime's (symmetrized)
-                # out-degrees, which init_state cannot see: values =
-                # remaining degree, Δ = removed flag, frontier = the
-                # initially-removed set
-                deg = rt.csr.out_degree.astype(jnp.float32)
-                removed = deg < program.peel_k
-                state = HyTMState(values=deg,
-                                  delta=removed.astype(jnp.float32),
-                                  frontier=removed)
-            else:
-                values, delta, frontier = program.init_state(
-                    rt.csr.n_nodes, source)
-                state = HyTMState(values=values, delta=delta,
-                                  frontier=frontier)
-        else:
-            state = initial_state
-
+    with span("hytm.init", obs, track=track):
+        state = init()
         calib = None
         correction = None
         if config.autotune:
@@ -905,157 +975,91 @@ def _run_single_device(program, source, config, rt, initial_state,
             correction = jnp.asarray(calib.correction(), jnp.float32)
 
         rows: dict[str, list] = {k: [] for k in HISTORY_KEYS}
-        # the warm signature mirrors the jit cache key: statics + every
-        # shape the trace specializes on (node/edge capacity, partition
-        # grid) — a dispatch not seen here compiles, and its wall time
-        # must not feed the calibrator
-        shapes = (program, config, rt.n_hub_partitions, rt.csr.n_nodes,
-                  rt.csr.edge_src.shape[0], rt.parts.n_partitions,
-                  rt.parts.block_size,
-                  None if rt.route is None else rt.route.width)
-        if config.sync_every > 1:
-            info_shapes = rt.info_shape_cache.get(shapes)
-            if info_shapes is None:
-                info_shapes = jax.eval_shape(
-                    lambda s: _iteration_impl(
-                        s, rt.csr, rt.parts, rt.zc_req, rt.inv_deg, program,
-                        config, rt.n_hub_partitions, correction, rt.route,
-                    ),
-                    state,
-                )[1]
-                rt.info_shape_cache[shapes] = info_shapes
-            cur_chunk = min(config.sync_every, config.max_iters)
-            history = init_history_buffers(info_shapes, cur_chunk)
+        extra_rows: dict[str, list] = {}
+        shapes = info_shapes(state, correction)
+        cur_chunk = min(config.sync_every, config.max_iters)
+        history = init_history_buffers(shapes, cur_chunk, keys)
     t0 = time.monotonic()
     iters = 0
-    if config.sync_every > 1:
-        # Chunked device-resident driver: one hytm_chunk dispatch per K
-        # iterations, one host sync per chunk (n_done + history drain).
-        while iters < config.max_iters:
-            chunk = min(config.sync_every, config.max_iters - iters)
-            if chunk != cur_chunk:
-                # the rare max_iters tail; otherwise the drained buffers
-                # cycle back in, so on accelerators the donated memory is
-                # reused across chunks
-                history = init_history_buffers(info_shapes, chunk)
-                cur_chunk = chunk
-            signature = ("chunk", *shapes, chunk, correction is not None)
-            warm = _consume_warm(signature)
+    while iters < config.max_iters:
+        chunk = min(config.sync_every, config.max_iters - iters)
+        if chunk != cur_chunk:
+            # the rare max_iters tail; otherwise the drained buffers
+            # cycle back in, so on accelerators the donated memory is
+            # reused across chunks
+            history = init_history_buffers(shapes, chunk, keys)
+            cur_chunk = chunk
+        fn, args, warm, signature = prepare(chunk, correction)
 
-            # injected faults fire BEFORE the dispatch (see
-            # resilience.supervisor) so the donated buffers of the
-            # previous chunk are intact and a retry is bit-identical
-            def attempt(st=state, h=history, corr=correction):
-                with quiet_donation():
-                    return hytm_chunk(
-                        st, h, rt.csr, rt.parts, rt.zc_req, rt.inv_deg,
-                        program, config, rt.n_hub_partitions, chunk, corr,
-                        rt.route,
-                    )
+        # injected faults fire BEFORE the dispatch (see
+        # resilience.supervisor) so the donated buffers of the
+        # previous chunk are intact and a retry is bit-identical
+        def attempt(st=state, h=history, fn=fn, args=args):
+            with quiet_donation():
+                return fn(st, h, *args)
 
-            with span("chunk", obs, cat=CAT_RUN, track=_TRACK,
-                      vt=float(iters)) as chunk_span:
-                t_chunk = time.monotonic()
-                with span("hytm.dispatch" if warm else "hytm.compile", obs,
-                          track=_TRACK):
-                    if not warm:
-                        scopes.register(
-                            signature, hytm_chunk, state, history, rt.csr,
-                            rt.parts, rt.zc_req, rt.inv_deg, program, config,
-                            rt.n_hub_partitions, chunk, correction, rt.route)
-                    state, history, n_done, last_active, pe_sum = (
-                        attempt() if faults is None else guarded_dispatch(
-                            attempt, site="chunk_dispatch", faults=faults,
-                            policy=retry, obs=obs, mesh=False,
-                            kernels=resolve_use_kernels(config.use_kernels),
-                        ))
-                with span("hytm.wait", obs, track=_TRACK):
-                    n_done, last_active = int(n_done), int(last_active)
-                iters += n_done
-                if calib is not None:
-                    # observe BEFORE the history drain so the measured
-                    # wall window covers dispatch + execution only
-                    correction = calib.observe_chunk(
-                        state.values, np.asarray(pe_sum, dtype=float),
-                        t_chunk,
-                        skip=not warm,  # a compiling chunk measures compile
-                    )
-                # drain before the next dispatch donates these buffers;
-                # rows past n_done are stale (early exit) and sliced off
-                with span("hytm.drain", obs, track=_TRACK):
-                    drained = jax.device_get(history)
-                    for k in rows:
-                        rows[k].append(drained[k][:n_done])
-                    if obs is not None:
-                        record_history_rows(obs, drained, n_done,
-                                            iters - n_done)
-                        chunk_span.vt_dur = float(n_done)
-                        chunk_span.args.update(n_done=n_done, warm=warm)
-            if on_chunk is not None:
-                # chunk boundary: the drained rows are on host and the
-                # next dispatch has not donated the state yet — the one
-                # point a checkpoint can capture a resumable snapshot
-                on_chunk(state=state, iterations=iters, rows=rows,
-                         calibrator=calib, last_active=last_active)
-            if last_active == 0:
-                break
-        history = {k: np.concatenate(v) for k, v in rows.items()}
-    else:
-        # Legacy per-iteration driver (sync_every == 1): bit-for-bit the
-        # pre-chunk dataflow.  History is staged as device references and
-        # pulled once after convergence — the only per-iteration sync
-        # left is the loop condition itself.
-        for _ in range(config.max_iters):
-            signature = ("iteration", *shapes, correction is not None)
-            warm = _consume_warm(signature)
-            t_iter = time.monotonic()
-
-            def attempt(st=state, corr=correction):
-                return hytm_iteration(
-                    st, rt.csr, rt.parts, rt.zc_req, rt.inv_deg,
-                    program, config, rt.n_hub_partitions, corr, rt.route,
-                )
-
+        with span("chunk", obs, cat=CAT_RUN, track=track,
+                  vt=float(iters)) as chunk_span:
+            t_chunk = time.monotonic()
             with span("hytm.dispatch" if warm else "hytm.compile", obs,
-                      track=_TRACK):
-                if not warm:
-                    scopes.register(
-                        signature, hytm_iteration, state, rt.csr, rt.parts,
-                        rt.zc_req, rt.inv_deg, program, config,
-                        rt.n_hub_partitions, correction, rt.route)
-                state, info = attempt() if faults is None else guarded_dispatch(
-                    attempt, site="chunk_dispatch", faults=faults,
-                    policy=retry, obs=obs, mesh=False,
-                    kernels=resolve_use_kernels(config.use_kernels),
-                )
-            iters += 1
+                      track=track):
+                if not warm and signature is not None:
+                    scopes.register(signature, fn, state, history, *args)
+                state, history, n_done, last_active, pe_sum = (
+                    attempt() if faults is None else guarded_dispatch(
+                        attempt, site="chunk_dispatch", faults=faults,
+                        policy=retry, obs=obs, mesh=mesh,
+                        kernels=resolve_use_kernels(config.use_kernels),
+                    ))
+            with span("hytm.wait", obs, track=track):
+                n_done, last_active = int(n_done), int(last_active)
+            iters += n_done
+            ran_with = correction
             if calib is not None:
-                correction = calib.observe_iteration(
-                    state.values, info["per_engine_time"], t_iter,
-                    skip=iters == 1,  # iteration 1 measures compile
+                # observe BEFORE the history drain so the measured
+                # wall window covers dispatch + execution only
+                correction = calib.observe(
+                    state.values, np.asarray(pe_sum, dtype=float), t_chunk,
+                    skip=not warm,  # a compiling chunk measures compile
                 )
-            for k in rows:
-                rows[k].append(info[k])
-            with span("hytm.wait", obs, track=_TRACK):
-                next_active = int(info["next_active"])
-            if next_active == 0:
-                break
-        with span("hytm.drain", obs, track=_TRACK):
-            staged = jax.device_get(rows)  # one host conversion, post-hoc
-            history = {k: np.stack(v) for k, v in staged.items()}
-            if obs is not None:
-                record_history_rows(obs, history, iters, 0)
-    with span("hytm.result", obs, track=_TRACK):
+            # drain before the next dispatch donates these buffers;
+            # rows past n_done are stale (early exit) and sliced off
+            with span("hytm.drain", obs, track=track):
+                drained = jax.device_get(history)
+                for k in rows:
+                    rows[k].append(drained[k][:n_done])
+                if on_rows is not None:
+                    more = on_rows(drained, n_done, iters - n_done, ran_with)
+                    for k, v in more.items():
+                        extra_rows.setdefault(k, []).append(v)
+                if obs is not None:
+                    record_history_rows(obs, drained, n_done,
+                                        iters - n_done, track=track)
+                    chunk_span.vt_dur = float(n_done)
+                    chunk_span.args.update(n_done=n_done, warm=warm)
+        if on_chunk is not None:
+            # chunk boundary: the drained rows are on host and the
+            # next dispatch has not donated the state yet — the one
+            # point a checkpoint can capture a resumable snapshot
+            on_chunk(state=state, iterations=iters, rows=rows,
+                     calibrator=calib, last_active=last_active)
+        if last_active == 0:
+            break
+    history = {k: np.concatenate(v) for k, v in (rows | extra_rows).items()}
+    with span("hytm.result", obs, track=track):
         jax.block_until_ready(state.values)
         wall = time.monotonic() - t0
         result = HyTMResult(
-            values=np.asarray(state.values),
-            delta=np.asarray(state.delta),
+            # the owner layout's ghost pads are cut off here
+            values=np.asarray(state.values)[:n_nodes],
+            delta=np.asarray(state.delta)[:n_nodes],
             iterations=iters,
             wall_seconds=wall,
             modeled_seconds=float(np.sum(history[KEY_TRANSFER_TIME])),
             total_transfer_bytes=float(np.sum(history[KEY_TRANSFER_BYTES])),
             history=history,
+            total_ici_bytes=float(np.sum(history.get(KEY_ICI_BYTES, 0.0))),
+            modeled_ici_seconds=float(np.sum(history.get(KEY_ICI_TIME, 0.0))),
             total_mispredictions=int(np.sum(history[KEY_MISPREDICTIONS])),
             engine_corrections=(
                 calib.correction() if calib is not None else None
@@ -1063,7 +1067,7 @@ def _run_single_device(program, source, config, rt, initial_state,
         )
         if obs is not None:
             record_run(
-                obs, result, track=_TRACK, wall_start=obs.wall_at(t0),
-                wall_dur=wall, program=program.name,
+                obs, result, track=track, wall_start=obs.wall_at(t0),
+                wall_dur=wall, program=program.name, label=label,
             )
     return result

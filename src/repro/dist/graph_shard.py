@@ -6,18 +6,18 @@ management — the edge-balanced partition — is also the natural unit of
 space as a ``(P_local, B)`` blocked edge array; vertex state (values,
 pending Δ, frontier) is replicated, the per-iteration flow is:
 
-  1. partition activity stats + Δ mass        (replicated, O(P))
-  2. per-device cost model + engine selection (Algorithm 1 on the local
-     stats shard — selection is per-partition, so the local result equals
-     the single-device one)
-  3. per-device priority schedule over its local partitions (hub ids are
+  1. partition activity stats, cost model + engine selection (Algorithm
+     1), Δ mass and the global second-pass mask — the single-device
+     selection stage (``core.hytm.select_partitions``) on replicated
+     state; each device takes its shard of the selected engines
+  2. per-device priority schedule over its local partitions (hub ids are
      globalized with the device's partition offset; the Δ-mode top-K
-     second-pass mask is a global rank, precomputed on replicated state)
-  4. local sweep over the local blocks, then one collective merge:
+     second-pass mask is a global rank, computed in step 1)
+  3. local sweep over the local blocks, then one collective merge:
      ``pmin`` for traversal combiners, ``psum`` for accumulative ones —
      the frontier/Δ exchange of the two-level HyTM
-  5. recompute-once second pass over loaded priority partitions, merged
-     the same way.
+  4. recompute-once second pass over loaded priority partitions, merged
+     the same way, then the single-device next-state and info stages.
 
 The cross-device sweep is **bulk-synchronous**: every device relaxes
 against the iteration-start state and updates merge once per pass.  That
@@ -26,10 +26,11 @@ dataflow exactly — bit-for-bit for min-combiners, up to float-summation
 order for sum-combiners — which is the equivalence contract
 ``tests/test_distributed.py`` checks on forced-host meshes.
 
-Engine semantics are unchanged: each local partition still relaxes
-through its selected FILTER/COMPACT/ZEROCOPY engine via ``lax.switch``,
-so the cost model's per-partition decisions (and the modeled transfer
-accounting) are identical to the single-device run.
+Engine semantics are unchanged: each local partition relaxes through its
+selected FILTER/COMPACT/ZEROCOPY engine via ``lax.switch``, and the
+selection is the single-device one, so the per-partition decisions (and
+the modeled transfer accounting) are identical to the single-device run.
+The host loop is ``core.hytm.drive_chunks`` as well.
 
 Second level (DESIGN.md §2): the cross-device merge is itself
 transfer-managed *in the model* — ``ici_level_cost`` selects per
@@ -63,7 +64,6 @@ sum-combiners, exactly like the replicated layout.
 
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 from functools import partial
 
@@ -77,24 +77,13 @@ from repro.core.cost_model import (
     COMPACT,
     FILTER,
     HISTORY_KEYS,
-    KEY_ACTIVE_EDGES,
-    KEY_ACTIVE_VERTICES,
-    KEY_ENGINES,
     KEY_ICI_BYTES,
     KEY_ICI_ENGINE,
     KEY_ICI_TIME,
     KEY_MERGED_ENTRIES,
     KEY_MISPREDICTIONS,
-    KEY_N_TASKS,
     KEY_PER_ENGINE_TIME,
-    KEY_TRANSFER_BYTES,
-    KEY_TRANSFER_TIME,
     NONE,
-    engine_costs,
-    init_history_buffers,
-    partition_stats,
-    select_engines,
-    selection_diagnostics,
     zc_request_counts,
 )
 from repro.core.engines import EdgeBlock, relax_with_engine
@@ -105,7 +94,12 @@ from repro.core.hytm import (
     HyTMState,
     _consume_warm,
     chunked_while,
-    quiet_donation,
+    drive_chunks,
+    iteration_info,
+    next_state,
+    pass2_frontier,
+    select_partitions,
+    start_state,
 )
 from repro.core.partition import (
     DevicePartitions,
@@ -113,11 +107,13 @@ from repro.core.partition import (
     partition_graph,
 )
 from repro.core.scheduler import make_schedule
-from repro.core.task_generation import forced_engine_plan, generate_tasks
 from repro.graph.algorithms import MIN, SUM, VertexProgram
 from repro.graph.csr import CSRGraph
 from repro.obs import span
-from repro.obs.export import CAT_RUN
+from repro.obs.scopes import SWEEP, scope
+
+# the recorder track of the mesh driver's events
+_TRACK = "mesh"
 
 
 @jax.tree_util.register_dataclass
@@ -206,7 +202,9 @@ class ShardedRuntime:
     vertex_sharding: str = "replicated"
     n_pad: int = 0
     halo: HaloPlan | None = None
-    # (program, config[, chunk]) -> jitted iteration/chunk; reusing a
+    # ("chunk" | "lanes", program, config, chunk, ...) -> a jitted chunk
+    # with its warm signatures, and ("info", program, config, grid) -> one
+    # iteration's info shapes; reusing a
     # runtime across run_hytm_sharded calls reuses the compiled sweep
     # instead of retracing a fresh shard_map closure every run.  The
     # device buffers above are *arguments* of the compiled functions, not
@@ -428,6 +426,11 @@ def _apply_merged(
 ):
     """Synchronous state update from a globally merged contribution vector
     (the shard_map analogue of core.hytm._sweep's sync branch)."""
+    if program.peel_k is not None:
+        # peeling: agg is the per-vertex count of newly-removed
+        # in-neighbors; subtract it from the remaining degree (additive,
+        # so async == sync == sharded)
+        return values - agg, delta, touched
     if program.combine == MIN:
         improved = touched & (agg < values)
         values = jnp.where(improved, agg, values)
@@ -441,10 +444,11 @@ def _make_iteration_impl(
     rt: ShardedRuntime, program: VertexProgram, config: HyTMConfig
 ):
     """Build the untraced per-iteration body for one runtime/program.
-    ``make_sharded_iteration`` jits it directly (the sync_every=1 driver);
-    ``make_sharded_chunk`` inlines it in a ``lax.while_loop`` so K
+    ``make_sharded_chunk`` runs it in a ``lax.while_loop`` so K
     shard_mapped iterations share one dispatch; ``vmap`` lifts it over a
-    lane dimension (``make_sharded_batched_chunk``).
+    lane dimension (``make_sharded_batched_chunk``).  Its selection,
+    pass-2 frontier, next-state and info stages are the single-device
+    ones (``core.hytm``); only the sweep passes are its own.
 
     ``rt`` contributes only the *static* structure (mesh, axis, node and
     partition counts) — the device buffers are traced **arguments** of
@@ -461,37 +465,21 @@ def _make_iteration_impl(
     # edge blocks read, then merges back to owned slices (_local_sweep)
     n = rt.n_pad if owner else rt.n_nodes
     n_loc = n // n_dev if owner else 0
-    P_total = rt.n_partitions
-    P_local = P_total // n_dev
-    mode = config.cds_mode
+    P_local = rt.n_partitions // n_dev
     # resolved once at trace time, like the single-device sweep; the
     # shard_mapped local sweep then routes through the same kernel or
     # oracle engines as every other consumer
     use_kernels = resolve_use_kernels(config.use_kernels)
 
-    def select_local(stats_slice, correction):
-        """Algorithm 1 on a (P_local,) stats shard — identical result to
-        slicing the global selection (selection is per-partition)."""
-        if config.forced_engine is None:
-            costs = engine_costs(stats_slice, config.link)
-            return select_engines(stats_slice, costs, config.link, correction)
-        return jnp.where(
-            stats_slice.active_edges > 0, config.forced_engine, NONE
-        ).astype(jnp.int32)
+    def sweep_pass(blocks, engines, second_mask, delta_mass, frontier,
+                   operand):
+        """One shard_mapped sweep pass over each device's shard of
+        ``engines``; returns the merged (agg, touched)."""
 
-    def sweep_pass(blocks, stats, second_mask, frontier, operand, delta_mass,
-                   correction, pass_two: bool):
-        """One shard_mapped sweep pass; returns merged (agg, touched) plus
-        the engines each device selected (for the second pass mask)."""
-
-        def local(blocks_l, stats_l, mask_l, dmass_l, frontier_, operand_,
-                  corr_):
+        def local(blocks_l, engines_l, mask_l, dmass_l, frontier_, operand_):
             dev = jax.lax.axis_index(axis)
-            engines_l = select_local(stats_l, corr_)
-            if pass_two:
-                engines_l = jnp.where(mask_l, engines_l, NONE)
             sched = make_schedule(
-                engines_l, dmass_l, rt.n_hub_partitions, mode,
+                engines_l, dmass_l, rt.n_hub_partitions, config.cds_mode,
                 config.recompute_once, pid_offset=dev * P_local,
                 priority_mask=mask_l,
             )
@@ -501,30 +489,25 @@ def _make_iteration_impl(
                 # model charges the compacted halo candidate against it)
                 frontier_ = jax.lax.all_gather(frontier_, axis, tiled=True)
                 operand_ = jax.lax.all_gather(operand_, axis, tiled=True)
-            agg, touched = _local_sweep(
+            return _local_sweep(
                 blocks_l, engines_l, sched.order, frontier_, operand_,
-                n, program, axis, use_kernels,
-                owner=owner, n_loc=n_loc,
+                n, program, axis, use_kernels, owner=owner, n_loc=n_loc,
             )
-            return agg, touched
 
         shard = P(axis)
-        rep = P()
-        state_spec = shard if owner else rep
+        state_spec = shard if owner else P()
         fn = jax.shard_map(
             local,
             mesh=mesh,
             in_specs=(
                 BlockedEdges(src=P(axis, None), dst=P(axis, None),
                              weight=P(axis, None), in_range=P(axis, None)),
-                jax.tree.map(lambda _: shard, stats),
-                shard, shard, state_spec, state_spec, rep,
+                shard, shard, shard, state_spec, state_spec,
             ),
             out_specs=(state_spec, state_spec),
             check_vma=False,
         )
-        return fn(blocks, stats, second_mask, delta_mass, frontier,
-                  operand, correction)
+        return fn(blocks, engines, second_mask, delta_mass, frontier, operand)
 
     def iteration(
         state: HyTMState,
@@ -535,131 +518,48 @@ def _make_iteration_impl(
         inv_deg: jax.Array,
         correction: jax.Array | None = None,
     ):
-        if correction is None:
-            # identity correction: float multiply by 1.0 is exact, so the
-            # uncorrected path stays bit-identical to the oracle contract
-            correction = jnp.ones(3, jnp.float32)
         frontier = state.frontier
-        values, delta = state.values, state.delta
+        sel = select_partitions(state, out_degree, zc_req, parts, program,
+                                config, rt.n_hub_partitions, correction)
+        engines, second_mask = sel.plan.engines, sel.sched.second_pass
 
-        # (1) global stats + Δ mass on the replicated vertex state.  As in
-        # core.hytm: only the 'delta' CDS mode reads the Δ mass, and
-        # min-combine programs carry an identically-zero Δ — skip the
-        # segment-sum in both cases.
-        stats = partition_stats(frontier, out_degree, zc_req, parts)
-        if program.combine == MIN or mode != "delta":
-            delta_mass = jnp.zeros(P_total, jnp.float32)
-        else:
-            delta_mass = jax.ops.segment_sum(
-                jnp.abs(delta) * frontier, parts.vertex_part_id,
-                num_segments=P_total,
+        def operand(values, delta):
+            if program.combine == SUM:
+                return program.damping * delta * inv_deg
+            return values
+
+        # pass 1: every active partition, synchronous merge
+        with scope(SWEEP):
+            agg, touched = sweep_pass(
+                blocks, engines, second_mask, sel.delta_mass, frontier,
+                operand(state.values, state.delta),
             )
-
-        # (2) global plan for the transfer accounting (identical to the
-        # per-device selections — selection is per-partition)
-        if config.forced_engine is None:
-            plan = generate_tasks(
-                stats, config.link, combine_k=config.combine_k,
-                enable_combination=config.enable_task_combination,
-                correction=correction,
-            )
-        else:
-            plan = forced_engine_plan(
-                stats, config.link, config.forced_engine,
-                enable_combination=config.enable_task_combination,
-                combine_k=config.combine_k,
-            )
-
-        # (3) global second-pass mask (Δ-mode top-K is a global rank)
-        sched_global = make_schedule(
-            plan.engines, delta_mass, rt.n_hub_partitions, mode,
-            config.recompute_once,
-        )
-        second_mask = sched_global.second_pass
-
-        # (4) pass 1: every active partition, synchronous merge
-        if program.combine == SUM:
-            operand = program.damping * delta * inv_deg
-        else:
-            operand = values
-        agg, touched = sweep_pass(
-            blocks, stats, second_mask, frontier, operand, delta_mass,
-            correction, pass_two=False,
-        )
-        if program.peel_k is not None:
-            # peeling: merged agg is the per-vertex count of newly-removed
-            # in-neighbors; subtract from the remaining degree (additive,
-            # so async == sync == sharded — core.hytm._sweep's peel branch)
-            values1, delta1, activated = values - agg, delta, touched
-        else:
             values1, delta1, activated = _apply_merged(
-                values, delta, frontier, agg, touched, program,
-            )
+                state.values, state.delta, frontier, agg, touched, program)
 
-        # (5) pass 2: recompute-once over loaded priority partitions
-        if program.peel_k is not None:
-            # a second peel pass would double-subtract the removal counts
-            frontier2 = jnp.zeros_like(frontier)
-        elif program.combine == MIN:
-            frontier2 = frontier | activated
-        else:
-            # |Δ| matches core.hytm: signed correction deltas (the
-            # incremental repro.stream path) must keep propagating.
-            frontier2 = jnp.abs(delta1) > program.tolerance
-        if program.combine == SUM:
-            operand2 = program.damping * delta1 * inv_deg
-        else:
-            operand2 = values1
-        agg2, touched2 = sweep_pass(
-            blocks, stats, second_mask, frontier2, operand2, delta_mass,
-            correction, pass_two=True,
-        )
-        # pass-2 consumption only touches re-processed partitions
-        processed2 = second_mask[parts.vertex_part_id] & (
-            plan.engines[parts.vertex_part_id] != NONE
-        )
-        if program.peel_k is not None:
-            values2, delta2, activated2 = values1 - agg2, delta1, touched2
-        else:
+        # pass 2: recompute-once over loaded priority partitions
+        with scope(SWEEP):
+            engines2 = jnp.where(second_mask, engines, NONE)
+            frontier2 = pass2_frontier(program, frontier, activated, delta1)
+            agg2, touched2 = sweep_pass(
+                blocks, engines2, second_mask, sel.delta_mass, frontier2,
+                operand(values1, delta1),
+            )
+            # pass-2 consumption only touches re-processed partitions
+            processed2 = engines2[parts.vertex_part_id] != NONE
             values2, delta2, activated2 = _apply_merged(
                 values1, delta1, frontier2 & processed2, agg2, touched2,
                 program,
             )
-        activated = activated | activated2
+        new_state = next_state(program, values2, delta2, activated,
+                               activated2)
+        info = iteration_info(sel, frontier, new_state.frontier, config,
+                              correction)
         # entries a compacted ICI exchange would ship: destinations any
         # device touched this iteration (both passes) — NOT the source
         # frontier, which undercounts by the fan-out in hub regimes
-        merged_entries = jnp.sum((touched | touched2).astype(jnp.int32))
-
-        if program.peel_k is not None:
-            # newly-removed: alive vertices whose remaining degree fell
-            # below k this round (matches core.hytm's peel post-pass)
-            alive = delta2 < 0.5
-            newly = alive & (values2 < program.peel_k)
-            next_frontier = newly
-            delta2 = delta2 + newly.astype(jnp.float32)
-        elif program.combine == MIN:
-            next_frontier = activated
-        else:
-            next_frontier = jnp.abs(delta2) > program.tolerance
-
-        new_state = HyTMState(values=values2, delta=delta2, frontier=next_frontier)
-        per_engine_time, mispredictions = selection_diagnostics(
-            plan.engines, plan.transfer_time, stats, plan.costs, correction,
-        )
-        info = {
-            KEY_ENGINES: plan.engines,
-            KEY_TRANSFER_BYTES: plan.transfer_bytes,
-            KEY_TRANSFER_TIME: jnp.sum(plan.transfer_time)
-            + plan.n_tasks.astype(jnp.float32) * config.link.launch_overhead_s,
-            KEY_N_TASKS: plan.n_tasks,
-            KEY_ACTIVE_VERTICES: jnp.sum(frontier.astype(jnp.int32)),
-            KEY_ACTIVE_EDGES: jnp.sum(stats.active_edges),
-            "next_active": jnp.sum(next_frontier.astype(jnp.int32)),
-            KEY_PER_ENGINE_TIME: per_engine_time,
-            KEY_MISPREDICTIONS: mispredictions,
-            KEY_MERGED_ENTRIES: merged_entries,
-        }
+        info[KEY_MERGED_ENTRIES] = jnp.sum(
+            (touched | touched2).astype(jnp.int32))
         return new_state, info
 
     return iteration
@@ -670,15 +570,6 @@ def _runtime_args(rt: ShardedRuntime) -> tuple:
     takes, read fresh from the runtime at each dispatch (so a patched
     view — DeltaCSR's sharded grid — is always what executes)."""
     return rt.blocks, rt.parts, rt.out_degree, rt.zc_req, rt.inv_deg
-
-
-def make_sharded_iteration(
-    rt: ShardedRuntime, program: VertexProgram, config: HyTMConfig
-):
-    """Build the jitted per-iteration function for one runtime/program:
-    ``iteration(state, blocks, parts, out_degree, zc_req, inv_deg,
-    correction)``."""
-    return jax.jit(_make_iteration_impl(rt, program, config))
 
 
 def make_sharded_chunk(
@@ -697,27 +588,16 @@ def make_sharded_chunk(
     ``_make_iteration_impl``), so warm-started reruns over a patched
     ``DeltaCSR`` view reuse this compiled chunk."""
     impl = _make_iteration_impl(rt, program, config)
-    keys = HISTORY_KEYS + (KEY_MERGED_ENTRIES,)
 
     @partial(jax.jit, donate_argnames=("state", "history"))
     def chunk_fn(state: HyTMState, history: dict, blocks, parts, out_degree,
-                 zc_req, inv_deg, correction: jax.Array):
+                 zc_req, inv_deg, correction: jax.Array | None):
         return chunked_while(
             lambda st: impl(st, blocks, parts, out_degree, zc_req, inv_deg,
                             correction),
             state, history, chunk)
 
-    shapes_cell: dict = {}  # eval_shape once per shape signature
-
-    def init_history(state: HyTMState, correction: jax.Array) -> dict:
-        shape_key = (rt.blocks.src.shape, rt.parts.n_partitions,
-                     rt.parts.block_size)
-        if shape_key not in shapes_cell:
-            shapes_cell[shape_key] = jax.eval_shape(
-                impl, state, *_runtime_args(rt), correction)[1]
-        return init_history_buffers(shapes_cell[shape_key], chunk, keys=keys)
-
-    return chunk_fn, init_history
+    return chunk_fn
 
 
 def make_sharded_batched_chunk(
@@ -772,8 +652,7 @@ def make_sharded_batched_chunk(
             )
 
         n_lanes = state.values.shape[0]
-        # sentinel ones: the first iteration always runs, matching the
-        # K=1 loop (which runs one iteration even on an empty frontier)
+        # sentinel ones: the first iteration always runs, as in hytm_chunk
         init = (state, jnp.int32(0), jnp.ones(n_lanes, jnp.int32),
                 jnp.zeros(3, jnp.float32), jnp.int32(0),
                 jnp.zeros(chunk, jnp.int32))
@@ -896,24 +775,19 @@ def owner_state_pad_values(program: VertexProgram) -> tuple[float, float]:
     return float(np.inf), 0.0
 
 
-def _owner_place_state(
-    rt: ShardedRuntime, program: VertexProgram,
-    values: jax.Array, delta: jax.Array, frontier: jax.Array,
-) -> HyTMState:
+def _owner_place_state(rt: ShardedRuntime, program: VertexProgram,
+                       state: HyTMState) -> HyTMState:
     """Pad an (n,) state triple to (n_pad,) and owner-shard it P(axis) —
     the placement every owner-mode dispatch (cold, warm, incremental,
     resumed) takes."""
     pad_v, pad_d = owner_state_pad_values(program)
-    values = _pad_vertex_vec(jnp.asarray(values, jnp.float32), rt.n_pad,
-                             pad_v)
-    delta = _pad_vertex_vec(jnp.asarray(delta, jnp.float32), rt.n_pad, pad_d)
-    frontier = _pad_vertex_vec(jnp.asarray(frontier, bool), rt.n_pad, False)
     shard = NamedSharding(rt.mesh, P(rt.axis))
-    return HyTMState(
-        values=jax.device_put(values, shard),
-        delta=jax.device_put(delta, shard),
-        frontier=jax.device_put(frontier, shard),
-    )
+    return HyTMState(*(
+        jax.device_put(_pad_vertex_vec(jnp.asarray(x, dtype), rt.n_pad, pad),
+                       shard)
+        for x, dtype, pad in ((state.values, jnp.float32, pad_v),
+                              (state.delta, jnp.float32, pad_d),
+                              (state.frontier, bool, False))))
 
 
 def run_hytm_sharded(
@@ -961,11 +835,12 @@ def run_hytm_sharded(
     min-combine).  With ``runtime`` and ``initial_state`` both given,
     ``g`` may be ``None``.
 
-    ``faults``/``retry``/``on_chunk`` mirror ``run_hytm``: injected
-    ``"chunk_dispatch"`` faults fire before the shard_mapped dispatch
-    (donated buffers intact, retries bit-identical), and ``on_chunk``
-    observes every chunk boundary for checkpointing — all zero-overhead
-    when absent.
+    The chunks run through ``core.hytm.drive_chunks``, the host loop of
+    the single device, with its spans on the ``mesh`` track:
+    ``faults``/``retry``/``on_chunk`` behave as in ``run_hytm``, and the
+    ICI level is charged per executed iteration from each chunk's drained
+    ``merged_entries`` rows, under the correction the chunk's selections
+    ran with.
     """
     if runtime is not None:
         rt = runtime
@@ -991,249 +866,84 @@ def run_hytm_sharded(
             f"runtime was built with vertex_sharding="
             f"{rt.vertex_sharding!r} but config requests "
             f"{config.vertex_sharding!r}; rebuild the runtime")
-    if initial_state is None:
-        if program.peel_k is not None:
-            # peeling programs seed from vertex degrees (init_state has no
-            # degree access); rt.out_degree is padded in owner mode —
-            # slice to the real vertices so pads never enter the frontier
-            deg = np.asarray(rt.out_degree)[:rt.n_nodes].astype(np.float32)
-            removed = deg < program.peel_k
-            values, delta, frontier = (
-                jnp.asarray(deg), jnp.asarray(removed, jnp.float32),
-                jnp.asarray(removed))
+    n_dev = int(mesh.shape[config.mesh_axis])
+    grid = (rt.blocks.src.shape, rt.parts.n_partitions, rt.parts.block_size)
+
+    def init():
+        if initial_state is None:
+            # rt.out_degree is padded in owner mode: slice to the real
+            # vertices so pads never enter a peel's frontier
+            state = start_state(program, rt.out_degree[:rt.n_nodes],
+                                rt.n_nodes, source)
         else:
-            values, delta, frontier = program.init_state(rt.n_nodes, source)
+            state = initial_state
         if owner:
-            state = _owner_place_state(rt, program, values, delta, frontier)
-        else:
-            state = HyTMState(values=values, delta=delta, frontier=frontier)
-    elif owner:
-        state = _owner_place_state(
-            rt, program, jnp.asarray(initial_state.values),
-            jnp.asarray(initial_state.delta),
-            jnp.asarray(initial_state.frontier))
-    else:
+            return _owner_place_state(rt, program, state)
+        if initial_state is None:
+            return state
         # replicate the warm triple over the mesh — identical placement to
         # the cold start, so the compiled sweep sees one layout either way
         rep = NamedSharding(mesh, P())
-        state = HyTMState(
-            values=jax.device_put(jnp.asarray(initial_state.values), rep),
-            delta=jax.device_put(jnp.asarray(initial_state.delta), rep),
-            frontier=jax.device_put(jnp.asarray(initial_state.frontier), rep),
-        )
+        return jax.tree.map(lambda x: jax.device_put(jnp.asarray(x), rep),
+                            state)
 
-    n_dev = int(mesh.shape[config.mesh_axis])
+    def info_shapes(state, correction):
+        key = ("info", program, config, *grid)
+        if key not in rt.iteration_cache:
+            rt.iteration_cache[key] = jax.eval_shape(
+                _make_iteration_impl(rt, program, config), state,
+                *_runtime_args(rt), correction)[1]
+        return rt.iteration_cache[key]
 
-    calib = None
-    correction = None
-    corr_np = None
-    if config.autotune:
-        from repro.autotune.feedback import OnlineCalibrator
+    def prepare(chunk, correction):
+        key = ("chunk", program, config, chunk)
+        cached = rt.iteration_cache.get(key)
+        if cached is None:
+            cached = rt.iteration_cache[key] = {
+                "fn": make_sharded_chunk(rt, program, config, chunk),
+                "seen": set()}
+        # warm iff THIS chunk_fn already dispatched THESE shapes: the
+        # seen-set lives on the cached entry, so when a DeltaCSR
+        # merge-compaction drops the entry (fresh jit cache) or moves
+        # the block grid, the recompiling dispatch is cold and its
+        # wall time never feeds the calibrator
+        warm = _consume_warm(grid, registry=cached["seen"])
+        return cached["fn"], (*_runtime_args(rt), correction), warm, None
 
-        calib = (calibrator if calibrator is not None
-                 else OnlineCalibrator(decay=config.autotune_decay))
-        correction = jnp.asarray(calib.correction(), jnp.float32)
-        corr_np = np.asarray(correction, dtype=float)
+    def charge_ici(drained, n_done, first, correction):
+        """The second-level accounting of a chunk's iterations (the
+        exchange mode depends on the live touched-entry count, and
+        feedback can reweigh the choice)."""
+        from repro.obs.record import record_ici
 
-    if config.sync_every < 1:
-        raise ValueError(f"sync_every must be >= 1, got {config.sync_every}")
-    if on_chunk is not None and config.sync_every == 1:
-        raise ValueError(
-            "on_chunk (checkpointing) requires the chunked driver — "
-            "set sync_every >= 2")
-    rows: dict[str, list] = {k: [] for k in HISTORY_KEYS}
-    # second-level accounting (per iteration: the exchange mode depends on
-    # the live active-vertex count, and feedback can reweigh the choice)
-    ici_hist: dict[str, list] = {
-        KEY_ICI_BYTES: [], KEY_ICI_TIME: [], KEY_ICI_ENGINE: []}
-
-    def charge_ici(merged_entries: float) -> None:
-        if owner and rt.halo is not None:
-            # owner layout: a compacted exchange ships at most the halo
-            halo_entries = min(float(merged_entries),
-                               float(rt.halo.halo_total))
-            ib, it_, ie = halo_level_cost(
-                rt.n_nodes, float(merged_entries), rt.halo.halo_total,
-                n_dev, config.ici_link, corr_np,
-            )
-        else:
-            halo_entries = None
-            ib, it_, ie = ici_level_cost(
-                rt.n_nodes, float(merged_entries), n_dev, config.ici_link,
-                corr_np,
-            )
-        it = len(ici_hist[KEY_ICI_BYTES])  # global iteration index
-        ici_hist[KEY_ICI_BYTES].append(ib)
-        ici_hist[KEY_ICI_TIME].append(it_)
-        ici_hist[KEY_ICI_ENGINE].append(ie)
-        if obs is not None:
-            from repro.obs.record import record_ici
-
-            record_ici(
-                obs, track="ici", it=it, bytes_=ib, seconds=it_, engine=ie,
-                merged_entries=float(merged_entries),
-                halo_entries=halo_entries,
-            )
-
-    t0 = time.monotonic()
-    iters = 0
-    if config.sync_every > 1:
-        # Chunked driver: one shard_mapped lax.while_loop dispatch per K
-        # iterations (same contract as core.hytm.hytm_chunk); the ICI
-        # level is charged per executed iteration from the drained
-        # merged_entries rows, under the SAME correction the chunk's
-        # HBM-level selections ran with.
-        corr_arr = (correction if correction is not None
-                    else jnp.ones(3, jnp.float32))
-        history, cur_chunk = None, -1
-        while iters < config.max_iters:
-            chunk = min(config.sync_every, config.max_iters - iters)
-            key = ("chunk", program, config, chunk)
-            cached = rt.iteration_cache.get(key)
-            if cached is None:
-                chunk_fn, init_history = make_sharded_chunk(
-                    rt, program, config, chunk)
-                cached = {"fn": chunk_fn, "init": init_history,
-                          "seen": set()}
-                rt.iteration_cache[key] = cached
-            if chunk != cur_chunk:
-                # allocated once per chunk size; afterwards the drained
-                # buffers cycle back in (donated reuse on accelerators)
-                history = cached["init"](state, corr_arr)
-                cur_chunk = chunk
-            # warm iff THIS chunk_fn already dispatched THESE shapes: the
-            # seen-set lives on the cached entry, so when a DeltaCSR
-            # merge-compaction drops the entry (fresh jit cache) or moves
-            # the block grid, the recompiling dispatch is cold and its
-            # wall time never feeds the calibrator
-            warm = _consume_warm(
-                (rt.blocks.src.shape, rt.parts.n_partitions,
-                 rt.parts.block_size),
-                registry=cached["seen"],
-            )
-            # faults fire BEFORE the shard_mapped dispatch — donated
-            # buffers from the previous chunk stay intact, so a retried
-            # dispatch is bit-identical
-            def attempt(st=state, h=history, ca=corr_arr, fn=cached["fn"]):
-                with quiet_donation():
-                    return fn(st, h, *_runtime_args(rt), ca)
-
-            with span("chunk", obs, cat=CAT_RUN, track="mesh",
-                      vt=float(iters)) as chunk_span:
-                t_chunk = time.monotonic()
-                if faults is None:
-                    out = attempt()
-                else:
-                    from repro.resilience.supervisor import guarded_dispatch
-
-                    out = guarded_dispatch(
-                        attempt, site="chunk_dispatch", faults=faults,
-                        policy=retry, obs=obs, mesh=True,
-                        kernels=resolve_use_kernels(config.use_kernels),
-                    )
-                state, history, n_done, last_active, pe_sum = out
-                n_done = int(n_done)
-                iters += n_done
-                if calib is not None:
-                    # observe BEFORE the drain + ICI loop: the measured
-                    # wall window covers dispatch + execution only
-                    corr_arr = calib.observe_chunk(
-                        state.values, np.asarray(pe_sum, dtype=float),
-                        t_chunk, skip=not warm,
-                    )
-                # drain BEFORE the next dispatch donates these buffers
-                drained = jax.device_get(history)
-                for me in drained[KEY_MERGED_ENTRIES][:n_done]:
-                    charge_ici(me)  # charged under the chunk's correction
-                if calib is not None:
-                    corr_np = np.asarray(corr_arr, dtype=float)
-                for k in rows:
-                    rows[k].append(drained[k][:n_done])
-                if obs is not None:
-                    from repro.obs.record import record_history_rows
-
-                    record_history_rows(
-                        obs, drained, n_done, iters - n_done, track="mesh")
-                    chunk_span.vt_dur = float(n_done)
-                    chunk_span.args.update(n_done=n_done, warm=warm)
-            if on_chunk is not None:
-                on_chunk(state=state, iterations=iters, rows=rows,
-                         calibrator=calib, last_active=int(last_active))
-            if int(last_active) == 0:
-                break
-        history = {k: np.concatenate(v) for k, v in rows.items()}
-    else:
-        cache_key = (program, config)
-        iteration = rt.iteration_cache.get(cache_key)
-        if iteration is None:
-            iteration = make_sharded_iteration(rt, program, config)
-            rt.iteration_cache[cache_key] = iteration
-        for _ in range(config.max_iters):
-            t_iter = time.monotonic()
-            if faults is None:
-                state, info = iteration(
-                    state, *_runtime_args(rt), correction)
+        corr = None if correction is None else np.asarray(correction, float)
+        charged = []
+        for it, me in enumerate(drained[KEY_MERGED_ENTRIES][:n_done], first):
+            if owner and rt.halo is not None:
+                # owner layout: a compacted exchange ships at most the halo
+                halo_entries = min(float(me), float(rt.halo.halo_total))
+                cost = halo_level_cost(
+                    rt.n_nodes, float(me), rt.halo.halo_total, n_dev,
+                    config.ici_link, corr)
             else:
-                from repro.resilience.supervisor import guarded_dispatch
+                halo_entries = None
+                cost = ici_level_cost(rt.n_nodes, float(me), n_dev,
+                                      config.ici_link, corr)
+            charged.append(cost)
+            if obs is not None:
+                record_ici(obs, track="ici", it=it, bytes_=cost[0],
+                           seconds=cost[1], engine=cost[2],
+                           merged_entries=float(me),
+                           halo_entries=halo_entries)
+        ici_bytes, ici_seconds, ici_engines = zip(*charged)
+        return {KEY_ICI_BYTES: np.asarray(ici_bytes),
+                KEY_ICI_TIME: np.asarray(ici_seconds),
+                KEY_ICI_ENGINE: np.asarray(ici_engines)}
 
-                def _attempt(st=state, corr=correction):
-                    return iteration(st, *_runtime_args(rt), corr)
-
-                state, info = guarded_dispatch(
-                    _attempt, site="chunk_dispatch", faults=faults,
-                    policy=retry, obs=obs, mesh=True,
-                    kernels=resolve_use_kernels(config.use_kernels),
-                )
-            iters += 1
-            # charge the ICI level under the SAME correction this
-            # iteration's HBM-level selection ran with (the update below
-            # only steers the next iteration, exactly as on the
-            # single-device path)
-            charge_ici(info[KEY_MERGED_ENTRIES])
-            if calib is not None:
-                correction = calib.observe_iteration(
-                    state.values, info[KEY_PER_ENGINE_TIME], t_iter,
-                    skip=iters == 1,  # iteration 1 measures compile
-                )
-                corr_np = np.asarray(correction, dtype=float)
-            for k in rows:
-                rows[k].append(info[k])
-            if int(info["next_active"]) == 0:
-                break
-        # history stayed on device during the loop; one pull post-hoc
-        staged = jax.device_get(rows)
-        history = {k: np.stack(v) for k, v in staged.items()}
-        if obs is not None:
-            from repro.obs.record import record_history_rows
-
-            record_history_rows(obs, history, iters, 0, track="mesh")
-    jax.block_until_ready(state.values)
-    wall = time.monotonic() - t0
-
-    for k, v in ici_hist.items():
-        history[k] = np.asarray(v)
-    result = HyTMResult(
-        # owner mode: gather the sharded (n_pad,) vectors and drop the
-        # ghost pads so callers always see host (n,) arrays
-        values=np.asarray(state.values)[:rt.n_nodes],
-        delta=np.asarray(state.delta)[:rt.n_nodes],
-        iterations=iters,
-        wall_seconds=wall,
-        modeled_seconds=float(np.sum(history[KEY_TRANSFER_TIME])),
-        total_transfer_bytes=float(np.sum(history[KEY_TRANSFER_BYTES])),
-        history=history,
-        total_ici_bytes=float(np.sum(history[KEY_ICI_BYTES])),
-        modeled_ici_seconds=float(np.sum(history[KEY_ICI_TIME])),
-        total_mispredictions=int(np.sum(history[KEY_MISPREDICTIONS])),
-        engine_corrections=(
-            calib.correction() if calib is not None else None
-        ),
-    )
-    if obs is not None:
-        from repro.obs.record import record_run
-
-        record_run(
-            obs, result, track="mesh", wall_start=obs.wall_at(t0),
-            wall_dur=wall, program=program.name, label=f"run[{n_dev}dev]",
-        )
-    return result
+    with span("hytm.run", obs, track=_TRACK):
+        return drive_chunks(
+            program, config, init, info_shapes=info_shapes, prepare=prepare,
+            track=_TRACK, n_nodes=rt.n_nodes, calibrator=calibrator,
+            obs=obs, faults=faults, retry=retry, on_chunk=on_chunk,
+            keys=HISTORY_KEYS + (KEY_MERGED_ENTRIES,), on_rows=charge_ici,
+            mesh=True, label=f"run[{n_dev}dev]")

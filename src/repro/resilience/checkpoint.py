@@ -354,9 +354,6 @@ def resume_run(path: str | os.PathLike, g, program, *, config, source=0,
     from repro.core.hytm import HyTMState, run_hytm
 
     ckpt = restore(path, expect_anchor=expect_anchor, program=program.name)
-    if config.sync_every < 2:
-        raise ValueError("resume_run requires the chunked driver "
-                         "(sync_every >= 2)")
     run_layout = getattr(config, "vertex_sharding", "replicated")
     if ckpt.state_layout != run_layout:
         raise CheckpointError(

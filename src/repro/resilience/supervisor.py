@@ -240,7 +240,7 @@ def run_supervised(g, program, source=0, config=None, *, n_hubs: int = 0,
     have_ckpt = False
     while True:
         hook = None
-        if ckpt_path is not None and cfg.sync_every > 1:
+        if ckpt_path is not None:
             # owner-sharded runs snapshot gathered (n_pad,) arrays — the
             # hook records the layout + real vertex count so restore can
             # slice the pads and reject cross-layout resumes typed-ly

@@ -494,7 +494,7 @@ class LaneScheduler:
         svc = self.svc
         if svc._calibrator is None:
             return correction
-        refreshed = svc._calibrator.observe_chunk(
+        refreshed = svc._calibrator.observe(
             pe_sum, np.asarray(pe_sum, dtype=float), t_chunk, skip=not warm)
         svc._record_feedback(int(mp_sum), refreshed)
         return svc._correction

@@ -19,7 +19,7 @@ each partition's edge range as a log-structured segment:
 
 Device buffers are *patched* (one scatter over the touched lanes + the
 (P,) live-count and (n,) degree vectors), never rebuilt — shapes are
-static between merges so ``hytm_iteration`` keeps its compiled sweep.
+static between merges so ``hytm_chunk`` keeps its compiled sweep.
 When a partition's block overflows, a **merge-compaction** folds the log
 into a fresh CSR, re-partitions, and re-uploads (``layout_version`` bump).
 
@@ -185,7 +185,7 @@ class _LaneIndex:
 
 
 class DeltaCSR:
-    """Mutable, versioned graph with a ``hytm_iteration``-compatible runtime.
+    """Mutable, versioned graph with a ``hytm_chunk``-compatible runtime.
 
     The vertex set is fixed at construction (updates are edge-only).
     Invariants between merge-compactions:

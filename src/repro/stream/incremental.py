@@ -228,8 +228,8 @@ def run_incremental(
     (tests/test_stream_sharded.py).  ``mesh`` optionally pins the device
     mesh (defaults to every visible device).
 
-    The run inherits ``config.sync_every``: with K > 1 the residual
-    convergence runs through the chunked device-resident driver
+    The run inherits ``config.sync_every``: the residual convergence
+    runs K iterations per dispatch through the chunked driver
     (``core.hytm.hytm_chunk``, or ``graph_shard.make_sharded_chunk`` on
     the mesh).  Incremental runs are exactly where the chunk's early exit
     matters — warm starts converge in a handful of iterations, and the

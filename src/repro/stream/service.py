@@ -294,7 +294,8 @@ class GraphService:
         sweeps, incremental runs, full accumulative runs): refresh the
         cached correction and accumulate the misprediction count into
         ``stats.extra``.  ``correction`` skips re-solving when the caller
-        already holds the refreshed vector (observe_iteration's return)."""
+        already holds the refreshed vector (``OnlineCalibrator.observe``'s
+        return)."""
         if self._calibrator is None:
             return
         if correction is None:

@@ -2,15 +2,16 @@
 
 The contract under test (core.hytm.hytm_chunk and its consumers):
 
-* ``sync_every = K > 1`` runs K iterations per compiled
-  ``lax.while_loop`` dispatch and must be **bit-identical** to the
-  legacy ``K = 1`` per-iteration loop for min-combine programs — values,
-  iteration count, modeled transfer bytes, per-iteration engine picks —
-  and tolerance-bounded for sum-combine (XLA may fuse the loop body
-  differently than the standalone iteration);
+* ``sync_every = K`` runs K iterations per compiled ``lax.while_loop``
+  dispatch and must be **bit-identical** to an independent stepper —
+  one jitted ``_iteration_impl`` per dispatch, defined below — for
+  min-combine programs (values, iteration count, modeled transfer
+  bytes, per-iteration engine picks) and tolerance-bounded for
+  sum-combine (XLA may fuse the loop body differently than the
+  standalone iteration);
 * the early exit (while-condition on the previous iteration's
   ``next_active``) means a converged run never executes an iteration
-  past convergence, so iteration counts match K=1 exactly even when
+  past convergence, so iteration counts match at every K, even when
   K >> iterations;
 * the loop really batches: driver dispatches drop from O(iterations) to
   O(iterations / K) (monkeypatch-counted regression below);
@@ -20,14 +21,48 @@ The contract under test (core.hytm.hytm_chunk and its consumers):
 """
 
 import dataclasses
+from functools import partial
+from types import SimpleNamespace
 
+import jax
 import numpy as np
 import pytest
 
 from _forced_devices import run_forced_devices
-from repro.core.hytm import HyTMConfig, run_hytm
+from repro.core.hytm import (
+    HyTMConfig,
+    HyTMState,
+    _iteration_impl,
+    build_runtime,
+    run_hytm,
+)
 from repro.graph.algorithms import BFS, CC, PAGERANK, SSSP
 from repro.graph.generators import grid_mesh_graph, rmat_graph
+
+_step = partial(jax.jit, static_argnames=("program", "config",
+                                          "n_hub_partitions"))(_iteration_impl)
+
+
+def _stepped(g, program, source, config):
+    """The reference the chunk loop is held to: one jitted iteration per
+    dispatch, with a host sync on ``next_active`` after each, until the
+    frontier drains."""
+    rt = build_runtime(g, config,
+                       weighted_norm=program.use_delta and program.weighted)
+    state = HyTMState(*program.init_state(g.n_nodes, source))
+    rows = []
+    while len(rows) < config.max_iters:
+        state, info = _step(state, rt.csr, rt.parts, rt.zc_req, rt.inv_deg,
+                            program, config, rt.n_hub_partitions, None,
+                            rt.route)
+        rows.append(jax.device_get(info))
+        if int(info["next_active"]) == 0:
+            break
+    history = {k: np.stack([r[k] for r in rows]) for k in rows[0]}
+    return SimpleNamespace(
+        values=np.asarray(state.values), delta=np.asarray(state.delta),
+        iterations=len(rows), history=history,
+        total_transfer_bytes=float(np.sum(history["transfer_bytes"])))
 
 
 def _assert_min_bit_exact(a, b):
@@ -41,16 +76,16 @@ def _assert_min_bit_exact(a, b):
 
 @pytest.mark.parametrize("cds_mode", ["hub", "delta"])
 def test_chunked_min_bit_exact_vs_k1(cds_mode):
-    """MIN programs: K in {4, 64} reproduces K=1 bit-for-bit (values,
-    iterations, transfer bytes, engine picks) — including with the
-    'delta' CDS schedule, whose |Δ| segment-sum the iteration now skips
-    for min-combine programs (Δ is identically zero)."""
+    """MIN programs: K in {1, 4, 64} reproduces the per-iteration stepper
+    bit-for-bit (values, iterations, transfer bytes, engine picks) —
+    including with the 'delta' CDS schedule, whose |Δ| segment-sum the
+    iteration skips for min-combine programs (Δ is identically zero)."""
     g = rmat_graph(800, 8_000, seed=3)
     for prog in (SSSP, CC):
         base_cfg = HyTMConfig(n_partitions=8, sync_every=1, cds_mode=cds_mode)
-        base = run_hytm(g, prog, source=0, config=base_cfg)
+        base = _stepped(g, prog, 0, base_cfg)
         assert base.iterations > 1
-        for K in (4, 64):
+        for K in (1, 4, 64):
             chunked = run_hytm(
                 g, prog, source=0,
                 config=dataclasses.replace(base_cfg, sync_every=K),
@@ -62,13 +97,14 @@ def test_chunked_min_bit_exact_vs_k1(cds_mode):
 
 
 def test_chunked_sum_tolerance_bounded():
-    """SUM programs: chunked results agree with K=1 within the program
-    tolerance (same iteration count on this CPU backend)."""
+    """SUM programs: chunked results at K in {1, 4, 64} agree with the
+    per-iteration stepper within the program tolerance (same iteration
+    count on this CPU backend)."""
     g = rmat_graph(800, 8_000, seed=3)
     pr = dataclasses.replace(PAGERANK, tolerance=1e-6)
     base_cfg = HyTMConfig(n_partitions=8, sync_every=1, cds_mode="delta")
-    base = run_hytm(g, pr, source=None, config=base_cfg)
-    for K in (4, 64):
+    base = _stepped(g, pr, None, base_cfg)
+    for K in (1, 4, 64):
         chunked = run_hytm(
             g, pr, source=None,
             config=dataclasses.replace(base_cfg, sync_every=K),
@@ -130,9 +166,9 @@ def test_chunked_early_exit_on_empty_frontier():
 
 def test_chunked_dispatch_count_regression():
     """The chunked loop really batches: ceil(iters/K) hytm_chunk
-    dispatches and ZERO hytm_iteration dispatches, vs exactly
-    ``iterations`` single-iteration dispatches for K=1 (counted through
-    the shared ``count_driver_dispatches`` monkeypatch seam)."""
+    dispatches at K=16, vs exactly ``iterations`` chunks of one at K=1
+    (counted through the shared ``count_driver_dispatches`` monkeypatch
+    seam)."""
     from repro.core.hytm import count_driver_dispatches
 
     g = grid_mesh_graph(120, 3, seed=0)  # diameter-bound: many iterations
@@ -140,15 +176,13 @@ def test_chunked_dispatch_count_regression():
     with count_driver_dispatches() as counts:
         res1 = run_hytm(g, BFS, source=0,
                         config=HyTMConfig(n_partitions=4, sync_every=1))
-    assert counts["iteration"] == res1.iterations
-    assert counts["chunk"] == 0
+    assert counts["chunk"] == res1.iterations
     assert res1.iterations > 2 * K  # the workload is dispatch-bound
 
     with count_driver_dispatches() as counts:
         resK = run_hytm(g, BFS, source=0,
                         config=HyTMConfig(n_partitions=4, sync_every=K))
     _assert_min_bit_exact(res1, resK)
-    assert counts["iteration"] == 0
     assert counts["chunk"] == -(-resK.iterations // K)  # == ceil(iters/K)
     assert counts["chunk"] <= resK.iterations // K + 1  # the CI gate bound
 
@@ -227,9 +261,9 @@ _SHARDED_CHUNK_SCRIPT = """
 
 
 def test_chunked_sharded_matches_k1_and_oracle():
-    """Sharded path on 4 forced-host devices: one shard_mapped chunk per
-    dispatch reproduces the per-iteration sharded run (bit-exact MIN with
-    identical ICI accounting; tolerance-bounded SUM) and the
-    single-device oracle, autotune on and off."""
+    """Sharded path on 4 forced-host devices: K=4 shard_mapped chunks
+    reproduce chunks of one (bit-exact MIN with identical ICI accounting;
+    tolerance-bounded SUM) and the single-device oracle, autotune on and
+    off."""
     out = run_forced_devices(_SHARDED_CHUNK_SCRIPT, devices=4)
     assert out.count("OK") == 3

@@ -237,12 +237,27 @@ def _inside(inner, outer):
     return outer[1] <= inner[1] and inner[2] <= outer[2]
 
 
-@pytest.mark.parametrize("sync_every", [4, 1], ids=["chunked", "K=1"])
-def test_profiler_trace_holds_driver_spans(graph, tmp_path, sync_every):
+@pytest.mark.parametrize("sync_every,mesh_axis", [(4, None), (1, None),
+                                                  (4, "graph")],
+                         ids=["chunked", "K=1", "mesh"])
+def test_profiler_trace_holds_driver_spans(graph, tmp_path, sync_every,
+                                           mesh_axis):
+    """Both runs' host spans nest in their ``hytm.run``, the first run
+    compiles and the second does not — on one device, for chunks of one,
+    and on a 1-device mesh, whose runtime (built once, like a cell's)
+    keeps its compiled chunk."""
     # a config no other test dispatches, so the first run compiles
-    cfg = HyTMConfig(n_partitions=8, sync_every=sync_every, max_iters=9_001)
+    cfg = HyTMConfig(n_partitions=8, sync_every=sync_every, max_iters=9_001,
+                     mesh_axis=mesh_axis)
+    runtime = None
+    if mesh_axis is not None:
+        from repro.dist.graph_shard import build_sharded_runtime
+        from repro.launch.mesh import make_graph_mesh
+
+        runtime = build_sharded_runtime(graph, cfg, make_graph_mesh(1))
     events = _profiled(tmp_path, lambda: [
-        run_hytm(graph, SSSP, source=0, config=cfg) for _ in range(2)])
+        run_hytm(graph, SSSP, source=0, config=cfg, runtime=runtime)
+        for _ in range(2)])
     caller = next(e for e in events if e[0] == "caller")
     runs = [e for e in events if e[0] == "hytm.run"]
     assert len(runs) == 2 and all(_inside(r, caller) for r in runs)
@@ -252,6 +267,13 @@ def test_profiler_trace_holds_driver_spans(graph, tmp_path, sync_every):
         assert {"hytm.wait", "hytm.drain"} <= set(names)
         assert ("hytm.compile" in names) == first
         assert "hytm.dispatch" in names or first
+    # a recorder puts the same spans on the driver's own track
+    rec = TraceRecorder()
+    run_hytm(graph, SSSP, source=0, config=cfg, runtime=runtime, obs=rec)
+    track = "device0" if mesh_axis is None else "mesh"
+    assert {"hytm.run", "hytm.init", "chunk", "hytm.dispatch", "hytm.wait",
+            "hytm.drain", "hytm.result"} <= {
+        e.name for e in rec.events if e.track == track}
 
 
 def test_run_span_names_its_route(graph, tmp_path):
@@ -349,8 +371,8 @@ def _computation(text, name):
 def test_op_scopes_cover_the_program(sync_every):
     """At scale 10 with the kernels (interpret mode here), the programs
     runs dispatched map to every scope, and >= 95 % of the work in the
-    loop body (the chunk's while body; the iteration's entry for K=1) of
-    a run over a built runtime maps to one.  That run's blocks are routed
+    chunk's while body (a chunk of one for K=1) of a run over a built
+    runtime maps to one.  That run's blocks are routed
     when the runtime is built, so its program has no ``filter.order``;
     a runtime without the route sorts under it.  The registered
     signature compiles to the program the concrete arguments compile
@@ -360,8 +382,7 @@ def test_op_scopes_cover_the_program(sync_every):
     import jax
 
     from repro.core.cost_model import init_history_buffers
-    from repro.core.hytm import (
-        HyTMState, _iteration_impl, build_runtime, hytm_chunk, hytm_iteration)
+    from repro.core.hytm import HyTMState, _iteration_impl, build_runtime, hytm_chunk
 
     g = rmat_graph(1 << 10, 16 << 10, seed=3)
     cfg = HyTMConfig(n_partitions=8, sync_every=sync_every, use_kernels=True,
@@ -376,16 +397,12 @@ def test_op_scopes_cover_the_program(sync_every):
     state = HyTMState(*SSSP.init_state(g.n_nodes, 0))
     args = (rt.csr, rt.parts, rt.zc_req, rt.inv_deg, SSSP, cfg,
             rt.n_hub_partitions)
-    if sync_every > 1:
-        info = jax.eval_shape(lambda s: _iteration_impl(s, *args)[1], state)
-        text = hytm_chunk.lower(state, init_history_buffers(info, sync_every),
-                                *args, sync_every, None, rt.route).compile().as_text()
-        entry = _computation(text, re.search(r"ENTRY %(\S+) ", text).group(1))
-        outer = next(line for line in entry if " while(" in line)
-        body = _computation(text, re.search(r"body=%([\w.-]+)", outer).group(1))
-    else:
-        text = hytm_iteration.lower(state, *args, None, rt.route).compile().as_text()
-        body = _computation(text, re.search(r"ENTRY %(\S+) ", text).group(1))
+    info = jax.eval_shape(lambda s: _iteration_impl(s, *args)[1], state)
+    text = hytm_chunk.lower(state, init_history_buffers(info, sync_every),
+                            *args, sync_every, None, rt.route).compile().as_text()
+    entry = _computation(text, re.search(r"ENTRY %(\S+) ", text).group(1))
+    outer = next(line for line in entry if " while(" in line)
+    body = _computation(text, re.search(r"body=%([\w.-]+)", outer).group(1))
     mine = dict(scopes.instruction_scopes(text))
     assert set(mine.items()) <= set(got)
     assert scopes.FILTER_ORDER not in mine.values()

@@ -225,14 +225,36 @@ def test_kill_resume_bit_identical(kill_at):
                                   res.history[KEY_TRANSFER_BYTES])
 
 
-def test_on_chunk_requires_chunked_driver():
+def test_on_chunk_fires_per_iteration_at_k1_and_resumes():
+    """At ``sync_every=1`` every iteration is a chunk boundary: the hook
+    fires once per iteration, and a run killed at one resumes
+    bit-identically to the uninterrupted run."""
+    import tempfile
+
     g, _ = _graph()
     cfg1 = dataclasses.replace(CFG, sync_every=1)
+    seen = []
+    base = run_hytm(g, SSSP, source=0, config=cfg1,
+                    on_chunk=lambda **kw: seen.append(kw["iterations"]))
+    assert base.iterations > 3
+    assert seen == list(range(1, base.iterations + 1))
+
+    ck = os.path.join(tempfile.mkdtemp(prefix="resil_"), "k1.ckpt.npz")
+    hook = CheckpointHook(ck, program=SSSP.name, anchor=(0, 0))
+    plan = plan_of(FaultSpec("chunk_dispatch", "fail", at=(3,)), seed=3)
     try:
-        run_hytm(g, SSSP, source=0, config=cfg1, on_chunk=lambda **kw: None)
-        raise AssertionError("expected ValueError")
-    except ValueError as e:
-        assert "sync_every" in str(e)
+        run_hytm(g, SSSP, source=0, config=cfg1, faults=plan, on_chunk=hook)
+        raise AssertionError("injected kill did not fire")
+    except RetriesExhausted:
+        pass
+    assert hook.saved == 3  # one checkpoint per completed iteration
+    res = resume_run(ck, g, SSSP, config=cfg1, source=0,
+                     expect_anchor=(0, 0))
+    np.testing.assert_array_equal(base.values, res.values)
+    assert res.iterations == base.iterations
+    assert res.total_transfer_bytes == base.total_transfer_bytes
+    np.testing.assert_array_equal(base.history[KEY_ENGINES],
+                                  res.history[KEY_ENGINES])
 
 
 _SHARDED_RESUME_SCRIPT = """
